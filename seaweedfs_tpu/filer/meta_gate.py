@@ -26,8 +26,11 @@ one store hit.
 from __future__ import annotations
 
 import asyncio
+import logging
 import os
 from typing import Optional
+
+logger = logging.getLogger(__name__)
 
 # below this many distinct paths the inline find_many is a few µs —
 # cheaper than a worker-thread round trip
@@ -77,6 +80,7 @@ class MetaLookupGate:
             "chains": 0,
             "device_batches": 0,
             "host_fallbacks": 0,
+            "device_error": 0,
             "identity_mismatches": 0,
         }
 
@@ -243,7 +247,12 @@ class MetaLookupGate:
         try:
             res = self.arena.probe_groups([(segments, keys)])[0]
         except Exception:
-            res = None
+            # a device failure is not a cold arena (lookup_gate's rule)
+            if not self.stats["device_error"]:
+                logger.exception("arena dispatch failed on the device")
+            self.stats["device_error"] += 1
+            self._note_fallback("device_error")
+            return None
         if res is None:
             self._note_fallback("arena_cold")
             return None

@@ -15,10 +15,10 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "gf256.cpp")
-_LIB = os.path.join(_HERE, "libgf256.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_tier = "numpy"  # flag set of the loaded library; "numpy" = none loaded
 
 
 def _cpu_flags() -> set:
@@ -52,32 +52,54 @@ def _flag_candidates(max_tier: str = "best") -> list:
     return candidates
 
 
-def _build(src: str = _SRC, lib: str = _LIB, max_tier: str = "best") -> bool:
+def _lib_path(flags: list) -> str:
+    """The built file is named after the flag set it was built with, so a
+    host only ever loads a library built for SIMD it has: a checkout that
+    travels with its .so files (they are git-ignored, but a disk copy
+    carries them) makes the next host build its own from gf256.cpp instead
+    of loading another's GFNI/AVX-512 code."""
+    return os.path.join(_HERE, f"libgf256_{_tier_name(flags)}.so")
+
+
+def _tier_name(flags: list) -> str:
+    return "-".join(f[2:] for f in flags) or "scalar"  # "-mavx2" -> "avx2"
+
+
+def _build_and_open(max_tier: str = "best"):
+    """(CDLL, tier name) of the best flag set this CPU has that builds and
+    loads, building from gf256.cpp when the named file is missing or older
+    than the source; (None, "numpy") when no candidate does."""
     for flags in _flag_candidates(max_tier):
-        cmd = ["g++", "-O3", "-shared", "-fPIC", *flags, src, "-o", lib]
+        lib = _lib_path(flags)
+        if not os.path.exists(lib) or os.path.getmtime(lib) < os.path.getmtime(
+            _SRC
+        ):
+            # build beside the target and rename: a concurrent process
+            # never dlopens a half-written file
+            tmp = f"{lib}.{os.getpid()}.tmp"
+            cmd = ["g++", "-O3", "-shared", "-fPIC", *flags, _SRC, "-o", tmp]
+            try:
+                subprocess.run(
+                    cmd, check=True, capture_output=True, timeout=120
+                )
+                os.replace(tmp, lib)
+            except (subprocess.SubprocessError, OSError):
+                continue
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            return True
-        except (subprocess.SubprocessError, FileNotFoundError):
+            return ctypes.CDLL(lib), _tier_name(flags)
+        except OSError:
             continue
-    return False
+    return None, "numpy"
 
 
 def load() -> Optional[ctypes.CDLL]:
     """The compiled library, building it first if necessary."""
-    global _lib, _load_failed
+    global _lib, _load_failed, _tier
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(
-            _SRC
-        ):
-            if not _build():
-                _load_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
+        lib, tier = _build_and_open()
+        if lib is None:
             _load_failed = True
             return None
         lib.gf_matmul.argtypes = [
@@ -103,7 +125,7 @@ def load() -> Optional[ctypes.CDLL]:
             lib.gf_encode_copy.restype = ctypes.c_int
         except AttributeError:  # stale .so without the symbol
             pass
-        _lib = lib
+        _lib, _tier = lib, tier
         return _lib
 
 
@@ -111,7 +133,14 @@ def available() -> bool:
     return load() is not None
 
 
-_BASE_LIB = os.path.join(_HERE, "libgf256_avx2.so")
+def tier() -> str:
+    """Which host codec tier is live: the flag set of the loaded library
+    (e.g. "gfni-avx512f-avx512bw-avx2", "avx2", "scalar"), or "numpy" when
+    no compiler was found and the table codec serves."""
+    load()
+    return _tier
+
+
 _base_lib = None
 _base_failed = False
 
@@ -125,15 +154,8 @@ def load_baseline():
     with _lock:
         if _base_lib is not None or _base_failed:
             return _base_lib
-        if not os.path.exists(_BASE_LIB) or os.path.getmtime(
-            _BASE_LIB
-        ) < os.path.getmtime(_SRC):
-            if not _build(lib=_BASE_LIB, max_tier="avx2"):
-                _base_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(_BASE_LIB)
-        except OSError:
+        lib, _ = _build_and_open(max_tier="avx2")
+        if lib is None:
             _base_failed = True
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)

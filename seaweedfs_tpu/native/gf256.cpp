@@ -18,7 +18,7 @@
 //  3. Scalar table fallback.
 //
 // Build: g++ -O3 -mgfni -mavx512f -mavx512bw -mavx2 -shared -fPIC
-//        gf256.cpp -o libgf256.so
+//        gf256.cpp -o libgf256_gfni-avx512f-avx512bw-avx2.so
 // (the Python loader probes /proc/cpuinfo and walks the flag candidates
 // down to scalar; VPSHUFB shuffles within each 128-bit lane, so
 // broadcasting the 16-entry nibble tables to both lanes gives the

@@ -100,7 +100,13 @@ def free_port_pair(taken: Optional[set] = None) -> int:
     outside `taken`. Scanned, not bound-and-released-at-0: the gRPC twin
     must be free too, and the kernel cannot promise a pair."""
     taken = taken or set()
-    for p in range(_PORT_LO, _PORT_HI):
+    # each process scans from its own place in the band: concurrent
+    # clusters (xdist workers, a smoke beside a soak) that all started at
+    # _PORT_LO picked the same "free" pair between scan and bind
+    span = _PORT_HI - _PORT_LO
+    first = os.getpid() * 61 % span
+    for i in range(span):
+        p = _PORT_LO + (first + i) % span
         if p in taken or (p + _GRPC_OFFSET) in taken:
             continue
         try:
@@ -240,6 +246,12 @@ class ProcCluster:
     child whose name or role matches gets the plan serialized into its
     `SEAWEEDFS_TPU_FAULTS`, so seeded in-process faults fire inside that
     subprocess from import time.
+
+    chip_child: a chip belongs to one process at a time, so exactly one
+    named child inherits this process's JAX environment (and with it the
+    chip, where the host has one); every other child gets
+    `JAX_PLATFORMS=cpu` set outright — its device planes then run on the
+    host on purpose, and its `/status` says so.
     """
 
     def __init__(
@@ -264,8 +276,10 @@ class ProcCluster:
         fleet: bool = False,
         fleet_bounds: Optional[list] = None,
         followers: int = 0,
+        chip_child: str = "volume-0",
     ):
         self.root = os.path.abspath(root)
+        self.chip_child = chip_child
         self.n_volumes = volumes
         self.n_filers = filers
         self.with_s3 = with_s3
@@ -319,6 +333,8 @@ class ProcCluster:
         env.update(self.extra_env)
         env["SEAWEEDFS_TPU_PULSE_SECONDS"] = str(self.pulse_seconds)
         env["PYTHONUNBUFFERED"] = "1"
+        if name != self.chip_child:
+            env["JAX_PLATFORMS"] = "cpu"
         # children run with their log dir as cwd: the package must be
         # importable by path, not by the parent's cwd
         pkg_root = os.path.dirname(

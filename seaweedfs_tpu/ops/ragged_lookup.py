@@ -40,15 +40,17 @@ builds the next generation on a background thread while in-flight
 dispatches keep their reference to the old one (generations are
 immutable; the swap is one pointer under a lock), so the serving path
 never stalls on a transfer. Every caller must treat `ensure()` returning
-None — device absent, arena cold, arena killed — as an instruction to
-serve from the host maps instead; the arena is an accelerator, never an
-authority.
+None — arena cold, arena killed — as an instruction to serve from the
+host maps instead; the arena is an accelerator, never an authority. A
+device that fails is neither: a failed upload or dispatch is counted as
+`device_error` and logged, never folded into "cold".
 """
 
 from __future__ import annotations
 
 import functools
 import itertools
+import logging
 import os
 import threading
 import time
@@ -59,7 +61,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..util.device import platform
 from .index_kernel import _search_range_bounded
+
+logger = logging.getLogger(__name__)
 
 PAGE = 2048  # rows; segment bases are page-aligned in the arena
 MIN_ROWS = 4096  # generations pad to pow2 row counts ≥ this (jit reuse)
@@ -69,25 +74,6 @@ ARENA_BYTES = int(
 )
 
 _HANDLES = itertools.count(1)
-
-_DEVICE_OK: Optional[bool] = None
-
-
-def device_available() -> bool:
-    """True when jax can run the ragged program on ANY backend (the CPU
-    stand-in included — provenance is the bench's `device_status` job,
-    availability is only about whether a dispatch would crash)."""
-    global _DEVICE_OK
-    if _DEVICE_OK is None:
-        try:
-            import jax
-
-            jax.devices()
-            _DEVICE_OK = True
-        except Exception:
-            _DEVICE_OK = False
-    return _DEVICE_OK
-
 
 def _metrics():
     try:
@@ -292,6 +278,7 @@ class DeviceColumnArena:
             "evictions": 0,
             "cold_misses": 0,
             "dead_refusals": 0,
+            "device_error": 0,
         }
 
     # ---------------- residency ----------------
@@ -299,9 +286,8 @@ class DeviceColumnArena:
         """All `segments` resident in the CURRENT generation -> that
         generation (LRU bumped). Otherwise registers them, queues one
         background refresh, and returns None (caller: host fallback)."""
-        if self._dead or not device_available():
-            if self._dead:
-                self.counters["dead_refusals"] += 1
+        if self._dead:
+            self.counters["dead_refusals"] += 1
             return None
         with self._lock:
             self._tick += 1
@@ -366,10 +352,15 @@ class DeviceColumnArena:
                 m.NEEDLE_MAP_DEVICE_SEGMENTS.set(len(gen.seg))
                 m.NEEDLE_MAP_DEVICE_UPLOADS.inc()
         except Exception:
-            # a failed upload must never take serving down: the arena
-            # just stays cold and every caller keeps host-serving
+            # a failed upload must never take serving down — callers keep
+            # host-serving — but it is a DEVICE ERROR, not a cold arena:
+            # counted under its own name and logged once with its traceback
             with self._lock:
                 self._refresh_queued = False
+                first = not self.counters["device_error"]
+                self.counters["device_error"] += 1
+            if first:
+                logger.exception("arena refresh failed on the device")
 
     def prefetch(self, segment: ArenaSegment) -> str:
         """Flush-path residency hint (ISSUE 20 satellite): the LSM store
@@ -377,7 +368,7 @@ class DeviceColumnArena:
         refresh uploads it before the first probe would cold-miss on it.
         Never blocks, never counts as a probe-path cold miss. Returns the
         outcome for the `arena_prefetch_total{result}` counter."""
-        if self._dead or not device_available():
+        if self._dead:
             return "unavailable"
         with self._lock:
             self._tick += 1
@@ -419,7 +410,7 @@ class DeviceColumnArena:
                 "registered_segments": len(self._sources),
                 "budget_bytes": self.budget,
                 "dead": self._dead,
-                "device_available": device_available(),
+                "platform": platform(),
             }
             out.update(self.counters)
         return out
@@ -429,16 +420,15 @@ class DeviceColumnArena:
         """groups: [(segments_newest_first, keys_u64)] — one entry per
         (volume | path-spine) contributor of the wakeup. Returns a list
         aligned with groups: None where this group must be host-served
-        (cold/dead/absent device), else {found, rank, off, size} numpy
+        (cold/dead), else {found, rank, off, size} numpy
         arrays aligned with the group's keys; `rank` indexes the group's
         newest-first segment list (the caller applies its own
         newest-wins + tombstone semantics)."""
         t0 = time.perf_counter()
         results: list = [None] * len(groups)
         plan = []  # (group index, segments, keys, gen)
-        if self._dead or not device_available():
-            if self._dead:
-                self.counters["dead_refusals"] += 1
+        if self._dead:
+            self.counters["dead_refusals"] += 1
             return results
         for gi, (segments, keys) in enumerate(groups):
             if len(keys) == 0:

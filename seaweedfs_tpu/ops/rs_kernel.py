@@ -20,6 +20,7 @@ from ..storage.erasure_coding.galois import (
     mat_mul,
     reconstruction_matrix,
 )
+from ..util.device import on_tpu
 from .gf256 import gf_matmul_bytes
 
 
@@ -54,14 +55,6 @@ class TpuRSCodec:
         self._standin = None  # lazy: host kernel the streamed pipeline
         # dispatches when no real accelerator backs the jax backend
 
-    def _on_real_device(self) -> bool:
-        import jax
-
-        try:
-            return jax.devices()[0].platform == "tpu"
-        except Exception:
-            return False
-
     def _standin_codec(self):
         """The kernel the streamed file pipeline dispatches per staged
         chunk when the jax backend is the CPU STAND-IN: running the GF
@@ -81,7 +74,7 @@ class TpuRSCodec:
                 self._standin = NativeRSCodec(
                     self.data_shards, self.parity_shards
                 )
-            except Exception:
+            except (RuntimeError, OSError):  # no compiler / no library
                 self._standin = self
         return self._standin
 
@@ -91,7 +84,7 @@ class TpuRSCodec:
         "device" (host->device upload + MXU/VPU kernel + download),
         "host_standin" (native SIMD kernel substituted on the CPU
         stand-in), or "device_emulated" (jax-on-CPU — no native lib)."""
-        if self._on_real_device():
+        if on_tpu():
             return "device"
         return (
             "device_emulated"
@@ -102,7 +95,7 @@ class TpuRSCodec:
     def pipeline_encode(self, data) -> np.ndarray:
         """Per-chunk encode for the streamed file pipeline (see
         _standin_codec for the stand-in substitution)."""
-        if self._on_real_device():
+        if on_tpu():
             return self.encode(data)
         standin = self._standin_codec()
         if standin is self:

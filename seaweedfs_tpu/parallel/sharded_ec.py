@@ -20,14 +20,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # moved to the jax namespace in newer releases
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
-from ..ops.gf256 import gf_matmul_expr, pack_bytes, unpack_bytes
+from ..ops.gf256 import gf_matmul_expr
 
 
 def make_mesh(
@@ -63,30 +59,56 @@ def _encode_packed(matrix: np.ndarray, packed):
     return jnp.stack(gf_matmul_expr(matrix, rows))
 
 
-def _pad_vol(data, vol: int):
-    """Zero-pad the volume axis up to a multiple of the mesh's vol axis so
-    uneven batches shard; GF(2^8) is linear, so zero stripes encode/verify
-    to zero and are simply stripped from the result."""
-    v = data.shape[0]
-    pad = (-v) % vol
-    if pad:
-        data = jnp.concatenate(
-            [data, jnp.zeros((pad,) + data.shape[1:], dtype=data.dtype)]
-        )
-    return data, v
+# per device id: [bytes held, of which zero padding of the vol axis], summed
+# over the inputs and outputs of every sharded call since the last clear()
+# — the multi-chip mirror of the encode pipeline's LAST_STAGES: a
+# diagnostic that shows "everything on the first device" or "half the mesh
+# encodes zeros" from outside, not part of the encode contract
+DEVICE_BYTES: dict = {}
 
 
-def sharded_encode(matrix: np.ndarray, data, mesh: Mesh):
-    """data uint8[V, C, N] -> parity uint8[V, R, N], sharded (vol, -, blk).
+def _note_placement(arr, real_volumes: int) -> None:
+    for sh in arr.addressable_shards:
+        vols = sh.index[0].indices(arr.shape[0])
+        n = max(1, vols[1] - vols[0])
+        pad = n - max(0, min(vols[1], real_volumes) - vols[0])
+        held = DEVICE_BYTES.setdefault(sh.device.id, [0, 0])
+        held[0] += sh.data.nbytes
+        held[1] += sh.data.nbytes * pad // n
 
-    N must be divisible by 4 * mesh.shape['blk'] (uint32 packing per device).
-    """
-    matrix = np.asarray(matrix, dtype=np.uint8)
-    v, c, n = data.shape
+
+def _put_words(data, mesh: Mesh):
+    """Host uint8[V, C, N] -> device uint32[V', C, N/4] sharded (vol, -, blk),
+    V' = V zero-padded to the mesh's vol axis (GF(2^8) is linear: zero
+    stripes encode/verify to zero and are stripped from the result).
+
+    The uint8->uint32 packing is a free numpy VIEW on the host, exactly as
+    the one-chip served path packs (gf256.pack_bytes_host): on a TPU the
+    same bitcast on the device is a relayout between tilings that the
+    compiler pads 12.8x (the v5e compiler refused the 16 MB-per-row batch
+    outright: 22 GB of HBM wanted). Returns (sharded words, V)."""
+    data = np.ascontiguousarray(data, dtype=np.uint8)
+    v, _c, n = data.shape
     blk = mesh.shape["blk"]
-    assert n % (4 * blk) == 0, f"N={n} not divisible by {4*blk}"
-    data = jnp.asarray(data, dtype=jnp.uint8)
-    data, v = _pad_vol(data, mesh.shape["vol"])
+    assert n % (4 * blk) == 0, f"N={n} not divisible by {4 * blk}"
+    pad = (-v) % mesh.shape["vol"]
+    if pad:
+        data = np.concatenate(
+            [data, np.zeros((pad,) + data.shape[1:], dtype=np.uint8)]
+        )
+    words = jax.device_put(
+        data.view(np.uint32), NamedSharding(mesh, P("vol", None, "blk"))
+    )
+    _note_placement(words, v)
+    return words, v
+
+
+@functools.lru_cache(maxsize=64)
+def _apply_body(matrix_key, mesh: Mesh):
+    """Jitted shard_map applying one static GF matrix to every local
+    stripe; cached per (matrix, mesh) so steady traffic reuses the trace
+    (and its compiled program) instead of rebuilding the closure."""
+    matrix = np.asarray(matrix_key, dtype=np.uint8)
 
     @functools.partial(
         shard_map,
@@ -94,24 +116,16 @@ def sharded_encode(matrix: np.ndarray, data, mesh: Mesh):
         in_specs=P("vol", None, "blk"),
         out_specs=P("vol", None, "blk"),
     )
-    def body(local):  # [v_loc, C, n_loc] uint8
-        packed = jax.lax.bitcast_convert_type(
-            local.reshape(local.shape[0], c, -1, 4), jnp.uint32
-        )
-        parity = jax.vmap(lambda p: _encode_packed(matrix, p))(packed)
-        return jax.lax.bitcast_convert_type(parity, jnp.uint8).reshape(
-            local.shape[0], matrix.shape[0], -1
-        )
+    def body(local):  # uint32[v_loc, C, w_loc]
+        return jax.vmap(lambda p: _encode_packed(matrix, p))(local)
 
-    return jax.jit(body)(data)[:v]
+    return jax.jit(body)
 
 
-def sharded_verify(matrix: np.ndarray, shards, mesh: Mesh):
-    """shards uint8[V, C+R, N] -> global mismatch count (psum over the mesh)."""
-    matrix = np.asarray(matrix, dtype=np.uint8)
+@functools.lru_cache(maxsize=64)
+def _verify_body(matrix_key, mesh: Mesh):
+    matrix = np.asarray(matrix_key, dtype=np.uint8)
     k = matrix.shape[1]
-    shards = jnp.asarray(shards, dtype=jnp.uint8)
-    shards, _ = _pad_vol(shards, mesh.shape["vol"])
 
     @functools.partial(
         shard_map,
@@ -119,47 +133,49 @@ def sharded_verify(matrix: np.ndarray, shards, mesh: Mesh):
         in_specs=P("vol", None, "blk"),
         out_specs=P(),
     )
-    def body(local):
-        c = k
-        packed = jax.lax.bitcast_convert_type(
-            local.reshape(local.shape[0], local.shape[1], -1, 4), jnp.uint32
-        )
-        parity = jax.vmap(lambda p: _encode_packed(matrix, p[:c]))(packed)
-        mism = jnp.sum((parity != packed[:, c:]).astype(jnp.int32))
+    def body(local):  # uint32[v_loc, C+R, w_loc]
+        parity = jax.vmap(lambda p: _encode_packed(matrix, p[:k]))(local)
+        mism = jnp.sum((parity != local[:, k:]).astype(jnp.int32))
         mism = jax.lax.psum(mism, axis_name="vol")
         return jax.lax.psum(mism, axis_name="blk")
 
-    return jax.jit(body)(shards)
+    return jax.jit(body)
+
+
+def _matrix_key(matrix) -> tuple:
+    return tuple(map(tuple, np.asarray(matrix, dtype=np.uint8)))
+
+
+def _apply(matrix, data, mesh: Mesh) -> np.ndarray:
+    words, v = _put_words(data, mesh)
+    out = _apply_body(_matrix_key(matrix), mesh)(words)
+    _note_placement(out, v)
+    return np.asarray(out).view(np.uint8)[:v]
+
+
+def sharded_encode(matrix: np.ndarray, data, mesh: Mesh) -> np.ndarray:
+    """data uint8[V, C, N] -> parity uint8[V, R, N], sharded (vol, -, blk).
+
+    N must be divisible by 4 * mesh.shape['blk'] (uint32 packing per device).
+    """
+    return _apply(matrix, data, mesh)
+
+
+def sharded_verify(matrix: np.ndarray, shards, mesh: Mesh) -> int:
+    """shards uint8[V, C+R, N] -> global count of mismatching packed words
+    (psum over the mesh)."""
+    words, _v = _put_words(shards, mesh)
+    return int(_verify_body(_matrix_key(matrix), mesh)(words))
 
 
 def sharded_reconstruct_step(
     dec_rows: np.ndarray, survivors, mesh: Mesh
-):
+) -> np.ndarray:
     """Degraded-read analogue: survivor rows sharded across the mesh's "blk"
     axis are locally matmul'd by the (static) decode rows; the "vol" axis
     batches volumes. survivors: uint8[V, k, N] -> uint8[V, len(dec_rows), N].
     """
-    dec_rows = np.asarray(dec_rows, dtype=np.uint8)
-    survivors = jnp.asarray(survivors, dtype=jnp.uint8)
-    k = dec_rows.shape[1]
-    survivors, v = _pad_vol(survivors, mesh.shape["vol"])
-
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=P("vol", None, "blk"),
-        out_specs=P("vol", None, "blk"),
-    )
-    def body(local):
-        packed = jax.lax.bitcast_convert_type(
-            local.reshape(local.shape[0], k, -1, 4), jnp.uint32
-        )
-        out = jax.vmap(lambda p: _encode_packed(dec_rows, p))(packed)
-        return jax.lax.bitcast_convert_type(out, jnp.uint8).reshape(
-            local.shape[0], dec_rows.shape[0], -1
-        )
-
-    return jax.jit(body)(survivors)[:v]
+    return _apply(dec_rows, survivors, mesh)
 
 
 def sharded_reconstruct_padded(
@@ -178,5 +194,5 @@ def sharded_reconstruct_padded(
         survivors = np.concatenate(
             [survivors, np.zeros((v, k, pad), dtype=np.uint8)], axis=2
         )
-    out = np.asarray(sharded_reconstruct_step(dec_rows, survivors, mesh))
+    out = sharded_reconstruct_step(dec_rows, survivors, mesh)
     return out[:, :, :n] if pad else out

@@ -18,10 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # moved to the jax namespace in newer releases
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops.index_kernel import _search_range, _split_u64
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import asyncio
 import functools as _functools
+import logging
 import os
 import time
 from typing import Optional
@@ -53,6 +54,8 @@ from ..util.metrics import (
     WRITE_STAGE_SECONDS,
 )
 from .volume_ec import EcHandlers
+
+logger = logging.getLogger(__name__)
 
 
 _NEEDS_FULL_APP = object()  # needle shape the fast tier doesn't serve
@@ -384,6 +387,31 @@ class VolumeServer(EcHandlers):
         # the HBM-resident column arena, host fallback when cold/absent)
         self.lookup_gate = None
         self.lookup_arena = None
+        # what the device planes of THIS process run on (None: they are
+        # off, JAX is never imported). `tpu` / `device` / `arena` are
+        # requests for the chip and refuse to start without one.
+        self.device: Optional[dict] = None
+        if codec_backend not in ("cpu", "numpy") or batch_lookup in (
+            "auto", "device", "arena",
+        ):
+            from ..util import device
+
+            asked = {}
+            if codec_backend == "tpu":
+                asked["storageBackend"] = codec_backend
+            if batch_lookup in ("device", "arena"):
+                asked["batchLookup"] = batch_lookup
+            if asked:
+                device.require_chip(**asked)
+            self.device = device.describe()
+            device.watch_compiles()
+            logger.warning(
+                "volume server %s: device planes on platform=%s "
+                "device_kind=%r count=%d (storageBackend=%s batchLookup=%s)",
+                self.address, self.device["platform"],
+                self.device["device_kind"], self.device["count"],
+                codec_backend, batch_lookup,
+            )
         if batch_lookup == "arena":
             from ..ops.ragged_lookup import get_default_arena
             from .lookup_gate import BatchLookupGate
@@ -1064,7 +1092,13 @@ class VolumeServer(EcHandlers):
 
         path = request.path
         if path == "/status":
-            return web.json_response({"Version": "seaweedfs-tpu", "Volumes": []})
+            return web.json_response(
+                {
+                    "Version": "seaweedfs-tpu",
+                    "Volumes": [],
+                    "Device": self.device,
+                }
+            )
         if path in ("/ui", "/ui/"):
             return self._ui_response()
         # /metrics and /debug/pprof (ref -pprof, util/grace/pprof.go) are

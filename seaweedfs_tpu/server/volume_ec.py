@@ -30,9 +30,11 @@ from ..util.backoff import (
 )
 from ..util.metrics import (
     EC_DEGRADED_READ_SECONDS,
+    EC_ENCODE_BYTES,
     EC_RECONSTRUCTIONS,
     RETRY_COUNTER,
 )
+from ..storage.erasure_coding import encoder
 from ..storage.erasure_coding import (
     DATA_SHARDS_COUNT,
     TOTAL_SHARDS_COUNT,
@@ -221,8 +223,15 @@ class EcHandlers:
                 except OSError:
                     dat_size = 0
                 await self._charge_maintenance(dat_size, plane=req["plane"])
-            await loop.run_in_executor(
-                None, lambda: write_ec_files(base, codec=codec)
+            def encode() -> str:
+                write_ec_files(base, codec=codec)
+                # which executor ran the kernel stage, read on the thread
+                # that just set it ("device" only when a TPU ran it)
+                return encoder.LAST_ROUTE.get("kernel", "host")
+
+            kernel = await loop.run_in_executor(None, encode)
+            EC_ENCODE_BYTES.inc(
+                os.path.getsize(base + ".dat"), backend=kernel
             )
             await loop.run_in_executor(None, write_sorted_file_from_idx, base)
             v = self.store.find_volume(vid)

@@ -310,13 +310,8 @@ class EcVolume:
             # tiny batches aren't worth a device dispatch / first-use
             # compile; 5-byte offsets exceed the kernel's u32 columns
             from ...types import OFFSET_SIZE
-            from ..volume import _device_available
 
-            use_device = (
-                OFFSET_SIZE == 4
-                and len(needle_ids) >= 64
-                and _device_available()
-            )
+            use_device = OFFSET_SIZE == 4 and len(needle_ids) >= 64
         if not use_device:
             from ...types import OFFSET_SIZE
 

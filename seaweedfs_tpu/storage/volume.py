@@ -46,23 +46,6 @@ from .super_block import SUPER_BLOCK_SIZE, SuperBlock, read_super_block
 from .ttl import EMPTY_TTL
 
 
-_DEVICE_OK: Optional[bool] = None
-
-
-def _device_available() -> bool:
-    """True when jax can run the bulk-lookup program (any backend)."""
-    global _DEVICE_OK
-    if _DEVICE_OK is None:
-        try:
-            import jax
-
-            jax.devices()
-            _DEVICE_OK = True
-        except Exception:
-            _DEVICE_OK = False
-    return _DEVICE_OK
-
-
 class NotFound(Exception):
     pass
 
@@ -875,7 +858,6 @@ class Volume:
                 snap_fn is not None
                 and OFFSET_SIZE == 4
                 and len(keys) >= 64
-                and _device_available()
             )
         if not use_device or snap_fn is None:
             from ..types import OFFSET_SIZE

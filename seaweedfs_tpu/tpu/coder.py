@@ -93,7 +93,8 @@ def adaptive_codec(
     This is the fix for the round-2 finding that the system shipped a
     transfer-bound device pipeline (0.14x baseline) while a 25x-faster host
     codec sat idle: the decision is made from a one-time measurement, not
-    from `jax.devices()` optimism, and any device failure falls back to CPU.
+    from `jax.devices()` optimism. On a CPU platform the host codec serves;
+    with a device attached, a probe that fails raises.
     """
     key = (data_shards, parity_shards, interpret)
     with _adaptive_lock:
@@ -106,48 +107,34 @@ def adaptive_codec(
 
 
 def _pick_adaptive(data_shards: int, parity_shards: int, interpret: bool):
+    from ..util.device import platform
+
     cpu_codec = get_codec("cpu", data_shards, parity_shards)
-    try:
-        import jax
+    if platform() == "cpu":
+        return cpu_codec
+    # a device is attached: a probe that fails on it is an error to see,
+    # not a reason to serve from the host in silence
+    from ..ops.rs_kernel import TpuRSCodec
 
-        if jax.devices()[0].platform == "cpu":
-            return cpu_codec
-        from ..ops.rs_kernel import TpuRSCodec
-
-        tpu_codec = TpuRSCodec(data_shards, parity_shards, interpret=interpret)
-        t_tpu = probe_roundtrip_seconds(tpu_codec)
-        t_cpu = probe_roundtrip_seconds(cpu_codec)
-        if t_tpu < t_cpu:
-            logger.info(
-                "adaptive codec: device path wins (%.1fms vs %.1fms/MB-stripe)",
-                t_tpu * 1e3,
-                t_cpu * 1e3,
-            )
-            return tpu_codec
+    tpu_codec = TpuRSCodec(data_shards, parity_shards, interpret=interpret)
+    t_tpu = probe_roundtrip_seconds(tpu_codec)
+    t_cpu = probe_roundtrip_seconds(cpu_codec)
+    if t_tpu < t_cpu:
         logger.info(
-            "adaptive codec: device round trip transfer-bound "
-            "(%.1fms vs %.1fms/MB-stripe) — serving native CPU codec",
+            "adaptive codec: device path wins (%.1fms vs %.1fms/MB-stripe)",
             t_tpu * 1e3,
             t_cpu * 1e3,
         )
-        return cpu_codec
-    except Exception as e:  # any device failure must not take down the server
-        logger.warning("adaptive codec: device probe failed (%s) — CPU", e)
-        return cpu_codec
+        return tpu_codec
+    logger.info(
+        "adaptive codec: device round trip transfer-bound "
+        "(%.1fms vs %.1fms/MB-stripe) — serving native CPU codec",
+        t_tpu * 1e3,
+        t_cpu * 1e3,
+    )
+    return cpu_codec
 
 
 def reset_adaptive_cache() -> None:
     with _adaptive_lock:
         _adaptive_cache.clear()
-
-
-def detect_backend() -> str:
-    """'tpu' when a TPU is attached, else 'cpu'."""
-    try:
-        import jax
-
-        if jax.devices()[0].platform == "tpu":
-            return "tpu"
-    except Exception:
-        pass
-    return "cpu"

@@ -771,14 +771,18 @@ class FastHTTPServer:
     async def stop(self):
         if self._server is not None:
             self._server.close()
-            try:
-                await self._server.wait_closed()
-            except Exception:
-                pass
+        # connections first: since Python 3.12 wait_closed() returns only
+        # when every connection is gone, and a keep-alive client never
+        # hangs up by itself (five bench legs waited here for ever)
         for conn in list(self._conns):
             try:
                 if conn.transport is not None:
                     conn.transport.close()
+            except Exception:
+                pass
+        if self._server is not None:
+            try:
+                await self._server.wait_closed()
             except Exception:
                 pass
 

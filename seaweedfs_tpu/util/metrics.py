@@ -354,6 +354,20 @@ VOLUME_GAUGE = REGISTRY.gauge(
 EC_ENCODE_BYTES = REGISTRY.counter(
     "seaweedfs_tpu_ec_encoded_bytes_total", "bytes erasure-coded, by backend"
 )
+# what the device planes' process spent compiling (util/device.py
+# watch_compiles): a chip run's cost before its first answer
+JAX_COMPILES = REGISTRY.counter(
+    "seaweedfs_tpu_jax_compiles_total",
+    "XLA backend compiles (persistent-cache loads included)",
+)
+JAX_COMPILE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_jax_compile_seconds_total",
+    "seconds in XLA backend compiles (persistent-cache loads included)",
+)
+JAX_COMPILE_CACHE = REGISTRY.counter(
+    "seaweedfs_tpu_jax_compile_cache_total",
+    "persistent compile cache lookups, by result (hit/miss)",
+)
 
 # degraded-mode visibility (see docs/robustness.md): every retry loop,
 # on-the-fly EC reconstruction and load-time torn-tail repair counts here,

@@ -1,4 +1,4 @@
-"""Test config (see repo-root conftest.py for the CPU re-exec)."""
+"""Test config: every test runs on the CPU, with 8 virtual devices for the mesh tests."""
 
 import os
 import sys

@@ -7,9 +7,37 @@ outgrew the window."""
 import importlib.util
 import json
 import os
+import signal
 import sys
+import threading
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+LEG_DEADLINE_S = 120
+
+
+@pytest.fixture(autouse=True)
+def _leg_deadline():
+    """A bench leg that never returns costs its own test, not the xdist
+    worker and every test queued behind it: `serving.open_loop` has been
+    seen waiting for ever in `asyncio.run` (seed tree included), which
+    held the driver's whole run to its time limit."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def on_alarm(signum, frame):
+        raise TimeoutError(f"bench leg still running after {LEG_DEADLINE_S} s")
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.alarm(LEG_DEADLINE_S)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
 
 
 def _load_bench():

@@ -85,39 +85,32 @@ def test_encode_rows_pointer_api_matches_stacked():
     assert np.array_equal(c.encode_rows(ro), oracle.encode(data))
 
 
-def test_adaptive_codec_falls_back_on_poisoned_device(monkeypatch):
+def test_adaptive_codec_raises_on_poisoned_device(monkeypatch):
+    """With a TPU attached, a failing device probe is an error to see —
+    never a silent switch to the host codec."""
     from seaweedfs_tpu.tpu import coder
+    from seaweedfs_tpu.util import device
 
     coder.reset_adaptive_cache()
-
-    class _Dev:
-        platform = "tpu"
 
     def boom(*a, **k):
         raise RuntimeError("device backend poisoned")
 
-    import jax
-
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    monkeypatch.setattr(device, "platform", lambda: "tpu")
     monkeypatch.setattr(coder, "probe_roundtrip_seconds", boom)
     try:
-        c = coder.adaptive_codec()
-        assert isinstance(c, CpuRSCodec)  # NativeRSCodec subclasses it
+        with pytest.raises(RuntimeError, match="poisoned"):
+            coder.adaptive_codec()
     finally:
         coder.reset_adaptive_cache()
 
 
 def test_adaptive_codec_cpu_platform_short_circuits(monkeypatch):
     from seaweedfs_tpu.tpu import coder
+    from seaweedfs_tpu.util import device
 
     coder.reset_adaptive_cache()
-
-    class _Dev:
-        platform = "cpu"
-
-    import jax
-
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    monkeypatch.setattr(device, "platform", lambda: "cpu")
 
     def no_probe(*a, **k):  # must not be consulted on the cpu platform
         raise AssertionError("probe should not run")
