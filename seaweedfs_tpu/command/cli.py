@@ -180,7 +180,9 @@ def _apply_config_defaults(
 
 def _build_volume_server(args, port_offset: int = 0):
     from ..server.volume import VolumeServer
+    from ..util.metrics import mark_startup
 
+    mark_startup("imports")
     _load_tier_config(getattr(args, "tierConfig", ""))
     dirs = args.dir.split(",")
     maxes = [int(m) for m in args.max.split(",")]
@@ -360,7 +362,9 @@ def cmd_server(argv: list[str]) -> int:
     args = p.parse_args(argv)
     from ..server.master import MasterServer
     from ..server.volume import VolumeServer
+    from ..util.metrics import mark_startup
 
+    mark_startup("imports")
     if args.tierConfig:
         import json
 

@@ -37,7 +37,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..util import trace
 from ..util.device import on_tpu
+from ..util.metrics import (
+    RS_DISPATCH_BYTES,
+    RS_DISPATCH_SECONDS,
+    RS_DISPATCHES,
+)
 
 # x^8 + x^4 + x^3 + x^2 + 1 (0x11D), matching the galois tables (galois.py).
 # 0x1D = bits 4,3,2,0 — the shift set in _xtime.
@@ -207,11 +213,21 @@ def gf_matmul_packed(
     jnp path takes any W.
     """
     matrix = np.asarray(matrix, dtype=np.uint8)
-    key = tuple(map(tuple, matrix))
     packed = jnp.asarray(packed, dtype=jnp.uint32)
     assert packed.shape[0] == matrix.shape[1], (packed.shape, matrix.shape)
-
     use_pallas = force_pallas if force_pallas is not None else on_tpu()
+    return _matmul_packed_on_device(
+        tuple(map(tuple, matrix)), packed, block_rows, use_pallas,
+        interpret, xtime_mode,
+    )
+
+
+def _matmul_packed_on_device(
+    key, packed, block_rows: int, use_pallas: bool, interpret: bool,
+    xtime_mode: str | None = None,
+):
+    """The dispatches of one call, as issued from the host, on words that
+    are already on the device: pad / reshape / kernel / reshape / slice."""
     w = packed.shape[1]
     if not use_pallas and not interpret:
         return _gf_matmul_jnp_packed(key, packed, xtime_mode)
@@ -225,12 +241,51 @@ def gf_matmul_packed(
     return out if out.shape[1] == w else out[:, :w]
 
 
+RS_OPS = ("encode", "decode", "apply")
+RS_STAGES = ("stack", "pack", "put", "dispatch", "fetch", "unpack")
+# the stages of a TpuRSCodec call, bound once: RS_STAGE[op][stage]. Leaves
+# of work, each an `rs.<stage>` event in a profiler trace; `rs.fetch`, the
+# blocking fetch of the device's answer, is the host's side of upload +
+# kernel + download and encloses the runtime's own `np.asarray(jax.Array)`
+RS_STAGE = {
+    op: {
+        st: trace.stage(
+            "rs." + st, RS_DISPATCH_SECONDS.child(op=op, stage=st), label=st
+        )
+        for st in RS_STAGES
+    }
+    for op in RS_OPS
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _rs_count_children(op: str, backend: str) -> tuple:
+    return (
+        RS_DISPATCHES.child(op=op, backend=backend),
+        RS_DISPATCH_BYTES.child(op=op, backend=backend, kind="real"),
+        RS_DISPATCH_BYTES.child(op=op, backend=backend, kind="padded"),
+    )
+
+
+def count_rs_dispatch(
+    op: str, backend: str, rows: int, width: int, padded_width: int
+) -> None:
+    """One codec call under `backend` (TpuRSCodec.pipeline_dispatch_kind's
+    vocabulary): `rows` = rows in + rows out, `width` the caller's bytes a
+    row, `padded_width` what the kernel's granule made of it."""
+    calls, real, padded = _rs_count_children(op, backend)
+    calls.inc()
+    real.inc(rows * width)
+    padded.inc(rows * (padded_width - width))
+
+
 def gf_matmul_bytes(
     matrix: np.ndarray,
     data,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     force_pallas: bool | None = None,
     interpret: bool = False,
+    op: str = "apply",
 ):
     """GF(2^8) matmul over flat byte rows: uint8[C, N] -> uint8[R, N].
 
@@ -241,17 +296,37 @@ def gf_matmul_bytes(
     ragged widths (degraded-read spans, stream tails) all land on a few
     compiled shapes instead of each compiling its own device-side pad and
     slice.
+
+    `op` (encode/decode/apply) labels the call's stages, dispatch and bytes
+    on /metrics (RS_STAGE, count_rs_dispatch).
     """
     matrix = np.asarray(matrix, dtype=np.uint8)
     assert data.shape[0] == matrix.shape[1], (data.shape, matrix.shape)
     n = data.shape[1]
     use_pallas = force_pallas if force_pallas is not None else on_tpu()
     granule = block_rows * LANE * 4 if use_pallas or interpret else 4
-    packed = pack_bytes_host(
-        np.asarray(data).astype(np.uint8, copy=False), granule
+    stages = RS_STAGE[op]
+    with stages["pack"]():
+        packed = pack_bytes_host(
+            np.asarray(data).astype(np.uint8, copy=False), granule
+        )
+    with stages["put"]():
+        on_device = jnp.asarray(packed, dtype=jnp.uint32)
+    with stages["dispatch"]():
+        out = _matmul_packed_on_device(
+            tuple(map(tuple, matrix)), on_device, block_rows, use_pallas,
+            interpret,
+        )
+    with stages["fetch"]():
+        out = np.asarray(out)
+    with stages["unpack"]():
+        out = unpack_bytes_host(out, n)
+    count_rs_dispatch(
+        op,
+        "device" if use_pallas and on_tpu() else "device_emulated",
+        matrix.shape[0] + matrix.shape[1], n, packed.shape[1] * 4,
     )
-    out = gf_matmul_packed(matrix, packed, block_rows, use_pallas, interpret)
-    return unpack_bytes_host(np.asarray(out), n)
+    return out
 
 
 # --- MXU bit-slice prototype (VERDICT r4 item 5) ---

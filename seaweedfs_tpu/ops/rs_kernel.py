@@ -21,7 +21,7 @@ from ..storage.erasure_coding.galois import (
     reconstruction_matrix,
 )
 from ..util.device import on_tpu
-from .gf256 import gf_matmul_bytes
+from .gf256 import RS_STAGE, count_rs_dispatch, gf_matmul_bytes
 
 
 class TpuRSCodec:
@@ -101,6 +101,12 @@ class TpuRSCodec:
         if standin is self:
             return self.encode(data)
         data = np.asarray(data)
+        # the stand-in has no sub-stages: its dispatches and bytes count
+        # under its own backend, and the pipeline's `kernel` stage times it
+        count_rs_dispatch(
+            "encode", "host_standin", self.total_shards, data.shape[1],
+            data.shape[1],
+        )
         if hasattr(standin, "encode_rows"):
             # row pointers: a narrow tail view (contiguous rows, strided
             # 2D) encodes without a compaction copy
@@ -109,18 +115,18 @@ class TpuRSCodec:
             )
         return standin.encode(np.ascontiguousarray(data, dtype=np.uint8))
 
-    def _apply(self, matrix: np.ndarray, data) -> np.ndarray:
-        out = gf_matmul_bytes(
+    def _apply(self, matrix: np.ndarray, data, op: str) -> np.ndarray:
+        return gf_matmul_bytes(
             matrix,
             data,
             force_pallas=self._force_pallas,
             interpret=self._interpret,
+            op=op,
         )
-        return np.asarray(out)
 
     def encode(self, data) -> np.ndarray:
         """uint8[k, N] -> parity uint8[m, N]."""
-        return self._apply(self.parity_matrix, data)
+        return self._apply(self.parity_matrix, data, "encode")
 
     def encode_all(self, data) -> np.ndarray:
         data_np = np.asarray(data, dtype=np.uint8)
@@ -150,7 +156,10 @@ class TpuRSCodec:
             return shards
 
         survivors = present[: self.data_shards]
-        sub = np.stack([np.asarray(shards[i], dtype=np.uint8) for i in survivors])
+        with RS_STAGE["decode"]["stack"]():
+            sub = np.stack(
+                [np.asarray(shards[i], dtype=np.uint8) for i in survivors]
+            )
 
         if missing_data or (missing_parity and not data_only):
             dec = reconstruction_matrix(self.matrix, survivors)
@@ -163,7 +172,7 @@ class TpuRSCodec:
                 par_rows = self.matrix[np.asarray(missing_parity)]
                 rows.append(mat_mul(par_rows, dec))
             m = np.concatenate(rows, axis=0)
-            recovered = self._apply(m, sub)
+            recovered = self._apply(m, sub, "decode")
             targets = missing_data + (missing_parity if not data_only else [])
             for out_row, i in enumerate(targets):
                 shards[i] = recovered[out_row]
@@ -172,7 +181,7 @@ class TpuRSCodec:
     def apply_matrix(self, m: np.ndarray, data) -> np.ndarray:
         """Public bulk GF(2^8) matmul on the device kernel (the primitive
         batched multi-volume rebuild dispatches through)."""
-        return self._apply(np.asarray(m, dtype=np.uint8), data)
+        return self._apply(np.asarray(m, dtype=np.uint8), data, "apply")
 
     def reconstruct_rows(
         self,
@@ -199,10 +208,11 @@ class TpuRSCodec:
         if need:
             survivors = present[: self.data_shards]
             rows = DECODE_ROWS_CACHE.rows_for(self.matrix, survivors, need)
-            sub = np.stack(
-                [np.asarray(shards[i], dtype=np.uint8) for i in survivors]
-            )
-            recovered = self._apply(rows, sub)
+            with RS_STAGE["decode"]["stack"]():
+                sub = np.stack(
+                    [np.asarray(shards[i], dtype=np.uint8) for i in survivors]
+                )
+            recovered = self._apply(rows, sub, "decode")
             if out is not None and len(need) == len(wanted):
                 out[:] = recovered  # device result lands in the recycled
                 recovered = out  # caller buffer (interface parity with CPU)

@@ -43,6 +43,7 @@ two-tier shape instead of re-wiring it by hand:
 
 from __future__ import annotations
 
+import asyncio
 import os
 import time
 from typing import Optional
@@ -56,7 +57,15 @@ from ..util.fasthttp import (
     FastHTTPServer,
     render_response,
 )
-from ..util.metrics import REQUEST_COUNTER
+from ..util.metrics import (
+    EVENT_LOOP_LAG_SECONDS,
+    EVENT_LOOP_LAG_TICKS,
+    REQUEST_COUNTER,
+    REQUEST_HISTOGRAM,
+    REQUEST_PROXIED,
+    REQUEST_PROXY_SECONDS,
+    REQUEST_WAIT_SECONDS,
+)
 
 # bound once: _dispatch pays these per request at serving QPS rates
 _perf = time.perf_counter
@@ -64,6 +73,57 @@ _coin = trace._rand.random
 _classify = overload.classify_method
 _set_tenant = tenancy.set_current
 _reset_tenant = tenancy.reset_current
+
+LOOP_LAG_PROBE_SECONDS = 0.010
+
+
+class LoopLagProbe:
+    """A callback on the serving loop, re-armed every 10 ms while requests
+    keep arriving, that adds how late it ran to
+    `event_loop_lag_seconds_total{server}` and counts its ticks: the one
+    reading of the time a request spends in the socket before the loop
+    parses it, which `req.t_arrive` cannot see. A request arms it
+    (`kick`); a tick that saw no request since the last one does not
+    re-arm, so an idle server has no timer (on the chip's host two cores
+    ticking an idle loop cost a conversion 3 % more CPU: PERF.md, PR 26).
+    It ends with the tier it measures (`alive`), whoever stops that."""
+
+    def __init__(self, server: str, alive):
+        self._seconds = EVENT_LOOP_LAG_SECONDS.child(server=server)
+        self._ticks = EVENT_LOOP_LAG_TICKS.child(server=server)
+        self._alive = alive
+        self._loop = None
+        self._handle = None
+        self._due = 0.0
+        self._kicked = False
+
+    def start(self, loop) -> None:
+        self._loop = loop
+
+    def kick(self) -> None:
+        self._kicked = True
+        if self._handle is None and self._loop is not None:
+            self._arm()
+
+    def _arm(self) -> None:
+        self._due = self._loop.time() + LOOP_LAG_PROBE_SECONDS
+        self._handle = self._loop.call_at(self._due, self._tick)
+
+    def _tick(self) -> None:
+        self._handle = None
+        if self._loop is None or not self._alive():
+            return
+        self._seconds.inc(max(0.0, self._loop.time() - self._due))
+        self._ticks.inc()
+        if self._kicked:
+            self._kicked = False
+            self._arm()
+
+    def stop(self) -> None:
+        self._loop = None
+        if self._handle is not None:
+            self._handle.cancel()
+            self._handle = None
 
 
 def _make_debug_middleware(name: str, address: str, pprof=None, ext=None):
@@ -238,7 +298,10 @@ class ServingCore:
         self.fast_server: Optional[FastHTTPServer] = None
         self._http_runner: Optional[web.AppRunner] = None
         self.internal_port: Optional[int] = None
-        self._req_counters: dict = {}
+        # per-method pre-bound children: (request_total, request_seconds,
+        # request_wait_seconds_total)
+        self._req_children: dict = {}
+        self._lag_probe = LoopLagProbe(name, self._serving)
         # overload control (ISSUE 9): priority admission + adaptive
         # concurrency limit in front of EVERY fast tier — None when
         # SEAWEEDFS_TPU_ADMIT=0. The shed answer is pre-rendered once:
@@ -266,11 +329,24 @@ class ServingCore:
         await site.start()
         self.internal_port = site._server.sockets[0].getsockname()[1]
         self.fast_server = FastHTTPServer(
-            self._dispatch, backend=("127.0.0.1", self.internal_port)
+            self._dispatch, backend=("127.0.0.1", self.internal_port),
+            proxy_stage=trace.stage(
+                "http.proxy",
+                REQUEST_PROXY_SECONDS.child(server=self.name),
+                REQUEST_PROXIED.child(server=self.name),
+                annotate=False,  # held across awaits: a counter only
+            ),
         )
+        self._lag_probe.start(asyncio.get_running_loop())
         await self.fast_server.start(self.host, self.port)
 
+    def _serving(self) -> bool:
+        # the volume and master servers stop the fast tier directly, not
+        # through stop(): the probe ends with it either way
+        return self.fast_server is not None and self.fast_server.serving
+
     async def stop(self) -> None:
+        self._lag_probe.stop()
         overload.drop_gate(self.gate)
         if self.fast_server is not None:
             await self.fast_server.stop()
@@ -291,16 +367,22 @@ class ServingCore:
         except (ImportError, AttributeError):
             pass  # private API: absent on other aiohttp versions
 
+    def _children(self, method: str) -> tuple:
+        bound = self._req_children.get(method)
+        if bound is None:
+            labels = {"server": self.name, "operation": method}
+            bound = self._req_children[method] = (
+                REQUEST_COUNTER.child(**labels),
+                REQUEST_HISTOGRAM.child(**labels),
+                REQUEST_WAIT_SECONDS.child(**labels),
+            )
+        return bound
+
     def count(self, method: str) -> None:
         """Count one served request; pre-bound children keep this O(1) on
         the hot path (DETACHED completions call this from their flush
         callback, so a proxied continuation is never double-counted)."""
-        child = self._req_counters.get(method)
-        if child is None:
-            child = self._req_counters[method] = REQUEST_COUNTER.child(
-                server=self.name, operation=method
-            )
-        child.inc()
+        self._children(method)[0].inc()
 
     async def _dispatch(self, req):
         """Fast-tier entry: trace join/head-sample, server-side fault
@@ -320,6 +402,7 @@ class ServingCore:
             # exempt from admission: the overloaded state must stay
             # observable WHILE it sheds.
             return FALLBACK
+        self._lag_probe.kick()
         gate = self.gate
         # tenant principal (ISSUE 12): derived BEFORE admission so the
         # gate's per-tenant subqueues and quotas order THIS request, and
@@ -354,8 +437,7 @@ class ServingCore:
         rec = trace.RECORDER
         sp = None
         enabled = rec.enabled
-        if enabled or gate is not None:
-            t0 = _perf()
+        t0 = _perf()
         if enabled:
             tp = req.headers.get(b"traceparent")
             pctx = (
@@ -400,15 +482,27 @@ class ServingCore:
         finally:
             if tok is not None:
                 _reset_tenant(tok)
+        # full fast-tier responses only, for the AIMD limiter and for
+        # request_seconds alike: FALLBACK walls are µs of proxy hand-off
+        # (the cold tier observes the replay) and DETACHED walls end at
+        # handler return — either would drag the latency signal (and
+        # thus the limit) toward fiction
+        full = out is not FALLBACK and out is not DETACHED
+        if out is not DETACHED:
+            _count, seconds, wait = self._children(req.method)
+            # the wait before service (loop backlog after parse +
+            # admission queue) is its own counter, beside request_seconds
+            # — which a FALLBACK gets from the cold tier it is replayed
+            # against (server/volume.py _dispatch), and a full response
+            # here: the service wall, on both tiers
+            wait.inc(t0 - req.t_arrive)
+            if full:
+                now = _perf()
+                seconds.observe(now - t0)
         if gate is not None:
-            # feed the AIMD limiter from full fast-tier responses only:
-            # FALLBACK walls are µs of proxy hand-off and DETACHED walls
-            # end at handler return — either would drag the latency
-            # signal (and thus the limit) toward fiction
-            if out is FALLBACK or out is DETACHED:
+            if not full:
                 gate.release(tenant=tenant)
             else:
-                now = _perf()
                 # service wall feeds the AIMD limit; wait+service feeds
                 # the admitted-latency histograms (per-server AND
                 # per-tenant), response bytes the tenant's byte quota
@@ -417,7 +511,7 @@ class ServingCore:
                     len(out) if type(out) is bytes else 0,
                 )
         if enabled:
-            if out is FALLBACK or out is DETACHED:
+            if not full:
                 # FALLBACK walls are µs of proxy hand-off (the real work
                 # happens on the cold-tier replay) and DETACHED walls end
                 # at handler return, not response write — feeding either
@@ -447,8 +541,8 @@ class ServingCore:
                     if sp.parent_id == 0:
                         rec.note_root(dt)
                     sp.finish()
-        if out is not FALLBACK and out is not DETACHED:
-            self.count(req.method)
+        if full:
+            _count.inc()
         return out
 
     async def _apply_fault(self, plan, req):

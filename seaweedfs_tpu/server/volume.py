@@ -52,6 +52,8 @@ from ..util.metrics import (
     READ_CACHE_MISSES,
     READ_STAGE_SECONDS,
     WRITE_STAGE_SECONDS,
+    mark_startup,
+    startup_line,
 )
 from .volume_ec import EcHandlers
 
@@ -362,6 +364,7 @@ class VolumeServer(EcHandlers):
             needle_map_kind=needle_map_kind,
         )
         self.store.load()
+        mark_startup("store_load")
         self._http_runner: Optional[web.AppRunner] = None
         self._grpc_server = None
         self._heartbeat_task: Optional[asyncio.Task] = None
@@ -405,6 +408,7 @@ class VolumeServer(EcHandlers):
                 device.require_chip(**asked)
             self.device = device.describe()
             device.watch_compiles()
+            mark_startup("device")
             logger.warning(
                 "volume server %s: device planes on platform=%s "
                 "device_kind=%r count=%d (storageBackend=%s batchLookup=%s)",
@@ -442,6 +446,7 @@ class VolumeServer(EcHandlers):
         self._stage_read_render = READ_STAGE_SECONDS.child(
             stage="read_render"
         )
+        mark_startup("index_build")
 
     def _group_committer(self, vid: int):
         gc = self._group_committers.get(vid)
@@ -540,6 +545,11 @@ class VolumeServer(EcHandlers):
         self._heartbeat_task = asyncio.ensure_future(self._heartbeat_loop())
         if self.scrub_mbps > 0:
             self._scrub_task = asyncio.ensure_future(self._scrub_loop())
+        mark_startup("listening")
+        logger.warning(
+            "volume server %s ready: seconds since process start %s",
+            self.address, startup_line(),
+        )
 
     async def stop(self) -> None:
         self._shutdown = True

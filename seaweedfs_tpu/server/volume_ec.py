@@ -28,13 +28,14 @@ from ..util.backoff import (
     remaining,
     shared_retry_budget,
 )
+from ..util import trace
 from ..util.metrics import (
     EC_DEGRADED_READ_SECONDS,
+    EC_DEGRADED_READ_STAGE_SECONDS,
     EC_ENCODE_BYTES,
     EC_RECONSTRUCTIONS,
     RETRY_COUNTER,
 )
-from ..storage.erasure_coding import encoder
 from ..storage.erasure_coding import (
     DATA_SHARDS_COUNT,
     TOTAL_SHARDS_COUNT,
@@ -60,6 +61,34 @@ from ..storage.volume_info import VolumeInfo, save_volume_info
 from ..types import TOMBSTONE_FILE_SIZE, to_actual_offset
 
 SHARD_LOCATION_TTL = 10.0  # seconds between LookupEcVolume refreshes
+
+
+def _read_stage(label: str, annotate: bool = True):
+    return trace.stage(
+        "ec.read." + label,
+        EC_DEGRADED_READ_STAGE_SECONDS.child(stage=label),
+        annotate=annotate,
+        label=label,
+    )
+
+
+# the stages of a cold degraded read (_recover_one_interval), bound once;
+# their count is ec_reconstructions_total{kind="cold"}. Counters only
+# where an await or a thread hand-off lets other requests run inside:
+# survivor_read is the wall of the gathers (its leaf, each synchronous
+# shard pread, is the `ec.read.pread` event), executor_wait runs from
+# run_in_executor to the worker's first line, decode is the worker's wall
+# around reconstruct_rows (its leaves are the codec's `rs.*` events)
+# before a reconstruct is tried at all: the location refreshes (forced
+# LookupEcVolume calls to the master, EC_REFRESH_ROUNDS of them) and the
+# remote holders asked for a shard this server does not hold — paid by
+# every degraded read, a hit in the interval cache included
+_ST_REMOTE_ATTEMPTS = _read_stage("remote_attempts", annotate=False)
+_ST_SURVIVOR_READ = _read_stage("survivor_read", annotate=False)
+_ST_PREAD = trace.stage("ec.read.pread")
+_ST_EXECUTOR_WAIT = _read_stage("executor_wait", annotate=False)
+_ST_DECODE = _read_stage("decode", annotate=False)
+_ST_CACHE_PUT = _read_stage("cache_put")
 
 # total wall-clock budget for one EC needle read, across every interval,
 # remote attempt, location refresh and reconstruction; each remote RPC gets
@@ -223,15 +252,15 @@ class EcHandlers:
                 except OSError:
                     dat_size = 0
                 await self._charge_maintenance(dat_size, plane=req["plane"])
-            def encode() -> str:
-                write_ec_files(base, codec=codec)
-                # which executor ran the kernel stage, read on the thread
-                # that just set it ("device" only when a TPU ran it)
-                return encoder.LAST_ROUTE.get("kernel", "host")
-
-            kernel = await loop.run_in_executor(None, encode)
+            run = await loop.run_in_executor(
+                None, lambda: write_ec_files(base, codec=codec)
+            )
+            # which executor ran the kernel stage, from THIS run's own
+            # route ("device" only when a TPU ran it): two encodes in
+            # flight never label each other's bytes
             EC_ENCODE_BYTES.inc(
-                os.path.getsize(base + ".dat"), backend=kernel
+                os.path.getsize(base + ".dat"),
+                backend=run.route.get("kernel", "host"),
             )
             await loop.run_in_executor(None, write_sorted_file_from_idx, base)
             v = self.store.find_volume(vid)
@@ -807,28 +836,29 @@ class EcHandlers:
             return data
         if deadline is None:
             deadline = deadline_after(EC_READ_DEADLINE_SECONDS)
-        await self._refresh_shard_locations(ev)
-        try:
-            data = await self._read_remote_shard_interval(
-                ev, shard_id, offset, size, file_key, deadline
-            )
-            if data is not None:
-                return data
-            # the cached locations may be stale (ref store_ec.go:211
-            # forgets failed shard locations); force-refresh and retry in
-            # bounded rounds while the deadline allows
-            for _ in range(EC_REFRESH_ROUNDS):
-                if time.monotonic() >= deadline:
-                    break
-                RETRY_COUNTER.inc(op="ec_location_refresh")
-                await self._refresh_shard_locations(ev, force=True)
+        with _ST_REMOTE_ATTEMPTS():
+            await self._refresh_shard_locations(ev)
+            try:
                 data = await self._read_remote_shard_interval(
                     ev, shard_id, offset, size, file_key, deadline
                 )
                 if data is not None:
                     return data
-        except EcHandlers._Deleted:
-            return None
+                # the cached locations may be stale (ref store_ec.go:211
+                # forgets failed shard locations); force-refresh and retry
+                # in bounded rounds while the deadline allows
+                for _ in range(EC_REFRESH_ROUNDS):
+                    if time.monotonic() >= deadline:
+                        break
+                    RETRY_COUNTER.inc(op="ec_location_refresh")
+                    await self._refresh_shard_locations(ev, force=True)
+                    data = await self._read_remote_shard_interval(
+                        ev, shard_id, offset, size, file_key, deadline
+                    )
+                    if data is not None:
+                        return data
+            except EcHandlers._Deleted:
+                return None
         # degraded: reconstruct from any DATA_SHARDS_COUNT other shards
         # (ref store_ec.go:319-373)
         return await self._recover_one_interval(
@@ -1059,7 +1089,8 @@ class EcHandlers:
         async def fetch(shard_id: int) -> None:
             shard = ev.find_shard(shard_id)
             if shard is not None:
-                b = shard.read_at(span_size, span_start)
+                with _ST_PREAD():
+                    b = shard.read_at(span_size, span_start)
             elif ev.remote_shard(shard_id) is not None:
                 # cold tier: an offloaded survivor feeds reconstruction
                 # through the read-through cache (one ranged remote GET)
@@ -1085,11 +1116,12 @@ class EcHandlers:
         # widening to the rest only on a shortfall
         needed = max(0, ev.data_shards - len(local))
         first = remote[: needed + 1] if needed else []
-        await asyncio.gather(*(fetch(i) for i in local + first))
-        if sum(1 for b in bufs if b is not None) < ev.data_shards:
-            rest = [i for i in remote if i not in first]
-            if rest:
-                await asyncio.gather(*(fetch(i) for i in rest))
+        with _ST_SURVIVOR_READ():
+            await asyncio.gather(*(fetch(i) for i in local + first))
+            if sum(1 for b in bufs if b is not None) < ev.data_shards:
+                rest = [i for i in remote if i not in first]
+                if rest:
+                    await asyncio.gather(*(fetch(i) for i in rest))
         present = [i for i in range(total) if bufs[i] is not None]
         if len(present) < ev.data_shards:
             return None
@@ -1099,15 +1131,20 @@ class EcHandlers:
         ]
         codec = self.codec_for(ev.data_shards, ev.parity_shards)
         loop = asyncio.get_event_loop()
-        rows = await loop.run_in_executor(
-            None,
-            lambda: codec.reconstruct_rows(trimmed, [missing_shard]),
-        )
+        t_submit = time.perf_counter()
+
+        def decode() -> list:
+            _ST_EXECUTOR_WAIT.since(t_submit)
+            with _ST_DECODE():
+                return codec.reconstruct_rows(trimmed, [missing_shard])
+
+        rows = await loop.run_in_executor(None, decode)
         out = rows[0]
         if out is None:
             return None
-        span = np.ascontiguousarray(out).tobytes()
-        cache.put(ev.volume_id, missing_shard, span_start, span)
+        with _ST_CACHE_PUT():
+            span = np.ascontiguousarray(out).tobytes()
+            cache.put(ev.volume_id, missing_shard, span_start, span)
         EC_RECONSTRUCTIONS.inc(kind="cold")
         EC_DEGRADED_READ_SECONDS.observe(
             time.perf_counter() - t_start, result="cold"

@@ -33,6 +33,8 @@ from . import (
     to_ext,
 )
 from ...types import TOMBSTONE_FILE_SIZE, to_actual_offset
+from ...util import trace
+from ...util.metrics import EC_ENCODE_STAGE_CALLS, EC_ENCODE_STAGE_SECONDS
 from ..idx import iter_index, entry_to_bytes
 from ..needle import get_actual_size
 from ..needle_map import MemDb
@@ -40,21 +42,86 @@ from ..super_block import SuperBlock
 
 DEFAULT_CHUNK = 4 * 1024 * 1024  # per-shard streaming chunk
 
-# set by write_ec_files after each run: {"route": ..., "spliced": bool} —
-# benchmark/diagnostic introspection, not part of the encode contract
+# the last write_ec_files run's route, {"route": ..., "spliced": bool}:
+# benchmark/diagnostic introspection, not part of the encode contract.
+# Assigned once when a run ends, from the run's own EncodeRun (which
+# write_ec_files returns: concurrent runs read their own, not this)
 LAST_ROUTE: dict = {}
 
-# per-stage wall seconds of the last write_ec_files run. The synchronous
-# routes fill read_s / kernel_s / shard_write_s (or fused/splice where
-# stages aren't separable). The STREAMED pipeline route fills the
-# five-stage budget read_s / stage_s / kernel_s / write_s / sync_s plus
-# pipeline_depth and coverage_of_wall: read/stage/sync are main-thread
-# walls that PARTITION the run (their sum over total_s is the disclosed
-# coverage), while kernel_s (pool) and write_s (writer thread) are
-# overlapped walls whose ratio to total_s discloses overlap efficiency.
-# Not synchronized across concurrent write_ec_files_multi volumes.
+# per-stage wall seconds of the last write_ec_files run, assigned once
+# when it ends from its EncodeRun. The synchronous routes fill read_s /
+# kernel_s / shard_write_s (or fused/splice where stages aren't
+# separable). The STREAMED pipeline route fills read_s / stage_s
+# (= slot_wait_s + submit_s) / kernel_s / write_s / sync_s plus
+# parity_wait_s, pipeline_depth and coverage_of_wall: read/stage/sync are
+# main-thread walls that PARTITION the run (their sum over total_s is the
+# disclosed coverage), while kernel_s (pool) and write_s / parity_wait_s
+# (writer thread) are overlapped walls whose ratio to total_s discloses
+# overlap efficiency.
 LAST_STAGES: dict = {}
-_STAGE_LOCK = threading.Lock()
+
+
+class EncodeRun:
+    """What one write_ec_files run took and which way it went: the sink
+    of the run's stages (util/trace.Stage), added to from the main, pool
+    and writer threads of THIS run only."""
+
+    def __init__(self):
+        self.route: dict = {}
+        # what is no stage's wall: total_s, pipeline_depth, ...
+        self.extra: dict = {}
+        self._seconds: dict = {}
+        self._lock = threading.Lock()
+
+    def add(self, label: str, dt: float) -> None:
+        with self._lock:
+            self._seconds[label] = self._seconds.get(label, 0.0) + dt
+
+    def seconds(self, *labels: str) -> float:
+        with self._lock:
+            return sum(self._seconds.get(k, 0.0) for k in labels)
+
+    def stages(self) -> dict:
+        """The run's budget under LAST_STAGES' keys."""
+        with self._lock:
+            out = {k + "_s": v for k, v in self._seconds.items()}
+        if "slot_wait_s" in out or "submit_s" in out:
+            out["stage_s"] = out.get("slot_wait_s", 0.0) + out.get(
+                "submit_s", 0.0
+            )
+        if self.route.get("route") != "pipeline" and "write_s" in out:
+            out["shard_write_s"] = out.pop("write_s")
+        out.update(self.extra)
+        return out
+
+
+def _encode_stage(label: str, annotate: bool = True):
+    return trace.stage(
+        "ec.encode." + label,
+        EC_ENCODE_STAGE_SECONDS.child(stage=label),
+        EC_ENCODE_STAGE_CALLS.child(stage=label),
+        annotate=annotate,
+        label=label,
+    )
+
+
+# the encode plane's stages, bound once. Work at the leaves is annotated
+# (an `ec.encode.<stage>` event in a profiler trace); waits are counters
+# only; `kernel` has no event of its own: its leaves are the codec's
+# `rs.*` stages. `sync` is the final flush + close + rename; the drain
+# before it (the writer's join) is a wait that adds to the same counter.
+_ST_SPLICE = _encode_stage("splice")
+_ST_READ = _encode_stage("read")
+_ST_SLOT_WAIT = _encode_stage("slot_wait", annotate=False)
+_ST_SUBMIT = _encode_stage("submit", annotate=False)
+_ST_KERNEL = _encode_stage("kernel", annotate=False)
+_ST_PARITY_WAIT = _encode_stage("parity_wait", annotate=False)
+_ST_WRITE = _encode_stage("write")
+_ST_SYNC = _encode_stage("sync")
+_ST_SYNC_DRAIN = trace.stage(
+    "ec.encode.sync_drain", EC_ENCODE_STAGE_SECONDS.child(stage="sync"),
+    annotate=False, label="sync",
+)
 
 # per-stage wall seconds of the last rebuild_ec_files run (read_s /
 # decode_s / write_s / total_s) — the repair-plane mirror of LAST_STAGES.
@@ -69,17 +136,6 @@ _REBUILD_STAGE_LOCK = threading.Lock()
 # survivor maps / "pread" buffered reads, pipelined or not) — the repair
 # mirror of LAST_ROUTE
 LAST_REBUILD_ROUTE: dict = {}
-
-
-def _stage_add(key: str, dt: float) -> None:
-    LAST_STAGES[key] = LAST_STAGES.get(key, 0.0) + dt
-
-
-def _stage_add_locked(key: str, dt: float) -> None:
-    # the streamed pipeline adds kernel_s/write_s from pool and writer
-    # threads concurrently with the main thread's read_s/stage_s: lock
-    with _STAGE_LOCK:
-        LAST_STAGES[key] = LAST_STAGES.get(key, 0.0) + dt
 
 
 def _env_int(name: str, default: int) -> int:
@@ -164,6 +220,7 @@ def _read_exact(f, out: np.ndarray, offset: int) -> None:
 
 
 def _encode_rows(
+    run: EncodeRun,
     dat_f,
     outputs,
     codec,
@@ -172,8 +229,6 @@ def _encode_rows(
     rows: int,
     chunk: int,
 ) -> None:
-    import time as _time
-
     k = codec.data_shards
     data = np.empty((k, chunk), dtype=np.uint8)
     for row in range(rows):
@@ -182,28 +237,27 @@ def _encode_rows(
         while done < block_size:
             this = min(chunk, block_size - done)
             buf = data[:, :this] if this != chunk else data
-            t0 = _time.perf_counter()
-            for i in range(k):
-                _read_into(dat_f, buf[i], row_start + i * block_size + done)
-            t1 = _time.perf_counter()
-            parity = codec.encode(buf)
-            t2 = _time.perf_counter()
+            with _ST_READ(run):
+                for i in range(k):
+                    _read_into(
+                        dat_f, buf[i], row_start + i * block_size + done
+                    )
+            with _ST_KERNEL(run):
+                parity = codec.encode(buf)
             # contiguous-row memoryviews: BufferedWriter copies synchronously,
             # so reusing `data` next iteration is safe and we skip a tobytes()
             # copy of every byte written
-            for i in range(k):
-                if outputs[i] is not None:
-                    outputs[i].write(buf[i].data)
-            for p in range(codec.parity_shards):
-                outputs[k + p].write(np.ascontiguousarray(parity[p]).data)
-            t3 = _time.perf_counter()
-            _stage_add("read_s", t1 - t0)
-            _stage_add("kernel_s", t2 - t1)
-            _stage_add("shard_write_s", t3 - t2)
+            with _ST_WRITE(run):
+                for i in range(k):
+                    if outputs[i] is not None:
+                        outputs[i].write(buf[i].data)
+                for p in range(codec.parity_shards):
+                    outputs[k + p].write(np.ascontiguousarray(parity[p]).data)
             done += this
 
 
 def _encode_rows_mmap(
+    run: EncodeRun,
     arr: np.ndarray,
     outputs,
     codec,
@@ -218,8 +272,6 @@ def _encode_rows_mmap(
     stream straight from the map. Only EOF-straddling tails get copied into
     a scratch row. The single-core replacement for the reference's
     read-copy-everything loop (ref ec_encoder.go:120-136)."""
-    import time as _time
-
     k = codec.data_shards
     dat_size = arr.size
     scratch = np.empty((k, chunk), dtype=np.uint8)
@@ -229,37 +281,33 @@ def _encode_rows_mmap(
         done = 0
         while done < block_size:
             this = min(chunk, block_size - done)
-            t0 = _time.perf_counter()
-            rows_v = []
-            for i in range(k):
-                off = row_start + i * block_size + done
-                end = off + this
-                if off >= dat_size:
-                    rows_v.append(zeros[:this])
-                elif end <= dat_size:
-                    rows_v.append(arr[off:end])
-                else:
-                    s = scratch[i, :this]
-                    n = dat_size - off
-                    s[:n] = arr[off:dat_size]
-                    s[n:] = 0
-                    rows_v.append(s)
-            t1 = _time.perf_counter()
-            parity = np.ascontiguousarray(codec.encode_rows(rows_v))
-            t2 = _time.perf_counter()
-            for i in range(k):
-                if outputs[i] is not None:
-                    outputs[i].write(rows_v[i].data)
-            for p in range(codec.parity_shards):
-                outputs[k + p].write(parity[p].data)
-            t3 = _time.perf_counter()
             # on this mmapped route the .dat "read" is page faults taken
-            # INSIDE kernel_s (encode touches the map) and shard_write_s
-            # (data shards stream from the map); read_s only covers the
-            # view assembly + EOF-tail copies
-            _stage_add("read_s", t1 - t0)
-            _stage_add("kernel_s", t2 - t1)
-            _stage_add("shard_write_s", t3 - t2)
+            # INSIDE the kernel stage (encode touches the map) and the
+            # write stage (data shards stream from the map); the read
+            # stage only covers the view assembly + EOF-tail copies
+            with _ST_READ(run):
+                rows_v = []
+                for i in range(k):
+                    off = row_start + i * block_size + done
+                    end = off + this
+                    if off >= dat_size:
+                        rows_v.append(zeros[:this])
+                    elif end <= dat_size:
+                        rows_v.append(arr[off:end])
+                    else:
+                        s = scratch[i, :this]
+                        n = dat_size - off
+                        s[:n] = arr[off:dat_size]
+                        s[n:] = 0
+                        rows_v.append(s)
+            with _ST_KERNEL(run):
+                parity = np.ascontiguousarray(codec.encode_rows(rows_v))
+            with _ST_WRITE(run):
+                for i in range(k):
+                    if outputs[i] is not None:
+                        outputs[i].write(rows_v[i].data)
+                for p in range(codec.parity_shards):
+                    outputs[k + p].write(parity[p].data)
             done += this
 
 
@@ -301,6 +349,7 @@ def _stream_items(
 
 
 def _encode_streamed(
+    run: EncodeRun,
     base_file_name: str,
     dat_f,
     codec,
@@ -339,12 +388,14 @@ def _encode_streamed(
     only .tmp files for the next run's sweep, never a torn shard
     masquerading as complete.
 
-    Per-stage walls land in LAST_STAGES: read_s (main-thread preadv, or
-    view construction + readahead on the mmap route), stage_s (ring
-    backpressure: free-slot waits + pad/submit/handoff), sync_s (final
-    drain + flush + rename) partition the main-thread wall — their sum
-    over total_s is the disclosed coverage_of_wall; kernel_s (pool) and
-    write_s (writer) are the overlapped walls. Returns (spliced, input)
+    Per-stage walls land in `run` (and on /metrics, and as events in a
+    profiler trace: the stages bound at the top of this module): read
+    (main-thread preadv, or view construction + readahead on the mmap
+    route), slot_wait (ring backpressure: the wait for a free slot),
+    submit (set-up, pad, pool.submit, hand-off), sync (final drain +
+    flush + rename) partition the main-thread wall — their sum over
+    total_s is the disclosed coverage_of_wall; kernel (pool), parity_wait
+    and write (writer) are the overlapped walls. Returns (spliced, input)
     where `input` is the route that fed the ring ("mmap" or "pread")."""
     import concurrent.futures as cf
     import mmap as mmap_mod
@@ -359,14 +410,12 @@ def _encode_streamed(
 
     spliced = False
     if splice_data is None or splice_data:
-        t0 = _time.perf_counter()
-        spliced = _splice_data_shards(
-            dat_path, base_file_name, k,
-            n_large, large_block, n_small, small_block,
-            suffix=".tmp",
-        )
-        if spliced:
-            _stage_add("splice_s", _time.perf_counter() - t0)
+        with _ST_SPLICE(run):
+            spliced = _splice_data_shards(
+                dat_path, base_file_name, k,
+                n_large, large_block, n_small, small_block,
+                suffix=".tmp",
+            )
 
     t_setup = _time.perf_counter()
     try:
@@ -431,10 +480,8 @@ def _encode_streamed(
     err: list = [None]
 
     def run_kernel(view: np.ndarray) -> np.ndarray:
-        t0 = _time.perf_counter()
-        out = np.asarray(dispatch(view))
-        _stage_add_locked("kernel_s", _time.perf_counter() - t0)
-        return out
+        with _ST_KERNEL(run):
+            return np.asarray(dispatch(view))
 
     def writer() -> None:
         while True:
@@ -443,14 +490,14 @@ def _encode_streamed(
                 return
             buf, used, fut, slot = entry
             try:
-                parity = fut.result()
-                t0 = _time.perf_counter()
-                for i in range(k):
-                    if outputs[i] is not None:
-                        outputs[i].write(buf[i, :used].data)
-                for p in range(m):
-                    outputs[k + p].write(parity[p, :used].data)
-                _stage_add_locked("write_s", _time.perf_counter() - t0)
+                with _ST_PARITY_WAIT(run):
+                    parity = fut.result()
+                with _ST_WRITE(run):
+                    for i in range(k):
+                        if outputs[i] is not None:
+                            outputs[i].write(buf[i, :used].data)
+                    for p in range(m):
+                        outputs[k + p].write(parity[p, :used].data)
             except BaseException as e:  # keep consuming: the main thread
                 # must never deadlock on a dead writer's unreturned slots
                 if err[0] is None:
@@ -465,72 +512,75 @@ def _encode_streamed(
     try:
         with cf.ThreadPoolExecutor(depth) as pool:
             writer_t.start()
-            # pool/writer/ring setup charges to stage_s: the coverage
+            # pool/writer/ring setup charges to submit: the coverage
             # partition must account for every main-thread second
-            _stage_add_locked("stage_s", _time.perf_counter() - t_setup)
+            _ST_SUBMIT.since(t_setup, run)
             prefetch(0)
             for idx, (start, block, done, width, g) in enumerate(items):
                 if err[0] is not None:
                     break
-                t0 = _time.perf_counter()
-                slot = freeq.get()
-                t1 = _time.perf_counter()
-                _stage_add_locked("stage_s", t1 - t0)
+                with _ST_SLOT_WAIT(run):
+                    slot = freeq.get()
                 used = width * g
                 first = start + done
                 view = None
-                if (
-                    mm_arr is not None
-                    and g == 1
-                    and first + (k - 1) * block + width <= dat_size
-                ):
-                    view = np.lib.stride_tricks.as_strided(
-                        mm_arr[first:], shape=(k, width),
-                        strides=(block, 1), writeable=False,
-                    )
-                    buf = view
-                else:
-                    if slot is None:
-                        slot = np.empty(
-                            (k, max(full_width, 1)), dtype=np.uint8
+                with _ST_READ(run):
+                    if (
+                        mm_arr is not None
+                        and g == 1
+                        and first + (k - 1) * block + width <= dat_size
+                    ):
+                        view = np.lib.stride_tricks.as_strided(
+                            mm_arr[first:], shape=(k, width),
+                            strides=(block, 1), writeable=False,
                         )
-                    for gi in range(g):
-                        row_start = start + gi * block * k
-                        sl = slice(gi * width, gi * width + width)
-                        for i in range(k):
-                            _read_into(
-                                dat_f, slot[i, sl],
-                                row_start + i * block + done,
-                            )
-                    buf = slot
-                prefetch(idx + 1)
-                t2 = _time.perf_counter()
-                _stage_add_locked("read_s", t2 - t1)
-                if view is None:
-                    if used < full_width and pad_tail:
-                        slot[:, used:] = 0
-                        kview = slot
+                        buf = view
                     else:
-                        kview = slot if used == full_width else slot[:, :used]
-                else:
-                    kview = view
-                outq.put((buf, used, pool.submit(run_kernel, kview), slot))
-                _stage_add_locked("stage_s", _time.perf_counter() - t2)
-            t0 = _time.perf_counter()
-            outq.put(None)
-            writer_t.join()
+                        if slot is None:
+                            slot = np.empty(
+                                (k, max(full_width, 1)), dtype=np.uint8
+                            )
+                        for gi in range(g):
+                            row_start = start + gi * block * k
+                            sl = slice(gi * width, gi * width + width)
+                            for i in range(k):
+                                _read_into(
+                                    dat_f, slot[i, sl],
+                                    row_start + i * block + done,
+                                )
+                        buf = slot
+                    prefetch(idx + 1)
+                with _ST_SUBMIT(run):
+                    if view is None:
+                        if used < full_width and pad_tail:
+                            slot[:, used:] = 0
+                            kview = slot
+                        else:
+                            kview = (
+                                slot if used == full_width
+                                else slot[:, :used]
+                            )
+                    else:
+                        kview = view
+                    outq.put(
+                        (buf, used, pool.submit(run_kernel, kview), slot)
+                    )
+            with _ST_SYNC_DRAIN(run):
+                outq.put(None)
+                writer_t.join()
         if err[0] is not None:
             raise err[0]
-        for f in outputs:
-            if f is not None:
-                f.flush()
-                f.close()
-        for i in range(total):
-            os.replace(
-                base_file_name + to_ext(i) + ".tmp", base_file_name + to_ext(i)
-            )
+        with _ST_SYNC(run):
+            for f in outputs:
+                if f is not None:
+                    f.flush()
+                    f.close()
+            for i in range(total):
+                os.replace(
+                    base_file_name + to_ext(i) + ".tmp",
+                    base_file_name + to_ext(i),
+                )
         ok = True
-        _stage_add_locked("sync_s", _time.perf_counter() - t0)
     finally:
         if not ok:
             if writer_t.is_alive():
@@ -928,14 +978,14 @@ def write_ec_files(
     splice_data: Optional[bool] = None,
     mmap_input: Optional[bool] = None,
     onepass: Optional[bool] = None,
-) -> None:
+) -> EncodeRun:
     """Generate .ec00-.ec13 from .dat (ref WriteEcFiles, ec_encoder.go:57).
 
     pipeline=None follows the codec's preference: the TPU codec takes the
     streamed depth-N double-buffered route (_encode_streamed: bounded ring
     of reused staging buffers, overlapped read/kernel/write, in-order
-    .ecNN.tmp outputs renamed on success, five-stage wall budget in
-    LAST_STAGES); the CPU codec keeps the reference's synchronous
+    .ecNN.tmp outputs renamed on success, stage budget in the returned
+    run); the CPU codec keeps the reference's synchronous
     structure. The streamed route's chunk and depth are env-tunable:
     SEAWEEDFS_TPU_EC_PIPELINE_CHUNK (bytes, default codec.preferred_chunk)
     and SEAWEEDFS_TPU_EC_PIPELINE_DEPTH (default codec.pipeline_workers).
@@ -955,9 +1005,34 @@ def write_ec_files(
     (onepass vs mmap vs pread) is picked by a one-time measured race on
     this host (_calibrate_host_route) — the ranking is
     hardware-dependent and point probes proved unreliable.
+
+    Returns the run's own EncodeRun (route taken, stage budget); the
+    module's LAST_ROUTE / LAST_STAGES are assigned from it once, at the end.
     """
-    global LAST_ROUTE
-    LAST_STAGES.clear()
+    global LAST_ROUTE, LAST_STAGES
+    run = EncodeRun()
+    try:
+        _write_ec_files(
+            run, base_file_name, codec, large_block_size, small_block_size,
+            chunk, pipeline, splice_data, mmap_input, onepass,
+        )
+    finally:
+        LAST_ROUTE, LAST_STAGES = run.route, run.stages()
+    return run
+
+
+def _write_ec_files(
+    run: EncodeRun,
+    base_file_name: str,
+    codec,
+    large_block_size: int,
+    small_block_size: int,
+    chunk: int,
+    pipeline: Optional[bool],
+    splice_data: Optional[bool],
+    mmap_input: Optional[bool],
+    onepass: Optional[bool],
+) -> None:
     import time as _time
 
     _t_enter = _time.perf_counter()
@@ -980,7 +1055,7 @@ def write_ec_files(
         if cal > 1e-3:
             # first call per codec runs a measured race; disclose it so
             # the stage sums still reconcile with total_s
-            LAST_STAGES["calibrate_s"] = round(cal, 3)
+            run.extra["calibrate_s"] = round(cal, 3)
     if onepass is None:
         onepass = route == "onepass"
     if mmap_input is None:
@@ -1013,11 +1088,11 @@ def write_ec_files(
         try:
             with open(dat_path, "rb") as dat_f:
                 spliced, input_kind = _encode_streamed(
-                    base_file_name, dat_f, codec,
+                    run, base_file_name, dat_f, codec,
                     n_large, large_block_size, n_small, small_block_size,
                     chunk, depth, splice_data, dat_path,
                 )
-            LAST_ROUTE = {
+            run.route = {
                 "route": "pipeline",
                 "spliced": spliced,
                 "input": input_kind,
@@ -1026,20 +1101,18 @@ def write_ec_files(
             }
         finally:
             total = _time.perf_counter() - _t_enter
-            LAST_STAGES["total_s"] = total
-            LAST_STAGES["pipeline_depth"] = depth
+            run.extra["total_s"] = total
+            run.extra["pipeline_depth"] = depth
             # coverage = the main-thread (blocking) stages over the wall:
-            # kernel_s/write_s are overlapped walls and deliberately NOT
+            # kernel/write are overlapped walls and deliberately NOT
             # summed here — the PR 2 write-budget disclosure discipline
-            blocking = sum(
-                LAST_STAGES.get(s, 0.0)
-                for s in ("read_s", "stage_s", "sync_s", "splice_s",
-                          "calibrate_s")
-            )
-            LAST_STAGES["coverage_of_wall"] = round(
+            blocking = run.seconds(
+                "read", "slot_wait", "submit", "sync", "splice"
+            ) + run.extra.get("calibrate_s", 0.0)
+            run.extra["coverage_of_wall"] = round(
                 blocking / max(total, 1e-9), 3
             )
-            LAST_STAGES.setdefault("ecx_s", 0.0)
+            run.extra["ecx_s"] = 0.0
         return
 
     if onepass and dat_size > 0:
@@ -1048,28 +1121,25 @@ def write_ec_files(
             n_large, large_block_size, n_small, small_block_size,
             chunk=chunk,
         ):
-            LAST_ROUTE = {"route": "onepass", "spliced": False}
+            run.route = {"route": "onepass", "spliced": False}
             # the fused native kernel interleaves read/encode/write in one
             # sweep: stages aren't separable, disclose the fused total
-            LAST_STAGES["fused_s"] = _time.perf_counter() - _t_enter
-            LAST_STAGES["total_s"] = LAST_STAGES["fused_s"]
-            LAST_STAGES["ecx_s"] = 0.0
+            fused = _time.perf_counter() - _t_enter
+            run.extra.update(fused_s=fused, total_s=fused, ecx_s=0.0)
             return
 
     spliced = False
     if splice_data is None or splice_data:
-        _t_sp = _time.perf_counter()
-        spliced = _splice_data_shards(
-            dat_path, base_file_name, k,
-            n_large, large_block_size, n_small, small_block_size,
-        )
-        if spliced:
-            # data shards were carved kernel-side (copy_file_range/pwrite
-            # interleave): read+write of the data shards in one stage
-            LAST_STAGES["splice_s"] = _time.perf_counter() - _t_sp
+        # data shards carved kernel-side (copy_file_range/pwrite
+        # interleave): read+write of the data shards in one stage
+        with _ST_SPLICE(run):
+            spliced = _splice_data_shards(
+                dat_path, base_file_name, k,
+                n_large, large_block_size, n_small, small_block_size,
+            )
     # introspection for benchmarks/diagnostics: which structure actually
     # ran (the roofline model differs when data shards were spliced)
-    LAST_ROUTE = {
+    run.route = {
         "route": "mmap" if use_mmap else "pread",
         "spliced": spliced,
     }
@@ -1092,11 +1162,11 @@ def write_ec_files(
                     )
                     arr = np.frombuffer(mm, dtype=np.uint8)
                     _encode_rows_mmap(
-                        arr, outputs, codec, 0,
+                        run, arr, outputs, codec, 0,
                         large_block_size, n_large, chunk,
                     )
                     _encode_rows_mmap(
-                        arr, outputs, codec, n_large * large_row,
+                        run, arr, outputs, codec, n_large * large_row,
                         small_block_size, n_small, small_chunk,
                     )
                 finally:
@@ -1106,22 +1176,23 @@ def write_ec_files(
                         mm.close()
             else:
                 _encode_rows(
-                    dat_f, outputs, codec, 0, large_block_size, n_large, chunk
+                    run, dat_f, outputs, codec, 0,
+                    large_block_size, n_large, chunk,
                 )
                 _encode_rows(
-                    dat_f, outputs, codec, n_large * large_row,
+                    run, dat_f, outputs, codec, n_large * large_row,
                     small_block_size, n_small, small_chunk,
                 )
     finally:
         for f in outputs:
             if f is not None:
                 f.close()
-        LAST_STAGES["total_s"] = _time.perf_counter() - _t_enter
+        run.extra["total_s"] = _time.perf_counter() - _t_enter
         # .ecx is NOT written here: write_ec_files produces .ec00-.ec13
         # only (the sorted .ecx index comes from write_sorted_file_from_idx
         # during volume->EC conversion) — stated so the stage breakdown
         # can't be misread as omitting it
-        LAST_STAGES.setdefault("ecx_s", 0.0)
+        run.extra["ecx_s"] = 0.0
 
 
 def _row_counts(

@@ -562,9 +562,10 @@ class FastHTTPProtocol(asyncio.Protocol):
                 self.transport.close()
 
     async def _proxy(self, req: FastRequest) -> bool:
-        resp, has_len = await proxy_request(
-            self.server.backend, req, transport=self.transport
-        )
+        with self.server.proxy_stage():
+            resp, has_len = await proxy_request(
+                self.server.backend, req, transport=self.transport
+            )
         if resp:
             self.transport.write(resp)
         if not has_len:
@@ -755,9 +756,14 @@ class FastHTTPServer:
     """Owns the public listening socket; `handler` is the fast tier,
     `backend` (host, port) the full aiohttp app for everything else."""
 
-    def __init__(self, handler: Handler, backend=None):
+    def __init__(self, handler: Handler, backend=None, proxy_stage=None):
         self.handler = handler
         self.backend = backend
+        # times the replay of a FALLBACK against `backend` (a
+        # util/trace.Stage of the owning ServingCore; a wait, so a counter)
+        self.proxy_stage = proxy_stage or trace.stage(
+            "http.proxy", annotate=False
+        )
         self._server: Optional[asyncio.AbstractServer] = None
         self._conns: set = set()
         self._detached_tasks: set = set()  # strong refs (loop holds weak)
@@ -767,6 +773,10 @@ class FastHTTPServer:
         self._server = await loop.create_server(
             lambda: FastHTTPProtocol(self), host, port, reuse_address=True
         )
+
+    @property
+    def serving(self) -> bool:
+        return self._server is not None and self._server.is_serving()
 
     async def stop(self):
         if self._server is not None:

@@ -15,6 +15,7 @@ Prometheus scraper would reject the whole exposition.
 
 from __future__ import annotations
 
+import os
 import threading
 import time as _time
 from bisect import bisect_left
@@ -348,8 +349,50 @@ REQUEST_COUNTER = REGISTRY.counter(
 REQUEST_HISTOGRAM = REGISTRY.histogram(
     "seaweedfs_tpu_request_seconds", "request latency by server/operation"
 )
-VOLUME_GAUGE = REGISTRY.gauge(
-    "seaweedfs_tpu_volumes", "volumes/ec-shards served per collection"
+# time a request spent between parse completion and the start of service
+# on a fast tier (loop backlog + admission queue): beside request_seconds
+# (the service wall) it splits a server-side latency into wait and work.
+# Counted for full fast-tier responses and for FALLBACKs — on the volume
+# server each of those is observed once in request_seconds, by the fast
+# tier or by the aiohttp _dispatch it is replayed against
+REQUEST_WAIT_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_request_wait_seconds_total",
+    "seconds requests waited on a fast tier from parse completion to the "
+    "start of service (loop backlog + admission queue), by server/operation; "
+    "full responses and FALLBACKs, not DETACHED ones",
+)
+# a request the fast tier does not fully understand (an EC read, a range,
+# /status) is replayed against the internal aiohttp listener over a new
+# loopback connection: the wall of that replay, which encloses the cold
+# tier's own request_seconds
+REQUEST_PROXY_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_request_proxy_seconds_total",
+    "seconds fast tiers spent replaying FALLBACK requests against their "
+    "cold tier (connect + replay + handler + relay), by server; divide by "
+    "request_proxied_total",
+)
+REQUEST_PROXIED = REGISTRY.counter(
+    "seaweedfs_tpu_request_proxied_total",
+    "requests a fast tier replayed against its cold tier, by server",
+)
+# one probe per ServingCore (serving_core.LoopLagProbe): a callback armed
+# by a request and re-armed every 10 ms while requests keep arriving adds
+# how late it ran — the one reading of time a request spends in the socket
+# before the loop parses it
+EVENT_LOOP_LAG_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_lag_seconds_total",
+    "seconds the serving loop ran its 10 ms probe callback late, by server",
+)
+EVENT_LOOP_LAG_TICKS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_lag_ticks_total",
+    "loop-lag probe callbacks run, by server",
+)
+# set once at start-up: where the seconds from process start to the first
+# served request go (imports/device/store_load/index_build/listening)
+STARTUP_SECONDS = REGISTRY.gauge(
+    "seaweedfs_tpu_startup_seconds",
+    "seconds since process start at which each start-up phase was done, "
+    "by phase (imports/device/store_load/index_build/listening)",
 )
 EC_ENCODE_BYTES = REGISTRY.counter(
     "seaweedfs_tpu_ec_encoded_bytes_total", "bytes erasure-coded, by backend"
@@ -444,6 +487,50 @@ READ_CACHE_EVICTIONS = REGISTRY.counter(
 # of every rebuild_ec_files run (stages overlap on the pipelined route, so
 # their sum can exceed the rebuild wall), degraded-read interval latency
 # split cold vs cache-served, and the decode-matrix LRU's hit rate
+# host-stage attribution of the codec's device path (util/trace.stage):
+# every TpuRSCodec call is pack -> put -> dispatch -> fetch -> unpack
+# (+ stack on a reconstruct), by op (encode/decode/apply). Stages run on
+# pool threads, so their sum can exceed the wall.
+RS_DISPATCH_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_rs_dispatch_seconds_total",
+    "host seconds of a TpuRSCodec call, by op (encode/decode/apply) and "
+    "stage (stack/pack/put/dispatch/fetch/unpack)",
+)
+RS_DISPATCHES = REGISTRY.counter(
+    "seaweedfs_tpu_rs_dispatches_total",
+    "TpuRSCodec calls, by op and backend (device = the Pallas kernel on a "
+    "TPU; device_emulated = jax on the CPU; host_standin = the native "
+    "codec the streamed pipeline substitutes without a TPU)",
+)
+RS_DISPATCH_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_rs_dispatch_bytes_total",
+    "bytes of TpuRSCodec calls, by op, backend and kind (real = (rows in "
+    "+ rows out) x the caller's width; padded = what the pad to the "
+    "kernel's granule added)",
+)
+# encode-plane attribution (the mirror of ec_rebuild_stage_seconds):
+# stage walls of every write_ec_files run; kernel (pool) and write /
+# parity_wait (writer thread) overlap the main thread's stages
+EC_ENCODE_STAGE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_encode_stage_seconds_total",
+    "write_ec_files stage wall seconds, by stage (splice/read/slot_wait/"
+    "submit/kernel/parity_wait/write/sync; pipelined stages overlap)",
+)
+EC_ENCODE_STAGE_CALLS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_encode_stage_calls_total",
+    "times each write_ec_files stage ran, by stage",
+)
+# where a degraded read's wall goes. Of a cold reconstruct: survivor_read
+# (the gathers of survivor fetches), executor_wait (submit -> the worker's
+# first line), decode (the worker's wall around reconstruct_rows),
+# cache_put; divide by ec_reconstructions_total{kind="cold"}. Before any
+# reconstruct, hit or cold: remote_attempts
+EC_DEGRADED_READ_STAGE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_degraded_read_stage_seconds_total",
+    "degraded EC read stage wall seconds, by stage (remote_attempts = "
+    "location refreshes + remote holders tried before any reconstruct; of "
+    "a cold reconstruct: survivor_read/executor_wait/decode/cache_put)",
+)
 EC_REBUILD_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_ec_rebuild_stage_seconds",
     "rebuild_ec_files per-stage wall seconds, by stage (read/decode/write; "
@@ -748,11 +835,6 @@ NEEDLE_MAP_DEVICE_UPLOADS = REGISTRY.counter(
     "double-buffered arena generation uploads completed (each builds "
     "the next resident set while the previous keeps serving)",
 )
-NEEDLE_MAP_DEVICE_EVICTIONS = REGISTRY.counter(
-    "seaweedfs_tpu_needle_map_device_evictions_total",
-    "segments denied residency by the arena's LRU byte budget at a "
-    "generation refresh",
-)
 NEEDLE_MAP_DEVICE_IDENTITY_MISMATCH = REGISTRY.counter(
     "seaweedfs_tpu_needle_map_device_identity_mismatch_total",
     "device answers that disagreed with the host map under the "
@@ -902,6 +984,43 @@ TIER_ORPHANS_SWEPT = REGISTRY.counter(
     "remote cold-tier objects deleted by the orphan sweep because no "
     "live .ctm manifest names them (past the grace age)",
 )
+
+def _process_start_epoch() -> float:
+    """When the kernel started this process (Linux: field 22 of
+    /proc/self/stat, in clock ticks since boot), so a start-up phase
+    counts the interpreter's own start too; elsewhere, now."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        with open("/proc/stat") as f:
+            boot = next(
+                int(line.split()[1]) for line in f if line.startswith("btime")
+            )
+        return boot + ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, StopIteration, IndexError):
+        return _time.time()
+
+
+_PROCESS_START = _process_start_epoch()
+_STARTUP_AGES: dict = {}
+
+
+def mark_startup(phase: str) -> None:
+    """Set `startup_seconds{phase}` to this process's age now: a server
+    calls it once as each start-up phase is done."""
+    age = _time.time() - _PROCESS_START
+    _STARTUP_AGES[phase] = age
+    STARTUP_SECONDS.set(age, phase=phase)
+
+
+def startup_line() -> str:
+    """`phase=seconds` of every phase marked so far, in the order they
+    were done: the ready log line's tail."""
+    return " ".join(
+        f"{phase}={age:.3f}"
+        for phase, age in sorted(_STARTUP_AGES.items(), key=lambda kv: kv[1])
+    )
+
 
 # the registry seam the bounded-cardinality lint checks: every family
 # that carries a `tenant` label MUST be listed here, or a retired

@@ -42,6 +42,13 @@ fan-out it rode. This module closes that attribution gap:
   context — serving-vs-maintenance interference is visible in one
   timeline.
 
+- **Stages** (ISSUE 26): `stage(...)` binds one named stage of host work
+  per site; ``with STAGE():`` then writes a `jax.profiler.TraceAnnotation`
+  (an event on the profiler's own clock, beside the device plane), adds
+  the wall to a `/metrics` counter child, and — under a sampled context —
+  records a child span here. The RS dispatch, the encode pipeline and the
+  degraded read are staged with it; docs/observability.md lists them.
+
 The reference (weed/) has no tracing; the design follows the W3C Trace
 Context wire format and Dapper-style in-process recording.
 """
@@ -52,6 +59,7 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
 from typing import Optional
@@ -593,6 +601,113 @@ def note_shed(name: str, **tags) -> None:
         c.flags |= FLAG_SHED
         return
     rec.promote_fault(name, "shed", **tags)
+
+
+# ---------------------------------------------------------------- stages --
+
+_perf = time.perf_counter
+_TRACE_ME = None
+
+
+def _trace_me():
+    """`jax.profiler.TraceAnnotation`, once this process has imported jax
+    by itself; None for a process that never does (master, filer), whose
+    stages are counters alone."""
+    global _TRACE_ME
+    if _TRACE_ME is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+class Stage:
+    """One named stage of host work, bound once per site (at import or
+    construction, like `ServingCore.count`'s children) and entered as
+    ``with STAGE():``. One pair of clock reads then feeds three sinks:
+
+    - a `jax.profiler.TraceAnnotation(name)` around the block, so the
+      stage is an event on the `/host:CPU` plane of whatever profiler
+      trace is being taken, on the clock the device plane is on
+      (`annotate=False` for a WAIT: an event around a wait, or one held
+      across an `await`, would cover other work and name nothing);
+    - `seconds` (a `/metrics` counter child) += the wall, `calls` += 1
+      where the site has no count of its own (a leaf inside a counted
+      stage passes neither and is an event alone);
+    - a child span in the flight recorder when this thread's context is
+      sampled, so a slow request in `/debug/traces` shows its stages.
+
+    `sink`, when a run passes one, also gets ``sink.add(label, seconds)``
+    (the per-run budget of `write_ec_files`). With no profiler running and
+    the request unsampled a stage costs two clock reads, one inactive
+    TraceMe and one counter add."""
+
+    __slots__ = ("name", "label", "_seconds", "_calls", "_annotate")
+
+    def __init__(self, name: str, seconds=None, calls=None,
+                 annotate: bool = True, label: Optional[str] = None):
+        self.name = name
+        self.label = label or name
+        self._seconds = seconds
+        self._calls = calls
+        self._annotate = annotate
+
+    def __call__(self, sink=None) -> "_StageCM":
+        return _StageCM(self, sink)
+
+    def since(self, t0: float, sink=None) -> float:
+        """Add the seconds since `t0` (a `perf_counter` reading) and
+        return now: for a wait that starts on one thread and ends on
+        another, which no `with` block can hold. Counter only."""
+        now = _perf()
+        self._add(now - t0, sink)
+        return now
+
+    def _add(self, dt: float, sink) -> None:
+        if self._seconds is not None:
+            self._seconds.inc(dt)
+        if self._calls is not None:
+            self._calls.inc()
+        if sink is not None:
+            sink.add(self.label, dt)
+
+
+class _StageCM:
+    __slots__ = ("_stage", "_sink", "_t0", "_event", "_span")
+
+    def __init__(self, st: Stage, sink):
+        self._stage = st
+        self._sink = sink
+
+    def __enter__(self):
+        st = self._stage
+        self._span = None
+        c = _CTX.get()
+        if c is not None and c.sampled:
+            sp = span(st.name)
+            if sp is not _NULL:
+                self._span = sp
+                sp.__enter__()
+        self._event = None
+        if st._annotate:
+            trace_me = _TRACE_ME or _trace_me()
+            if trace_me is not None:
+                self._event = trace_me(st.name)
+                self._event.__enter__()
+        self._t0 = _perf()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        dt = _perf() - self._t0
+        if self._event is not None:
+            self._event.__exit__(et, ev, tb)
+        if self._span is not None:
+            self._span.__exit__(et, ev, tb)
+        self._stage._add(dt, self._sink)
+        return False
+
+
+stage = Stage  # ``trace.stage(name, seconds_child, ...)`` at a site
 
 
 # exemplar hook: histograms ask for the live sampled trace id at observe

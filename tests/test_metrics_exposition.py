@@ -169,7 +169,7 @@ def test_metrics_hygiene_lint():
         assert family in names, f"meta-plane family {family} not registered"
     # metadata device-kernel plane (ISSUE 18): pin the ragged arena
     # families (residency, dispatch/fallback economics, double-buffer
-    # uploads, LRU evictions, and the identity-check verdict counter)
+    # uploads, and the identity-check verdict counter)
     for family in (
         "seaweedfs_tpu_needle_map_device_resident_bytes",
         "seaweedfs_tpu_needle_map_device_segments",
@@ -177,10 +177,30 @@ def test_metrics_hygiene_lint():
         "seaweedfs_tpu_needle_map_device_probes_total",
         "seaweedfs_tpu_needle_map_device_fallbacks_total",
         "seaweedfs_tpu_needle_map_device_uploads_total",
-        "seaweedfs_tpu_needle_map_device_evictions_total",
         "seaweedfs_tpu_needle_map_device_identity_mismatch_total",
     ):
         assert family in names, f"device-kernel family {family} not registered"
+    # host-stage attribution (ISSUE 26): the families the stage helper
+    # (util/trace.Stage) writes and the benchmark's per-layer metrics read
+    for family in (
+        "seaweedfs_tpu_rs_dispatch_seconds_total",
+        "seaweedfs_tpu_rs_dispatches_total",
+        "seaweedfs_tpu_rs_dispatch_bytes_total",
+        "seaweedfs_tpu_ec_encode_stage_seconds_total",
+        "seaweedfs_tpu_ec_encode_stage_calls_total",
+        "seaweedfs_tpu_ec_degraded_read_stage_seconds_total",
+        "seaweedfs_tpu_request_wait_seconds_total",
+        "seaweedfs_tpu_event_loop_lag_seconds_total",
+        "seaweedfs_tpu_event_loop_lag_ticks_total",
+        "seaweedfs_tpu_startup_seconds",
+    ):
+        assert family in names, f"stage family {family} not registered"
+    # defined, never set or read: gone with ISSUE 26
+    for family in (
+        "seaweedfs_tpu_volumes",
+        "seaweedfs_tpu_needle_map_device_evictions_total",
+    ):
+        assert family not in names, f"dead family {family} is back"
 
 
 def test_tenant_label_cardinality_enforced_at_registry_seam():
