@@ -56,8 +56,9 @@ LAST_ROUTE: dict = {}
 # parity_wait_s, pipeline_depth and coverage_of_wall: read/stage/sync are
 # main-thread walls that PARTITION the run (their sum over total_s is the
 # disclosed coverage), while kernel_s (pool) and write_s / parity_wait_s
-# (writer thread) are overlapped walls whose ratio to total_s discloses
-# overlap efficiency.
+# (the ordering writer thread) are overlapped walls whose ratio to total_s
+# discloses overlap efficiency; write_thread_s is summed over every
+# thread that wrote shard files.
 LAST_STAGES: dict = {}
 
 
@@ -110,6 +111,9 @@ def _encode_stage(label: str, annotate: bool = True):
 # only; `kernel` has no event of its own: its leaves are the codec's
 # `rs.*` stages. `sync` is the final flush + close + rename; the drain
 # before it (the writer's join) is a wait that adds to the same counter.
+# `write` is the ordering thread's wall round a chunk's shard writes, its
+# helpers' included; `write_thread` is what each writing thread spent in
+# its own write calls, so write_thread / write = threads writing at once.
 _ST_SPLICE = _encode_stage("splice")
 _ST_READ = _encode_stage("read")
 _ST_SLOT_WAIT = _encode_stage("slot_wait", annotate=False)
@@ -117,6 +121,7 @@ _ST_SUBMIT = _encode_stage("submit", annotate=False)
 _ST_KERNEL = _encode_stage("kernel", annotate=False)
 _ST_PARITY_WAIT = _encode_stage("parity_wait", annotate=False)
 _ST_WRITE = _encode_stage("write")
+_ST_WRITE_THREAD = _encode_stage("write_thread", annotate=False)
 _ST_SYNC = _encode_stage("sync")
 _ST_SYNC_DRAIN = trace.stage(
     "ec.encode.sync_drain", EC_ENCODE_STAGE_SECONDS.child(stage="sync"),
@@ -348,6 +353,27 @@ def _stream_items(
     return items
 
 
+# Raced on the chip's host (13 CPUs, shards to tmpfs, 1 GiB volumes; PERF.md
+# section 6, PR 27): 1, 2, 3, 4, 5, 7, 14 writing threads gave 1.07, 1.67,
+# 1.95, 2.04, 2.10, 2.07, 1.91 GB/s at 1.87, 1.95, 1.99, 2.14, 2.15, 2.50,
+# 3.01 cpu-s/GB. Past three the copies only slow each other down (the
+# threads' own write seconds grow as fast as their number), so a fourth
+# buys 5 % of rate for 8 % more CPU and a sixth buys nothing.
+_STREAM_WRITERS_MOST = 3
+
+
+def _stream_writers(n_files: int, depth: int) -> int:
+    """How many threads write a chunk's shard files in the streamed
+    pipeline, the ordering thread included: one a file and one a CPU left
+    beside the main thread and the pool's `depth` workers, and no more
+    than the race above found useful. One or two CPUs give 1: the writer
+    writes inline and starts no helper."""
+    from ...util import available_cpus
+
+    spare = available_cpus() - (1 + depth)
+    return max(1, min(n_files, spare, _STREAM_WRITERS_MOST))
+
+
 def _encode_streamed(
     run: EncodeRun,
     base_file_name: str,
@@ -361,7 +387,7 @@ def _encode_streamed(
     depth: int,
     splice_data,
     dat_path: str,
-) -> bool:
+) -> tuple[bool, str, int]:
     """The streamed, depth-N double-buffered encode pipeline (the route the
     device codec prefers; any codec runs it with pipeline=True).
 
@@ -382,8 +408,13 @@ def _encode_streamed(
     Each chunk's kernel dispatch (host->device upload + matmul + download,
     or the host-kernel dispatch the codec substitutes on the CPU stand-in)
     runs on a pool of `depth` workers so it overlaps the NEXT chunk's disk
-    read (main thread) and the PREVIOUS chunk's shard writes (dedicated
-    writer thread). Output is in-order into .ecNN.tmp files renamed into
+    read (main thread) and the PREVIOUS chunk's shard writes. Those are
+    the write side's: one ordering thread (`ec-stream-writer`) takes the
+    chunks in stream order and shares each chunk's writes with
+    `_stream_writers(...)` - 1 helpers, every thread appending to its own
+    fixed files (data shards before the chunk's parity is waited for,
+    parity shards after), and gives the slot back when all are done.
+    Output is in-order into .ecNN.tmp files renamed into
     place only when the whole stream succeeds — a mid-stream crash leaves
     only .tmp files for the next run's sweep, never a torn shard
     masquerading as complete.
@@ -395,15 +426,18 @@ def _encode_streamed(
     submit (set-up, pad, pool.submit, hand-off), sync (final drain +
     flush + rename) partition the main-thread wall — their sum over
     total_s is the disclosed coverage_of_wall; kernel (pool), parity_wait
-    and write (writer) are the overlapped walls. Returns (spliced, input)
-    where `input` is the route that fed the ring ("mmap" or "pread")."""
+    and write (the ordering thread: hand-out to all done, less its wait
+    for parity) are the overlapped walls, and write_thread adds up what
+    every writing thread spent in its write calls. Returns (spliced,
+    input, writers) where `input` is the route that fed the ring ("mmap"
+    or "pread") and `writers` the threads that wrote."""
     import concurrent.futures as cf
+    import contextlib
     import mmap as mmap_mod
     import queue as queue_mod
     import time as _time
 
     k = codec.data_shards
-    m = codec.parity_shards
     total = codec.total_shards
 
     _sweep_stale_tmp(base_file_name, total)
@@ -483,27 +517,86 @@ def _encode_streamed(
         with _ST_KERNEL(run):
             return np.asarray(dispatch(view))
 
+    # the write side: the ordering thread (`writer`, the one consumer of
+    # outq) and n_writers - 1 helpers. A shard file belongs to ONE thread,
+    # which appends to it in chunk order: no lock, no offsets to keep.
+    # Dealt round-robin, so the parity files spread over the threads
+    files = [i for i in range(total) if outputs[i] is not None]
+    n_writers = _stream_writers(len(files), depth)
+    shares = [
+        (
+            [i for i in files[t::n_writers] if i < k],
+            [i for i in files[t::n_writers] if i >= k],
+        )
+        for t in range(n_writers)
+    ]
+    doneq: queue_mod.SimpleQueue = queue_mod.SimpleQueue()
+
+    @contextlib.contextmanager
+    def noted():
+        """An exception in any writing thread lands in err[0]; the thread
+        goes on consuming, so no slot is lost and nobody deadlocks."""
+        try:
+            yield
+        except BaseException as e:
+            if err[0] is None:
+                err[0] = e
+
+    def write_files(mine, rows, first: int, used: int) -> None:
+        with _ST_WRITE_THREAD(run):
+            for i in mine:
+                outputs[i].write(rows[i - first, :used].data)
+
+    def helper(data_files, parity_files, tasks) -> None:
+        while True:
+            task = tasks.get()
+            if task is None:
+                return
+            buf, used, fut = task
+            with noted():
+                write_files(data_files, buf, 0, used)
+                if parity_files:
+                    write_files(parity_files, fut.result(), k, used)
+            doneq.put(None)
+
+    task_qs = [queue_mod.SimpleQueue() for _ in shares[1:]]
+    helpers = [
+        threading.Thread(
+            target=helper, args=(*share, tasks),
+            name=f"ec-stream-writer-{t}", daemon=True,
+        )
+        for t, (share, tasks) in enumerate(zip(shares[1:], task_qs), 1)
+    ]
+
     def writer() -> None:
+        data_files, parity_files = shares[0]
+        for thread in helpers:
+            thread.start()
         while True:
             entry = outq.get()
             if entry is None:
-                return
+                break
             buf, used, fut, slot = entry
-            try:
-                with _ST_PARITY_WAIT(run):
-                    parity = fut.result()
-                with _ST_WRITE(run):
-                    for i in range(k):
-                        if outputs[i] is not None:
-                            outputs[i].write(buf[i, :used].data)
-                    for p in range(m):
-                        outputs[k + p].write(parity[p, :used].data)
-            except BaseException as e:  # keep consuming: the main thread
-                # must never deadlock on a dead writer's unreturned slots
-                if err[0] is None:
-                    err[0] = e
-            finally:
-                freeq.put(slot)
+            parity = None
+            # one task a thread a chunk; the data shards need no parity,
+            # so they are written while the codec still works on it
+            with _ST_WRITE(run), noted():
+                for tasks in task_qs:
+                    tasks.put((buf, used, fut))
+                write_files(data_files, buf, 0, used)
+            with _ST_PARITY_WAIT(run), noted():
+                parity = fut.result()
+            with _ST_WRITE(run):
+                if parity is not None:
+                    with noted():
+                        write_files(parity_files, parity, k, used)
+                for _ in helpers:
+                    doneq.get()
+            freeq.put(slot)
+        for tasks in task_qs:
+            tasks.put(None)
+        for thread in helpers:
+            thread.join()
 
     writer_t = threading.Thread(
         target=writer, name="ec-stream-writer", daemon=True
@@ -600,7 +693,7 @@ def _encode_streamed(
             except (BufferError, OSError):
                 pass  # a straggling view still exports the buffer: the
                 # mapping closes when it is collected
-    return spliced, "mmap" if mm is not None else "pread"
+    return spliced, "mmap" if mm is not None else "pread", n_writers
 
 
 def _fs_type_of(path: str) -> str:
@@ -1087,7 +1180,7 @@ def _write_ec_files(
         ))
         try:
             with open(dat_path, "rb") as dat_f:
-                spliced, input_kind = _encode_streamed(
+                spliced, input_kind, writers = _encode_streamed(
                     run, base_file_name, dat_f, codec,
                     n_large, large_block_size, n_small, small_block_size,
                     chunk, depth, splice_data, dat_path,
@@ -1098,6 +1191,7 @@ def _write_ec_files(
                 "input": input_kind,
                 "kernel": getattr(codec, "pipeline_dispatch_kind", "host"),
                 "pipeline_depth": depth,
+                "writers": writers,
             }
         finally:
             total = _time.perf_counter() - _t_enter

@@ -510,11 +510,13 @@ RS_DISPATCH_BYTES = REGISTRY.counter(
 )
 # encode-plane attribution (the mirror of ec_rebuild_stage_seconds):
 # stage walls of every write_ec_files run; kernel (pool) and write /
-# parity_wait (writer thread) overlap the main thread's stages
+# parity_wait (the ordering writer thread) overlap the main thread's
+# stages; write_thread is summed over every thread that writes shards
 EC_ENCODE_STAGE_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_ec_encode_stage_seconds_total",
     "write_ec_files stage wall seconds, by stage (splice/read/slot_wait/"
-    "submit/kernel/parity_wait/write/sync; pipelined stages overlap)",
+    "submit/kernel/parity_wait/write/write_thread/sync; pipelined stages "
+    "overlap)",
 )
 EC_ENCODE_STAGE_CALLS = REGISTRY.counter(
     "seaweedfs_tpu_ec_encode_stage_calls_total",

@@ -3,10 +3,12 @@ must be byte-identical to the one-shot reference route across geometries,
 chunk sizes, and ragged final extents — and a mid-stream crash must leave
 only sweepable .ecNN.tmp files, never a torn shard that looks complete."""
 
+import errno
 import os
 import signal
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -101,6 +103,116 @@ def test_streamed_pread_staging_route_matches(tmp_path, monkeypatch):
     )
     assert enc.LAST_ROUTE["input"] == "pread"
     assert _read_shards(ref_base, k + m) == _read_shards(got_base, k + m)
+
+
+def _with_writers(monkeypatch, writers, depth=2):
+    """Let the pipeline see just the CPUs that give `writers` writing
+    threads (the main thread, the pool's `depth` workers, and those), on
+    a host where that many are worth having."""
+    monkeypatch.setattr(
+        "seaweedfs_tpu.util.available_cpus", lambda: 1 + depth + writers
+    )
+    monkeypatch.setattr(enc, "_STREAM_WRITERS_MOST", 14)
+
+
+@pytest.mark.parametrize("writers", [1, 2, 5])
+def test_streamed_matches_oneshot_from_any_number_of_writers(
+    tmp_path, monkeypatch, writers
+):
+    """The 14 shard files are the one-shot encode's byte for byte whether
+    one thread writes them or several do, each its own files: a .dat of
+    large rows, small rows and a tail that straddles EOF mid-row."""
+    k, m = 10, 4
+    size = 2 * LARGE * k + 3 * SMALL * k + 2 * SMALL + 517
+    ref_base = str(tmp_path / "ref")
+    _write_dat(ref_base, size, 27)
+    write_ec_files(
+        ref_base, codec=CpuRSCodec(k, m), large_block_size=LARGE,
+        small_block_size=SMALL, pipeline=False, splice_data=False,
+        mmap_input=False, onepass=False,
+    )
+    _with_writers(monkeypatch, writers)
+    threads_before = set(threading.enumerate())
+    got_base = str(tmp_path / "streamed")
+    _write_dat(got_base, size, 27)
+    run = write_ec_files(
+        got_base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
+        small_block_size=SMALL, chunk=1 << 14, pipeline=True,
+        splice_data=False,
+    )
+    assert run.route["writers"] == writers
+    assert _read_shards(ref_base, k + m) == _read_shards(got_base, k + m)
+    assert not any(n.endswith(".tmp") for n in os.listdir(tmp_path))
+    # every thread of the run is gone with it
+    assert not [
+        t.name for t in set(threading.enumerate()) - threads_before
+        if t.name.startswith("ec-stream-writer")
+    ]
+
+
+class _FailingShard:
+    """A shard file whose second write finds the disk full."""
+
+    def __init__(self, f, raised_on: list):
+        self._f = f
+        self._writes = 0
+        self._raised_on = raised_on
+
+    def write(self, data):
+        self._writes += 1
+        if self._writes == 2:
+            self._raised_on.append(threading.current_thread().name)
+            raise OSError(errno.ENOSPC, "No space left on device")
+        return self._f.write(data)
+
+    def flush(self):
+        self._f.flush()
+
+    def close(self):
+        self._f.close()
+
+
+@pytest.mark.parametrize("shard", [11, 6], ids=["parity", "data"])
+def test_a_helper_threads_write_error_ends_the_run(
+    tmp_path, monkeypatch, shard
+):
+    """5 writers: shards 1, 6 and 11 are the first helper's. Its OSError
+    is the run's, the ring keeps turning till the stream is consumed (no
+    deadlock: a time limit of this test's own), and nothing is left but
+    the .dat."""
+    k, m = 10, 4
+    base = str(tmp_path / "v")
+    _write_dat(base, 4 * LARGE * k + 999, 5)
+    _with_writers(monkeypatch, 5)
+    raised_on: list = []
+    target = base + to_ext(shard) + ".tmp"
+
+    def opening(path, *a, **kw):
+        f = open(path, *a, **kw)
+        return _FailingShard(f, raised_on) if path == target else f
+
+    monkeypatch.setattr(enc, "open", opening, raising=False)
+    outcome: list = []
+
+    def encode():
+        try:
+            write_ec_files(
+                base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
+                small_block_size=SMALL, chunk=1 << 14, pipeline=True,
+                splice_data=False,
+            )
+            outcome.append(None)
+        except BaseException as e:
+            outcome.append(e)
+
+    t = threading.Thread(target=encode, daemon=True)
+    t.start()
+    t.join(120)
+    assert not t.is_alive(), "the pipeline deadlocked on a failed writer"
+    assert isinstance(outcome[0], OSError), outcome
+    assert outcome[0].errno == errno.ENOSPC
+    assert raised_on == ["ec-stream-writer-1"]
+    assert os.listdir(tmp_path) == ["v.dat"]
 
 
 def test_streamed_rebuild_roundtrip(tmp_path):

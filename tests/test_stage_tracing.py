@@ -156,8 +156,13 @@ def test_encode_moves_every_pipeline_stage_and_every_rs_encode_stage(tmp_path):
     assert run.route["route"] == "pipeline"
     assert run.route["kernel"] == "device_emulated"
     for stage in ("splice", "read", "slot_wait", "submit", "kernel",
-                  "parity_wait", "write", "sync"):
+                  "parity_wait", "write", "write_thread", "sync"):
         assert moved(before, after, ENCODE_SECONDS, stage=stage) > 0, stage
+    # as many writing threads as the files left to write and the CPUs allow
+    n_files = 4 if run.route["spliced"] else 14
+    assert run.route["writers"] == enc._stream_writers(
+        n_files, run.route["pipeline_depth"]
+    )
     for stage in ("pack", "put", "dispatch", "fetch", "unpack"):
         assert moved(before, after, RS_SECONDS, op="encode", stage=stage) > 0, stage
     assert moved(before, after, RS_SECONDS, op="decode") == 0
@@ -177,6 +182,37 @@ def test_encode_moves_every_pipeline_stage_and_every_rs_encode_stage(tmp_path):
     # the counters got what the run's own budget got
     assert abs(moved(before, after, ENCODE_SECONDS, stage="kernel")
                - stages["kernel_s"]) < 1e-6
+
+
+@pytest.mark.parametrize("writers", [1, 3])
+def test_write_thread_over_write_is_the_threads_writing_at_once(
+    tmp_path, monkeypatch, writers
+):
+    """`write` is the ordering thread's wall round a chunk's shard writes,
+    `write_thread` what every writing thread spent inside its own: with
+    one writer the second lies inside the first, with several it adds up
+    to at least as much (1 MiB writes, so the hand-out is small beside
+    them)."""
+    monkeypatch.setattr(
+        "seaweedfs_tpu.util.available_cpus", lambda: 1 + 2 + writers
+    )
+    base, kw = small_encode(tmp_path, emulated_codec(), size=(40 << 20) + 123)
+    before = scrape()
+    run = enc.write_ec_files(base, splice_data=False, **kw)
+    after = scrape()
+    assert run.route["writers"] == writers and not run.route["spliced"]
+    wall = moved(before, after, ENCODE_SECONDS, stage="write")
+    in_threads = moved(before, after, ENCODE_SECONDS, stage="write_thread")
+    assert abs(in_threads - run.seconds("write_thread")) < 1e-6
+    # 4 rows of 1 MiB blocks and a 128 KiB row for the tail: 5 chunks, and
+    # in each every thread writes its data files, then its parity files
+    calls = "seaweedfs_tpu_ec_encode_stage_calls_total"
+    assert moved(before, after, calls, stage="write_thread") == 5 * 2 * writers
+    assert moved(before, after, calls, stage="write") == 5 * 2
+    if writers == 1:
+        assert 0 < in_threads <= wall
+    else:
+        assert in_threads >= wall * 0.9, (in_threads, wall)
 
 
 def test_the_synchronous_route_keeps_its_keys(tmp_path):
@@ -338,6 +374,16 @@ NEW_METRICS = {
         "ec_read.decode_padding_share", "ec_read.device_decode_share",
     ],
 }
+# ISSUE 27's, appended to BENCHMARK.json after all of the above
+WRITER_METRICS = {
+    "warm-rs10.4.ec-encode": ["ec_pipeline.write_parallelism"],
+}
+ALL_NEW_METRICS = [
+    (cell, name)
+    for cells in (NEW_METRICS, WRITER_METRICS)
+    for cell, names in cells.items()
+    for name in names
+]
 
 
 async def _encode_lose_a_shard_and_get(tmp_path) -> tuple:
@@ -444,9 +490,7 @@ def test_the_probe_stops_with_the_tier_it_measures(degraded_get):
                       server="volume") == ticks
 
 
-@pytest.mark.parametrize(
-    "cell,name", [(cell, name) for cell, names in NEW_METRICS.items() for name in names]
-)
+@pytest.mark.parametrize("cell,name", ALL_NEW_METRICS)
 def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, name):
     """Every new per-layer metric, evaluated as a run evaluates it, against
     the /metrics pair the CPU run above recorded."""
@@ -463,6 +507,10 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
         assert value == 0.0  # a CPU decoded: the share a chip run must read as 100
     elif name == "ec_read.decode_padding_share":
         assert value == 0.0  # the jax path pads to 4 bytes, the Pallas kernel to 256 KiB
+    elif name == "ec_pipeline.write_parallelism":
+        # threads writing at once: at most 1 with one writer, else up to
+        # as many as wrote
+        assert 0 < value <= enc._stream_writers(14, 2)
     else:
         assert value > 0, value
     # with nothing recorded the metric is absent, never 0
@@ -471,6 +519,6 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
 
 def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     names = [e["name"] for e in common.benchmark_json()["per_layer"]]
-    new = [n for names_ in NEW_METRICS.values() for n in names_]
+    new = [name for _cell, name in ALL_NEW_METRICS]
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     assert len(json.dumps(common.benchmark_json())) < 64 << 10
