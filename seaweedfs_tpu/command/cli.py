@@ -982,7 +982,7 @@ backend = "tpu"           # route erasure coding through the TPU kernels
 # (ref weed scaffold master template)
 [master.maintenance]
 scripts = '''
-ec.encode -fullPercent 95
+ec.encode -fullPercent=95 -quietFor=1h
 ec.rebuild
 ec.balance
 volume.balance -force

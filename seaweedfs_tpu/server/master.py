@@ -859,6 +859,7 @@ peers: {escape(", ".join(self.raft.others()) or "none")}</p>
                             "read_heat",
                             "write_heat",
                             "size",
+                            "modified_at_second",
                         ):
                             if k in m:
                                 info[k] = m[k]

@@ -32,7 +32,9 @@ from ..util import trace
 from ..util.metrics import (
     EC_DEGRADED_READ_SECONDS,
     EC_DEGRADED_READ_STAGE_SECONDS,
+    EC_ENCODE_BATCH_FALLBACKS,
     EC_ENCODE_BYTES,
+    EC_GENERATE_SECONDS,
     EC_RECONSTRUCTIONS,
     RETRY_COUNTER,
 )
@@ -227,6 +229,13 @@ class EcHandlers:
         (6.3 / 12.4); the geometry is persisted in the .vif so readers and
         rebuilds recover it (our extension — the reference fixes 10.4 at
         compile time, ec_encoder.go:17-23)."""
+        t0 = time.perf_counter()
+        try:
+            return await self._ec_generate(req)
+        finally:
+            EC_GENERATE_SECONDS.inc(time.perf_counter() - t0, rpc="single")
+
+    async def _ec_generate(self, req) -> dict:
         vid = int(req["volume_id"])
         collection = req.get("collection", "")
         data_shards = int(req.get("data_shards", 0))
@@ -255,13 +264,6 @@ class EcHandlers:
             run = await loop.run_in_executor(
                 None, lambda: write_ec_files(base, codec=codec)
             )
-            # which executor ran the kernel stage, from THIS run's own
-            # route ("device" only when a TPU ran it): two encodes in
-            # flight never label each other's bytes
-            EC_ENCODE_BYTES.inc(
-                os.path.getsize(base + ".dat"),
-                backend=run.route.get("kernel", "host"),
-            )
             await loop.run_in_executor(None, write_sorted_file_from_idx, base)
             v = self.store.find_volume(vid)
             save_volume_info(
@@ -272,17 +274,42 @@ class EcHandlers:
                     parity_shards=parity_shards,
                 ),
             )
+            # which executor ran the kernel stage, from THIS run's own
+            # route ("device" only when a TPU ran it): two encodes in
+            # flight never label each other's bytes. Counted once the
+            # volume is whole: one that reports an error is not counted
+            EC_ENCODE_BYTES.inc(
+                os.path.getsize(base + ".dat"),
+                backend=run.route.get("kernel", "host"),
+            )
             return {}
         except Exception as e:
             return {"error": str(e)}
 
     async def _grpc_ec_generate_batch(self, req, context) -> dict:
-        """Batched multi-volume encode: all requested local volumes stream
-        through shared wide encode batches (write_ec_files_multi), so one
-        device dispatch serves every volume in a round instead of one volume
-        paying it alone (our extension; the reference encodes volumes
-        serially, command_ec_encode.go:110-135). Returns per-volume errors
-        keyed by id; volumes absent from `errors` succeeded."""
+        """Batched multi-volume encode: all requested local volumes are
+        converted by ONE call of write_ec_files_multi, which picks the route
+        (a device codec's volumes one after another through the streamed
+        pipeline; a host codec's across cores). Our extension; the reference encodes volumes serially, one
+        RPC each (command_ec_encode.go:110-135). Returns per-volume errors
+        keyed by id; volumes absent from `errors` succeeded.
+
+        What the one-volume RPC guarantees holds here: the streamed
+        pipeline's shard files appear under their final names only when
+        whole (commit by rename, as in write_ec_files), every volume's
+        bytes are counted under the backend of the run that encoded THEM,
+        and a batch that fails is counted
+        (ec_encode_batch_fallback_total{reason}) and logged before the
+        volumes it had not finished are converted one by one, so that one
+        broken volume reports its own error under its own id and its
+        neighbours finish."""
+        t0 = time.perf_counter()
+        try:
+            return await self._ec_generate_batch(req)
+        finally:
+            EC_GENERATE_SECONDS.inc(time.perf_counter() - t0, rpc="batch")
+
+    async def _ec_generate_batch(self, req) -> dict:
         vids = [int(v) for v in req.get("volume_ids", [])]
         collection = req.get("collection", "")
         data_shards = int(req.get("data_shards", 0))
@@ -313,27 +340,44 @@ class EcHandlers:
                 except OSError:
                     pass
             await self._charge_maintenance(total, plane=req["plane"])
+        runs: dict = {}  # vid -> the EncodeRun that encoded it
         try:
-            await loop.run_in_executor(
+            got = await loop.run_in_executor(
                 None,
                 lambda: write_ec_files_multi(
                     [b for _vid, b in bases], codec=codec
                 ),
             )
-        except Exception:
-            # one broken volume must not sink its co-batched neighbours:
-            # retry each volume alone so only the faulty ones report errors
-            healthy = []
-            for vid, base in bases:
+            runs = {vid: run for (vid, _b), run in zip(bases, got)}
+        except Exception as e:
+            # never silent: a device error in the batch shows on /metrics
+            # and in the log before the volumes go one by one
+            EC_ENCODE_BATCH_FALLBACKS.inc(
+                reason="io" if isinstance(e, OSError) else "codec"
+            )
+            from ..util.log import warning
+
+            warning(
+                "ec generate batch %s failed (%s: %s); converting its "
+                "volumes one by one", [v for v, _b in bases],
+                type(e).__name__, e,
+            )
+            # the volumes the batch finished before it failed are whole
+            runs = {
+                vid: run
+                for (vid, _b), run in zip(bases, getattr(e, "encoded", []))
+            }
+            for vid, base in bases[len(runs):]:
                 try:
-                    await loop.run_in_executor(
+                    runs[vid] = await loop.run_in_executor(
                         None, lambda b=base: write_ec_files(b, codec=codec)
                     )
-                    healthy.append((vid, base))
-                except Exception as e:
-                    errors[str(vid)] = str(e)
-            bases = healthy
+                except Exception as e1:
+                    errors[str(vid)] = str(e1)
         for vid, base in bases:
+            run = runs.get(vid)
+            if run is None:
+                continue
             try:
                 await loop.run_in_executor(
                     None, write_sorted_file_from_idx, base
@@ -346,6 +390,10 @@ class EcHandlers:
                         data_shards=data_shards,
                         parity_shards=parity_shards,
                     ),
+                )
+                EC_ENCODE_BYTES.inc(
+                    os.path.getsize(base + ".dat"),
+                    backend=run.route.get("kernel", "host"),
                 )
             except Exception as e:
                 errors[str(vid)] = str(e)

@@ -6,6 +6,8 @@ Each command: async fn(env, argv) -> output string.
 from __future__ import annotations
 
 import asyncio
+import re
+import time
 from collections import defaultdict
 
 from ..storage.erasure_coding import DATA_SHARDS_COUNT, TOTAL_SHARDS_COUNT
@@ -19,6 +21,7 @@ from .ec_common import (
     plan_balanced_spread,
     plan_dedupe,
     plan_rack_balance,
+    select_volumes_for_ec_encode,
 )
 
 COMMANDS: dict[str, callable] = {}
@@ -51,6 +54,31 @@ def _parse_flags(argv: list[str]) -> dict[str, str]:
     return flags
 
 
+_GO_DURATION_UNITS = {
+    "ns": 1e-9, "us": 1e-6, "\u00b5s": 1e-6, "ms": 1e-3,
+    "s": 1.0, "m": 60.0, "h": 3600.0,
+}
+_GO_DURATION_PART = re.compile(r"(\d+\.?\d*|\.\d+)(ns|us|\u00b5s|ms|s|m|h)")
+
+
+def parse_go_duration(text: str) -> float:
+    """Seconds of a Go duration flag (time.ParseDuration): `1h`, `30m`,
+    `45s`, `1h30m`, `1.5h`, `0`. ValueError on anything else."""
+    body = text.strip().lstrip("+")
+    if body == "0":
+        return 0.0
+    pos, total = 0, 0.0
+    while pos < len(body):
+        part = _GO_DURATION_PART.match(body, pos)
+        if part is None:
+            break
+        total += float(part.group(1)) * _GO_DURATION_UNITS[part.group(2)]
+        pos = part.end()
+    if pos == 0 or pos != len(body):
+        raise ValueError(f"bad duration {text!r}; want e.g. 1h, 30m, 45s")
+    return total
+
+
 async def run_command(env: CommandEnv, line: str) -> str:
     parts = line.strip().split()
     if not parts:
@@ -65,6 +93,14 @@ async def run_command(env: CommandEnv, line: str) -> str:
 # ---------------- basic ----------------
 @command("help")
 async def cmd_help(env, argv) -> str:
+    """help [command]: the commands, or one command's own description."""
+    if argv:
+        fn = COMMANDS.get(argv[0])
+        if fn is None:
+            return f"unknown command: {argv[0]} (try `help`)"
+        import inspect
+
+        return f"{argv[0]}: " + inspect.cleandoc(fn.__doc__ or "no description")
     return "commands:\n  " + "\n  ".join(sorted(COMMANDS))
 
 
@@ -590,6 +626,17 @@ async def cmd_ec_encode(env, argv) -> str:
     """Erasure-code volumes and spread shards
     (ref command_ec_encode.go:55-264).
 
+    ec.encode -volumeId <id[,id...]> [-collection <name>] [-shards k.m]
+    ec.encode [-collection <name>] [-fullPercent=95] [-quietFor=1h] [-shards k.m]
+
+    Without -volumeId the master's topology selects, as upstream's
+    collectVolumeIdsForEcEncode does: the volumes of -collection (default:
+    the empty one) whose size is over -fullPercent of the master's volume
+    size limit AND that were last modified more than -quietFor ago (a Go
+    duration: 1h, 30m, 45s, 0s; default 1h). The master's maintenance
+    script runs `ec.encode -fullPercent=95 -quietFor=1h`. Volumes that share
+    a node are converted by one VolumeEcShardsGenerateBatch call.
+
     -shards k.m selects an alternate RS geometry (e.g. 6.3, 12.4); the
     default is the reference's 10.4 (ec_encoder.go:17-23).
     """
@@ -606,28 +653,28 @@ async def cmd_ec_encode(env, argv) -> str:
         if data_shards < 1 or parity_shards < 1:
             return f"bad -shards {flags['shards']!r}; want e.g. 10.4 or 6.3"
     vids: list[int] = []
+    nodes = await env.collect_data_nodes()
     if "volumeId" in flags:
         # comma-separated ids allowed: co-located ones encode as one batch
         vids = [int(x) for x in str(flags["volumeId"]).split(",") if x]
     else:
-        full_pct = float(flags.get("fullPercent", 95))
-        nodes = await env.collect_data_nodes()
+        try:
+            full_pct = float(flags.get("fullPercent", 95))
+            quiet_s = parse_go_duration(flags.get("quietFor", "1h"))
+        except ValueError as e:
+            return f"ec.encode: {e}"
         resp = await env.master_stub.call("VolumeList", {})
-        limit_mb = int(resp.get("volume_size_limit_mb", 30000))
-        seen = set()
-        for dn in nodes:
-            for v in dn.get("volumes", []):
-                vid = int(v["id"])
-                if vid in seen or v.get("collection", "") != collection:
-                    continue
-                if int(v.get("size", 0)) >= limit_mb * 1024 * 1024 * full_pct / 100:
-                    seen.add(vid)
-                    vids.append(vid)
+        vids = select_volumes_for_ec_encode(
+            [v for dn in nodes for v in dn.get("volumes", [])],
+            collection,
+            int(resp.get("volume_size_limit_mb", 30000)),
+            full_pct, quiet_s, time.time(),
+        )
     results = []
-    # volumes co-located on one node encode as a single shared batch
-    # (VolumeEcShardsGenerateBatch -> write_ec_files_multi): one device
-    # dispatch per round serves every volume instead of encoding serially
-    nodes = await env.collect_data_nodes()
+    # volumes co-located on one node are converted by ONE call
+    # (VolumeEcShardsGenerateBatch -> write_ec_files_multi, which picks
+    # the route: a device codec's one after another, a host codec's
+    # across cores) instead of one RPC each
     by_source: dict = {}
     for vid in vids:
         source = None

@@ -61,6 +61,33 @@ class ShardMove:
     target: str
 
 
+def select_volumes_for_ec_encode(
+    volumes: list,
+    collection: str,
+    volume_size_limit_mb: int,
+    full_percent: float,
+    quiet_seconds: float,
+    now: float,
+) -> list[int]:
+    """Which volumes `ec.encode` without -volumeId converts (ref
+    command_ec_encode.go collectVolumeIdsForEcEncode): those of
+    `collection` in the master's volume messages that were last modified
+    more than the quiet period ago and are over `full_percent` of the
+    volume size limit — both comparisons strict, as upstream's. A replica
+    reported by several nodes counts once; order is the topology's."""
+    quiet = int(quiet_seconds)
+    full = full_percent / 100 * float(volume_size_limit_mb) * 1024 * 1024
+    vids: dict[int, None] = {}  # an ordered set
+    for v in volumes:
+        if (
+            v.get("collection", "") == collection
+            and int(v.get("modified_at_second", 0)) + quiet < int(now)
+            and float(v.get("size", 0)) > full
+        ):
+            vids[int(v["id"])] = None
+    return list(vids)
+
+
 def plan_balanced_spread(
     nodes: list[EcNode], vid: int, shard_ids: list[int], source_url: str
 ) -> dict[str, list[int]]:

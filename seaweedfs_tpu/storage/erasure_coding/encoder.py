@@ -34,7 +34,11 @@ from . import (
 )
 from ...types import TOMBSTONE_FILE_SIZE, to_actual_offset
 from ...util import trace
-from ...util.metrics import EC_ENCODE_STAGE_CALLS, EC_ENCODE_STAGE_SECONDS
+from ...util.metrics import (
+    EC_ENCODE_BATCH_PIECES,
+    EC_ENCODE_STAGE_CALLS,
+    EC_ENCODE_STAGE_SECONDS,
+)
 from ..idx import iter_index, entry_to_bytes
 from ..needle import get_actual_size
 from ..needle_map import MemDb
@@ -127,6 +131,9 @@ _ST_SYNC_DRAIN = trace.stage(
     "ec.encode.sync_drain", EC_ENCODE_STAGE_SECONDS.child(stage="sync"),
     annotate=False, label="sync",
 )
+
+# the volume pieces each dispatch of the streamed pipeline carries: one
+_BATCH_PIECES = EC_ENCODE_BATCH_PIECES.child()
 
 # per-stage wall seconds of the last rebuild_ec_files run (read_s /
 # decode_s / write_s / total_s) — the repair-plane mirror of LAST_STAGES.
@@ -655,6 +662,7 @@ def _encode_streamed(
                             )
                     else:
                         kview = view
+                    _BATCH_PIECES.inc()
                     outq.put(
                         (buf, used, pool.submit(run_kernel, kview), slot)
                     )
@@ -1358,28 +1366,38 @@ def write_ec_files_multi(
     chunk: int = DEFAULT_CHUNK,
     workers: Optional[int] = None,
     mesh=None,
-) -> None:
-    """Encode MANY volumes' .dat files through shared wide encode batches
-    (BASELINE.json config 3 — batched multi-volume ec.encode).
+) -> list:
+    """Encode MANY volumes' .dat files in one call (BASELINE.json config 3 —
+    batched multi-volume ec.encode). Returns the EncodeRun that encoded
+    each volume, in the order given: its route says which kernel ran.
 
+    A device codec's volumes go one after another through write_ec_files,
+    the streamed pipeline: each is committed by rename when it is whole,
+    with that route's stages and counters. Raced on the chip's host against
+    wide batches of four volumes' pieces (13 CPUs, shards to tmpfs, the
+    cell warm-rs10.4-maint.ec-encode-full4; PERF.md section 6, PR 28), in
+    turn won 7 of 7: a wide batch saves dispatches but has to be staged by
+    a copy, on a host whose writers are bound by the memory bus. A failure
+    raises with the runs of the volumes already whole as its `encoded`, so
+    a caller that falls back need not convert those again.
+
+    Host codecs encode whole volumes concurrently across cores (each on
+    the single-threaded zero-copy path).
+
+    A mesh takes shared wide encode batches, which are what it shards.
     GF(2^8) parity is computed column-by-column, so pieces from different
     volumes concatenated along the column axis and encoded in ONE call are
-    byte-identical to per-volume encodes — but a single device dispatch now
-    amortizes its launch/transfer latency over every volume in the round
-    instead of paying it per 1MB block per volume (the reference encodes one
-    volume at a time through a 256KB loop, ref ec_encoder.go:57,120-136).
-    Each round takes the next piece of every unfinished volume, groups by
-    width, and pipelines read -> batched encode -> ordered writes.
-
-    Host codecs take a different route to the same aggregate win: encode
-    whole volumes concurrently across cores (each on the single-threaded
-    zero-copy path), since a host matmul gains nothing from wider batches.
+    byte-identical to per-volume encodes. Each round takes the next piece
+    of every unfinished volume, groups by width, and pipelines read ->
+    batched encode -> ordered writes. No server path reaches this route;
+    it writes under the final names and has no stages (PERF.md section 7).
     """
     import concurrent.futures as cf
     from collections import deque
 
     codec = _get_codec(codec)
     k = codec.data_shards
+    base_file_names = list(base_file_names)
 
     if not getattr(codec, "is_device", False):
         from ...util import available_cpus
@@ -1388,8 +1406,8 @@ def write_ec_files_multi(
             1, min(len(base_file_names), workers or available_cpus())
         )
 
-        def one(base: str) -> None:
-            write_ec_files(
+        def one(base: str) -> EncodeRun:
+            return write_ec_files(
                 base, codec=codec,
                 large_block_size=large_block_size,
                 small_block_size=small_block_size,
@@ -1397,13 +1415,25 @@ def write_ec_files_multi(
             )
 
         if n_workers == 1:  # no pool indirection when there's no parallelism
-            for base in base_file_names:
-                one(base)
-            return
+            return [one(base) for base in base_file_names]
         with cf.ThreadPoolExecutor(n_workers) as pool:
-            for _ in pool.map(one, base_file_names):
-                pass
-        return
+            return list(pool.map(one, base_file_names))
+
+    if mesh is None:
+        runs: list = []
+        try:
+            for base in base_file_names:
+                runs.append(write_ec_files(
+                    base, codec=codec,
+                    large_block_size=large_block_size,
+                    small_block_size=small_block_size,
+                    chunk=chunk,
+                ))
+        except Exception as e:
+            e.encoded = runs
+            raise
+        return runs
+
     width_cap = max(
         small_block_size, getattr(codec, "preferred_chunk", chunk)
     )
@@ -1471,11 +1501,8 @@ def write_ec_files_multi(
                 for p in range(codec.parity_shards):
                     outputs[k + p].write(parity[p, sl].data)
 
-        if mesh is not None:
-            def encode_batch(buf: np.ndarray) -> np.ndarray:
-                return _mesh_encode(codec, mesh, buf)
-        else:
-            encode_batch = codec.encode
+        def encode_batch(buf: np.ndarray) -> np.ndarray:
+            return _mesh_encode(codec, mesh, buf)
 
         depth = max(1, workers or 2)  # device pipeline depth
         with cf.ThreadPoolExecutor(depth) as pool:
@@ -1494,6 +1521,9 @@ def write_ec_files_multi(
             dat_f.close()
             for f in outputs:
                 f.close()
+    run = EncodeRun()
+    run.route = {"route": "wide_batch", "kernel": "mesh", "volumes": len(vols)}
+    return [run] * len(vols)
 
 
 def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx") -> None:

@@ -217,6 +217,11 @@ class Store:
                         "read_heat": round(rh, 4),
                         "write_heat": round(wh, 4),
                         "size": v.data_file_size(),
+                        # ec.encode -quietFor reads it off the master: a
+                        # volume taking writes must not look quiet there
+                        "modified_at_second": int(
+                            v.last_modified_ts_seconds
+                        ),
                     }
                 )
         try:

@@ -69,29 +69,36 @@ def volume_base_name(directory: str, collection: str, vid: int) -> str:
     return os.path.join(directory, str(vid))
 
 
+def modified_second(n: Needle) -> int:
+    """When a record changed its volume, in seconds: the date the client
+    sent with it (`ts=`), else the second it was appended."""
+    return n.last_modified or n.append_at_ns // 10**9
+
+
 def check_volume_data_integrity(
     dat: BackendStorageFile, version: int, idx_path: str
-) -> int:
+) -> tuple[int, int]:
     """Verify idx size alignment and the last entry's needle CRC; returns
-    last_append_at_ns (ref: weed/storage/volume_checking.go:15-46)."""
+    (last_append_at_ns, the second the volume was last modified), both
+    read off that record (ref: weed/storage/volume_checking.go:15-46)."""
     idx_size = os.path.getsize(idx_path)
     if idx_size % NEEDLE_MAP_ENTRY_SIZE != 0:
         raise ValueError(f"index file size {idx_size} not a multiple of 16")
     if idx_size == 0:
-        return 0
+        return 0, 0
     from .idx import parse_entry
 
     with open(idx_path, "rb") as f:
         f.seek(idx_size - NEEDLE_MAP_ENTRY_SIZE)
         key, offset_units, size = parse_entry(f.read(NEEDLE_MAP_ENTRY_SIZE))
     if offset_units == 0:
-        return 0
+        return 0, 0
     if size == TOMBSTONE_FILE_SIZE:
         size = 0
     n = read_needle_data(dat, to_actual_offset(offset_units), size, version)
     if n.id != key:
         raise ValueError(f"index key {key:#x} does not match needle id {n.id:#x}")
-    return n.append_at_ns
+    return n.append_at_ns, modified_second(n)
 
 
 class UnrecoverableCorruption(Exception):
@@ -377,9 +384,7 @@ class Volume:
         self.nm: NeedleMap
         if os.path.exists(base + ".idx") and dat_exists:
             try:
-                self.last_append_at_ns = check_volume_data_integrity(
-                    self.data_backend, self.version, base + ".idx"
-                )
+                self._note_last_record(base)
                 if needle_map_kind == "memory":
                     # the last idx entry can verify while the dat still
                     # carries a torn record PAST it (crash mid-append,
@@ -453,6 +458,22 @@ class Volume:
             return load_lsm_needle_map(base + ".idx", version=self.version)
         return load_needle_map(base + ".idx")
 
+    def _note_last_record(self, base: str) -> None:
+        """At load: the append frontier and the modification time, from the
+        last indexed record (a restart must not make a volume that was
+        written a minute ago look untouched since 1970 to `ec.encode
+        -quietFor`)."""
+        self.last_append_at_ns, self.last_modified_ts_seconds = (
+            check_volume_data_integrity(
+                self.data_backend, self.version, base + ".idx"
+            )
+        )
+
+    def _note_modified(self, n: Needle) -> None:
+        self.last_modified_ts_seconds = max(
+            self.last_modified_ts_seconds, modified_second(n)
+        )
+
     def _recover_torn_tail(self, base: str) -> None:
         """Repair a torn .dat/.idx tail on load; read-only fallback when
         even the repaired prefix fails verification."""
@@ -464,9 +485,7 @@ class Volume:
                 self.data_backend, self.version, base + ".idx",
                 data_start=self.super_block.block_size(),
             )
-            self.last_append_at_ns = check_volume_data_integrity(
-                self.data_backend, self.version, base + ".idx"
-            )
+            self._note_last_record(base)
         except Exception:
             self.no_write_or_delete = True
             return
@@ -663,8 +682,7 @@ class Volume:
 
             if nv is None or to_actual_offset(nv.offset_units) < offset:
                 self.nm.put(n.id, to_offset_units(offset), n.size)
-            if self.last_modified_ts_seconds < n.last_modified:
-                self.last_modified_ts_seconds = n.last_modified
+            self._note_modified(n)
             return offset, size_for_index, False
 
     def write_needle_batch(self, needles: list) -> list:
@@ -748,8 +766,7 @@ class Volume:
                     self.nm.put(key, off_units, size)
             for i, n, offset, size_for_index in pending:
                 self.last_append_at_ns = n.append_at_ns
-                if self.last_modified_ts_seconds < n.last_modified:
-                    self.last_modified_ts_seconds = n.last_modified
+                self._note_modified(n)
                 results[i] = (offset, size_for_index, False)
             return results
 
@@ -770,6 +787,7 @@ class Volume:
             blob, _, _ = n.to_bytes(self.version)
             self.data_backend.write_at(blob, end)
             self.last_append_at_ns = n.append_at_ns
+            self._note_modified(n)
             self.nm.delete(n.id, to_offset_units(end))
             return size
 

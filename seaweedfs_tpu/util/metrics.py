@@ -522,6 +522,26 @@ EC_ENCODE_STAGE_CALLS = REGISTRY.counter(
     "seaweedfs_tpu_ec_encode_stage_calls_total",
     "times each write_ec_files stage ran, by stage",
 )
+# what only a batch has. Pieces: the volume pieces the streamed pipeline's
+# dispatches carried, summed; over rs_dispatches_total{op="encode"} it is
+# volumes per dispatch. Every dispatch carries one today (a batch converts
+# its volumes in turn), so the ratio reads 1.0: nothing was batched.
+# Generate seconds: the wall of a generate RPC's handler (encode + .ecx +
+# .vif), rpc = "batch" (VolumeEcShardsGenerateBatch) or "single". Fallbacks:
+# a batch that failed as one call and whose volumes were then converted one
+# by one, reason = "io" (an OSError) or "codec" (anything else)
+EC_ENCODE_BATCH_PIECES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_encode_batch_pieces_total",
+    "volume pieces carried by the streamed encode pipeline's dispatches",
+)
+EC_GENERATE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_generate_seconds_total",
+    "wall seconds in EC generate RPC handlers, by rpc (batch/single)",
+)
+EC_ENCODE_BATCH_FALLBACKS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_encode_batch_fallback_total",
+    "batched encodes that fell back to one volume at a time, by reason",
+)
 # where a degraded read's wall goes. Of a cold reconstruct: survivor_read
 # (the gathers of survivor fetches), executor_wait (submit -> the worker's
 # first line), decode (the worker's wall around reconstruct_rows),

@@ -36,10 +36,17 @@ def _shards(base: str) -> list:
     return out
 
 
-def test_multi_device_batch_path_matches_oracle(tmp_path):
-    """The shared-wide-batch streaming path (is_device codecs) must be
-    byte-identical to per-volume encodes across mixed geometries."""
+@pytest.mark.parametrize("route", ["in_turn", "mesh"])
+def test_multi_device_batch_path_matches_oracle(tmp_path, route):
+    """A device codec's batch — its volumes in turn through the streamed
+    pipeline, or shared wide batches over a mesh — must be byte-identical
+    to per-volume encodes across mixed geometries."""
+    import jax
+
     from seaweedfs_tpu.ops.rs_kernel import TpuRSCodec
+    from seaweedfs_tpu.parallel.sharded_ec import make_mesh
+
+    mesh = make_mesh(devices=jax.devices("cpu")) if route == "mesh" else None
 
     sizes = [
         LARGE * 10 * 2 + SMALL * 10 * 2 + 333,
@@ -64,7 +71,7 @@ def test_multi_device_batch_path_matches_oracle(tmp_path):
     assert getattr(codec, "is_device", False)
     write_ec_files_multi(
         multis, codec=codec,
-        large_block_size=LARGE, small_block_size=SMALL,
+        large_block_size=LARGE, small_block_size=SMALL, mesh=mesh,
     )
     for s, m, size in zip(singles, multis, sizes):
         assert _shards(m) == _shards(s), size
