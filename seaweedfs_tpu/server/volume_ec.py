@@ -36,6 +36,7 @@ from ..util.metrics import (
     EC_ENCODE_BYTES,
     EC_GENERATE_SECONDS,
     EC_RECONSTRUCTIONS,
+    EC_REMOTE_ATTEMPTS,
     RETRY_COUNTER,
 )
 from ..storage.erasure_coding import (
@@ -62,7 +63,14 @@ from ..storage.volume import volume_base_name
 from ..storage.volume_info import VolumeInfo, save_volume_info
 from ..types import TOMBSTONE_FILE_SIZE, to_actual_offset
 
-SHARD_LOCATION_TTL = 10.0  # seconds between LookupEcVolume refreshes
+# seconds between LookupEcVolume refreshes; upstream keeps the table 11 s
+# while fewer than 10 shards are known (ref store_ec.go:218-259)
+SHARD_LOCATION_TTL = 10.0
+
+
+def _locations_fresh(ev: EcVolume) -> bool:
+    """The location table is the master's answer of the last TTL."""
+    return time.time() - ev.shard_locations_refresh_time < SHARD_LOCATION_TTL
 
 
 def _read_stage(label: str, annotate: bool = True):
@@ -80,11 +88,13 @@ def _read_stage(label: str, annotate: bool = True):
 # survivor_read is the wall of the gathers (its leaf, each synchronous
 # shard pread, is the `ec.read.pread` event), executor_wait runs from
 # run_in_executor to the worker's first line, decode is the worker's wall
-# around reconstruct_rows (its leaves are the codec's `rs.*` events)
-# before a reconstruct is tried at all: the location refreshes (forced
-# LookupEcVolume calls to the master, EC_REFRESH_ROUNDS of them) and the
-# remote holders asked for a shard this server does not hold — paid by
-# every degraded read, a hit in the interval cache included
+# around reconstruct_rows (its leaves are the codec's `rs.*` events).
+# Before a reconstruct is tried at all, remote_attempts: the TTL'd
+# location refresh (one LookupEcVolume a SHARD_LOCATION_TTL, a dict look-up
+# otherwise) and, only where the table names a holder of the shard, the
+# holders asked and the forced refreshes after they failed. A shard the
+# fresh table gives nobody costs a read the look-up alone; how often that
+# is, is ec_remote_attempts_total{outcome}
 _ST_REMOTE_ATTEMPTS = _read_stage("remote_attempts", annotate=False)
 _ST_SURVIVOR_READ = _read_stage("survivor_read", annotate=False)
 _ST_PREAD = trace.stage("ec.read.pread")
@@ -101,8 +111,9 @@ EC_READ_DEADLINE_SECONDS = float(
 # per-url remote-read retry: quick second chance for transient resets; the
 # deadline, not the attempt count, is the real bound
 EC_REMOTE_READ_POLICY = BackoffPolicy(base=0.02, cap=0.25, attempts=2)
-# rounds of (force-refresh locations, re-attempt remote reads) before
-# falling back to reconstruction — replaces the old single force-refresh
+# rounds of (force-refresh locations, re-attempt remote reads) after every
+# LISTED holder failed, before falling back to reconstruction: the list may
+# be stale. A shard nobody is listed for never starts them
 EC_REFRESH_ROUNDS = 2
 
 # degraded-read interval cache: reconstructed spans kept per server so
@@ -760,9 +771,23 @@ class EcHandlers:
     async def _refresh_shard_locations(
         self, ev: EcVolume, force: bool = False
     ) -> None:
-        now = time.time()
-        if not force and now - ev.shard_locations_refresh_time < SHARD_LOCATION_TTL:
+        """Bring ev.shard_locations up to the master's answer: unforced
+        only once the table is older than SHARD_LOCATION_TTL. At most one
+        LookupEcVolume is in flight a volume: a caller that arrives while
+        one is out awaits its answer instead of sending its own."""
+        if not force and _locations_fresh(ev):
             return
+        lookup = ev.shard_locations_lookup
+        if lookup is None or lookup.done():
+            lookup = ev.shard_locations_lookup = asyncio.ensure_future(
+                self._lookup_shard_locations(ev)
+            )
+        # shielded: a caller that is cancelled (its client hung up) must not
+        # take the answer away from the others waiting on it
+        await asyncio.shield(lookup)
+
+    async def _lookup_shard_locations(self, ev: EcVolume) -> None:
+        now = time.time()
         stub = Stub(grpc_address(self.master), "master")
         try:
             resp = await stub.call("LookupEcVolume", {"volume_id": ev.volume_id})
@@ -777,6 +802,15 @@ class EcHandlers:
                     l["url"] for l in entry["locations"]
                 ]
             ev.shard_locations_refresh_time = now
+
+    def _remote_holders(self, ev: EcVolume, shard_id: int) -> list[str]:
+        """Who the location table names for the shard, less this server."""
+        with ev.shard_locations_lock:
+            return [
+                url
+                for url in ev.shard_locations.get(shard_id, ())
+                if url not in (self.address, self.public_url)
+            ]
 
     class _Deleted(Exception):
         """Needle tombstoned on a remote holder: a definitive answer, not
@@ -819,13 +853,9 @@ class EcHandlers:
         get one jittered retry, and every RPC's timeout is the remaining
         read deadline (a stalled holder can no longer eat a bare 30s of a
         15s read budget). Raises _Deleted on a tombstone answer."""
-        with ev.shard_locations_lock:
-            urls = list(ev.shard_locations.get(shard_id, []))
         rng = getattr(self, "_backoff_rng", None)
         budget = shared_retry_budget()
-        for url in urls:
-            if url in (self.address, self.public_url):
-                continue
+        for url in self._remote_holders(ev, shard_id):
             for attempt in range(EC_REMOTE_READ_POLICY.attempts):
                 if deadline is not None and time.monotonic() >= deadline:
                     return None
@@ -886,27 +916,50 @@ class EcHandlers:
             deadline = deadline_after(EC_READ_DEADLINE_SECONDS)
         with _ST_REMOTE_ATTEMPTS():
             await self._refresh_shard_locations(ev)
+            nobody = _locations_fresh(ev) and not self._remote_holders(
+                ev, shard_id
+            )
+        if nobody:
+            # the master's fresh answer names nobody to ask: asking it again
+            # cannot name one (ref store_ec.go:211-259 never looks up again
+            # for want of a holder). One that appears is seen at the next
+            # TTL refresh; until then reads reconstruct
+            data = await self._recover_one_interval(
+                ev, shard_id, offset, size, file_key, deadline
+            )
+            if data is not None:
+                EC_REMOTE_ATTEMPTS.inc(outcome="no_holder")
+                return data
+            # short of survivors as well: the table is up to a TTL old, and
+            # a holder of this shard or of a survivor may have come back
+            # since. What is left is the path of a stale list
+        with _ST_REMOTE_ATTEMPTS():
             try:
                 data = await self._read_remote_shard_interval(
                     ev, shard_id, offset, size, file_key, deadline
                 )
-                if data is not None:
-                    return data
-                # the cached locations may be stale (ref store_ec.go:211
-                # forgets failed shard locations); force-refresh and retry
-                # in bounded rounds while the deadline allows
+                # every listed holder failed (or the table could not be
+                # refreshed): the cached locations may be stale (ref
+                # store_ec.go:211 forgets failed shard locations);
+                # force-refresh and retry in bounded rounds while the
+                # deadline allows
                 for _ in range(EC_REFRESH_ROUNDS):
-                    if time.monotonic() >= deadline:
+                    if data is not None or time.monotonic() >= deadline:
                         break
                     RETRY_COUNTER.inc(op="ec_location_refresh")
                     await self._refresh_shard_locations(ev, force=True)
                     data = await self._read_remote_shard_interval(
                         ev, shard_id, offset, size, file_key, deadline
                     )
-                    if data is not None:
-                        return data
             except EcHandlers._Deleted:
+                # a holder's definitive answer: the needle is gone
+                EC_REMOTE_ATTEMPTS.inc(outcome="served")
                 return None
+            EC_REMOTE_ATTEMPTS.inc(
+                outcome="failed" if data is None else "served"
+            )
+            if data is not None:
+                return data
         # degraded: reconstruct from any DATA_SHARDS_COUNT other shards
         # (ref store_ec.go:319-373)
         return await self._recover_one_interval(

@@ -199,6 +199,9 @@ class EcVolume:
         self.shard_locations: dict[int, list[str]] = {}
         self.shard_locations_lock = threading.RLock()
         self.shard_locations_refresh_time = 0.0
+        # the LookupEcVolume now out for this volume, if any (an asyncio
+        # future of the volume server's loop): refreshes share its answer
+        self.shard_locations_lookup = None
         # device-resident .ecx snapshot for bulk probes; invalidated on
         # tombstone writes (see bulk_locate)
         from ...ops.snapshot_cache import SnapshotCache

@@ -549,9 +549,20 @@ EC_ENCODE_BATCH_FALLBACKS = REGISTRY.counter(
 # reconstruct, hit or cold: remote_attempts
 EC_DEGRADED_READ_STAGE_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_ec_degraded_read_stage_seconds_total",
-    "degraded EC read stage wall seconds, by stage (remote_attempts = "
-    "location refreshes + remote holders tried before any reconstruct; of "
+    "degraded EC read stage wall seconds, by stage (remote_attempts = the "
+    "TTL'd location refresh, and where a holder is listed the holders "
+    "tried and the forced refreshes after them, before any reconstruct; of "
     "a cold reconstruct: survivor_read/executor_wait/decode/cache_put)",
+)
+# once for every EC interval that got past the local shard and the cold
+# tier: was there anyone to ask for it, and did they answer
+EC_REMOTE_ATTEMPTS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_remote_attempts_total",
+    "EC interval reads of a shard this server does not hold, by outcome "
+    "(no_holder = the fresh location table names nobody and the interval "
+    "was reconstructed at once; served = a listed holder answered; failed "
+    "= holders listed and none answered after the forced refreshes, or "
+    "the table could not be refreshed: on to reconstruct)",
 )
 EC_REBUILD_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_ec_rebuild_stage_seconds",

@@ -195,6 +195,8 @@ def test_metrics_hygiene_lint():
         "seaweedfs_tpu_startup_seconds",
     ):
         assert family in names, f"stage family {family} not registered"
+    # how often a degraded read had a holder to ask (ISSUE 29)
+    assert "seaweedfs_tpu_ec_remote_attempts_total" in names
     # defined, never set or read: gone with ISSUE 26
     for family in (
         "seaweedfs_tpu_volumes",
