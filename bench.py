@@ -1865,17 +1865,14 @@ def measure_encode_e2e(size_bytes: int = 4 << 30, emit=None):
 
         def run_ref():
             write_ec_files(
-                base, codec=cpu_codec, chunk=256 * 1024,
-                pipeline=False, splice_data=False, mmap_input=False,
+                base, codec=cpu_codec, chunk=256 * 1024, splice_data=False,
             )
 
         def run_best():
-            from seaweedfs_tpu.storage.erasure_coding import encoder as _enc
-
-            write_ec_files(base, codec=best)
-            result["best_route"] = dict(_enc.LAST_ROUTE)
+            run = write_ec_files(base, codec=best)
+            result["best_route"] = dict(run.route)
             result["best_stages"] = {
-                k: round(v, 3) for k, v in _enc.LAST_STAGES.items()
+                k: round(v, 3) for k, v in run.stages().items()
             }
 
         golden = None
@@ -1935,23 +1932,20 @@ def measure_encode_e2e(size_bytes: int = 4 << 30, emit=None):
         # first-call jit/table setup stays out of the timed window
         warm = getattr(tpu_codec, "pipeline_encode", tpu_codec.encode)
         warm(np.zeros((10, tpu_codec.preferred_chunk), np.uint8))
-        from seaweedfs_tpu.storage.erasure_coding import encoder as _enc
-
         t0 = time.perf_counter()
-        write_ec_files(base, codec=tpu_codec)
+        run = write_ec_files(base, codec=tpu_codec)
         result["tpu_gbps"] = tpu_size / (time.perf_counter() - t0) / 1e9
         result["tpu_size_bytes"] = tpu_size
         result["tpu_stages"] = {
-            k: round(v, 3) for k, v in _enc.LAST_STAGES.items()
+            k: round(v, 3) for k, v in run.stages().items()
         }
-        result["tpu_route"] = dict(_enc.LAST_ROUTE)
+        result["tpu_route"] = dict(run.route)
         result["device_status"] = _device_status()
         tpu_samples = _shard_samples(base)
         _rm_shards(base)
         if golden is None:
             write_ec_files(
-                base, codec=cpu_codec, chunk=256 * 1024,
-                pipeline=False, splice_data=False, mmap_input=False,
+                base, codec=cpu_codec, chunk=256 * 1024, splice_data=False,
             )
             golden = _shard_samples(base)
             _rm_shards(base)
@@ -5842,15 +5836,14 @@ def _e2e_results(r: dict) -> list:
             total = stages.get("total_s") or sum(
                 v for k, v in stages.items() if k.endswith("_s")
             )
-            kern = stages.get("kernel_s", stages.get("fused_s", 0.0))
+            kern = stages.get("kernel_s", 0.0)
             entry["stage_breakdown"] = {
                 **stages,
                 "kernel_share": round(kern / max(total, 1e-9), 3),
                 "note": (
-                    "fused_s = single-sweep native route (read/encode/"
-                    "write interleaved, not separable); on the mmap route "
-                    ".dat page-fault reads land inside kernel_s/"
-                    "shard_write_s, so kernel_share is an UPPER bound on "
+                    "the .dat is read through a mapping, so its page-fault "
+                    "reads land inside kernel_s/write_s and kernel_share "
+                    "is an UPPER bound on "
                     "the kernel's true share; ecx_s=0 because "
                     "write_ec_files never writes .ecx (that belongs to "
                     "volume->EC conversion). kernel_share < ~0.5 means "
@@ -5867,8 +5860,7 @@ def _e2e_results(r: dict) -> list:
             # memcpy_equiv_per_byte ~5 looked like headroom that file IO
             # physics doesn't actually offer. Two bounds, route-aware:
             # every route reads the source once and fresh-writes parity;
-            # a route that fresh-writes data shards too (onepass/inline)
-            # pays 1.4/W, one that splices them kernel-side pays ~1.0/W
+            # a run that fresh-writes data shards too (inline) pays 1.4/W, one that splices them kernel-side pays ~1.0/W
             # of kernel copy + 1.4/memcpy of encode passes instead.
             R, W = legs["read_gbps"], legs["fresh_write_gbps"]
             mem_bw = r.get("host_memcpy_gbps") or 8.0

@@ -31,12 +31,11 @@ class TpuRSCodec:
     arrays (the storage pipeline writes them straight to shard files).
     """
 
-    # the EC file pipeline overlaps disk IO with device encode for this
-    # codec (upload + kernel + download per chunk are pipelined stages);
-    # large chunks amortize per-dispatch/transfer latency
-    prefers_pipeline = True
+    # the EC file pipeline overlaps disk IO with device encode (upload +
+    # kernel + download per chunk are pipelined stages); large chunks
+    # amortize per-dispatch/transfer latency
     preferred_chunk = 16 * 1024 * 1024
-    is_device = True  # multi-volume encode batches pieces into wide dispatches
+    is_device = True
 
     def __init__(
         self,
@@ -64,7 +63,8 @@ class TpuRSCodec:
         the jax path is then the best host kernel we have). On a real
         TPU this is never consulted. The pipeline structure (staging
         ring, overlap, stage walls) is identical either way; only the
-        kernel stage's executor differs, and LAST_ROUTE discloses it."""
+        kernel stage's executor differs, and the run's route discloses it
+        (`kernel`)."""
         if self._standin is None:
             try:
                 from ..storage.erasure_coding.coder_native import (

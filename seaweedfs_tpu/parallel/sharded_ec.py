@@ -60,9 +60,8 @@ def _encode_packed(matrix: np.ndarray, packed):
 
 
 # per device id: [bytes held, of which zero padding of the vol axis], summed
-# over the inputs and outputs of every sharded call since the last clear()
-# — the multi-chip mirror of the encode pipeline's LAST_STAGES: a
-# diagnostic that shows "everything on the first device" or "half the mesh
+# over the inputs and outputs of every sharded call since the last clear():
+# a diagnostic that shows "everything on the first device" or "half the mesh
 # encodes zeros" from outside, not part of the encode contract
 DEVICE_BYTES: dict = {}
 

@@ -299,14 +299,15 @@ class EcHandlers:
 
     async def _grpc_ec_generate_batch(self, req, context) -> dict:
         """Batched multi-volume encode: all requested local volumes are
-        converted by ONE call of write_ec_files_multi, which picks the route
-        (a device codec's volumes one after another through the streamed
-        pipeline; a host codec's across cores). Our extension; the reference encodes volumes serially, one
+        converted by ONE call of write_ec_files_multi, each through the
+        encode pipeline (write_ec_files): a device codec's one after
+        another, a host codec's at once.
+        Our extension; the reference encodes volumes serially, one
         RPC each (command_ec_encode.go:110-135). Returns per-volume errors
         keyed by id; volumes absent from `errors` succeeded.
 
-        What the one-volume RPC guarantees holds here: the streamed
-        pipeline's shard files appear under their final names only when
+        What the one-volume RPC guarantees holds here: the shard files
+        appear under their final names only when
         whole (commit by rename, as in write_ec_files), every volume's
         bytes are counted under the backend of the run that encoded THEM,
         and a batch that fails is counted

@@ -672,9 +672,9 @@ async def cmd_ec_encode(env, argv) -> str:
         )
     results = []
     # volumes co-located on one node are converted by ONE call
-    # (VolumeEcShardsGenerateBatch -> write_ec_files_multi, which picks
-    # the route: a device codec's one after another, a host codec's
-    # across cores) instead of one RPC each
+    # (VolumeEcShardsGenerateBatch -> write_ec_files_multi: each through
+    # the one encode pipeline, a device codec's one after another, a host
+    # codec's at once) instead of one RPC each
     by_source: dict = {}
     for vid in vids:
         source = None

@@ -33,9 +33,7 @@ class NativeRSCodec(CpuRSCodec):
         self._native = native
         from ...util import available_cpus
 
-        ncpu = available_cpus()
-        self.prefers_pipeline = ncpu > 1
-        self.pipeline_workers = max(2, min(8, ncpu))
+        self.pipeline_workers = max(2, min(8, available_cpus()))
 
     def _mat_apply(self, m: np.ndarray, data: np.ndarray) -> np.ndarray:
         return self._native.gf_matmul_native(m, data)

@@ -46,26 +46,6 @@ from ..super_block import SuperBlock
 
 DEFAULT_CHUNK = 4 * 1024 * 1024  # per-shard streaming chunk
 
-# the last write_ec_files run's route, {"route": ..., "spliced": bool}:
-# benchmark/diagnostic introspection, not part of the encode contract.
-# Assigned once when a run ends, from the run's own EncodeRun (which
-# write_ec_files returns: concurrent runs read their own, not this)
-LAST_ROUTE: dict = {}
-
-# per-stage wall seconds of the last write_ec_files run, assigned once
-# when it ends from its EncodeRun. The synchronous routes fill read_s /
-# kernel_s / shard_write_s (or fused/splice where stages aren't
-# separable). The STREAMED pipeline route fills read_s / stage_s
-# (= slot_wait_s + submit_s) / kernel_s / write_s / sync_s plus
-# parity_wait_s, pipeline_depth and coverage_of_wall: read/stage/sync are
-# main-thread walls that PARTITION the run (their sum over total_s is the
-# disclosed coverage), while kernel_s (pool) and write_s / parity_wait_s
-# (the ordering writer thread) are overlapped walls whose ratio to total_s
-# discloses overlap efficiency; write_thread_s is summed over every
-# thread that wrote shard files.
-LAST_STAGES: dict = {}
-
-
 class EncodeRun:
     """What one write_ec_files run took and which way it went: the sink
     of the run's stages (util/trace.Stage), added to from the main, pool
@@ -87,15 +67,18 @@ class EncodeRun:
             return sum(self._seconds.get(k, 0.0) for k in labels)
 
     def stages(self) -> dict:
-        """The run's budget under LAST_STAGES' keys."""
+        """The run's budget: `<stage>_s` wall seconds, stage_s (= slot_wait_s
+        + submit_s) and the extras. read / stage / sync / splice are
+        main-thread walls that PARTITION the run (their sum over total_s is
+        coverage_of_wall); kernel_s (pool) and write_s / parity_wait_s (the
+        ordering writer thread) are overlapped walls; write_thread_s is
+        summed over every thread that wrote shard files."""
         with self._lock:
             out = {k + "_s": v for k, v in self._seconds.items()}
         if "slot_wait_s" in out or "submit_s" in out:
             out["stage_s"] = out.get("slot_wait_s", 0.0) + out.get(
                 "submit_s", 0.0
             )
-        if self.route.get("route") != "pipeline" and "write_s" in out:
-            out["shard_write_s"] = out.pop("write_s")
         out.update(self.extra)
         return out
 
@@ -136,17 +119,16 @@ _ST_SYNC_DRAIN = trace.stage(
 _BATCH_PIECES = EC_ENCODE_BATCH_PIECES.child()
 
 # per-stage wall seconds of the last rebuild_ec_files run (read_s /
-# decode_s / write_s / total_s) — the repair-plane mirror of LAST_STAGES.
-# On the pipelined route the stages OVERLAP (decode_s is worker wall while
-# the main thread reads/writes), so their sum can exceed total_s; each
-# stage is still individually honest. Not synchronized across concurrent
+# decode_s / write_s / total_s). On the pipelined route the stages
+# OVERLAP (decode_s is worker wall while the main thread reads/writes),
+# so their sum can exceed total_s; each stage is still individually
+# honest. Not synchronized across concurrent
 # rebuild_ec_files_multi volumes.
 LAST_REBUILD_STAGES: dict = {}
 _REBUILD_STAGE_LOCK = threading.Lock()
 
 # which structure the last rebuild_ec_files run took ("mmap" zero-copy
-# survivor maps / "pread" buffered reads, pipelined or not) — the repair
-# mirror of LAST_ROUTE
+# survivor maps / "pread" buffered reads, pipelined or not)
 LAST_REBUILD_ROUTE: dict = {}
 
 
@@ -231,98 +213,6 @@ def _read_exact(f, out: np.ndarray, offset: int) -> None:
         n += got
 
 
-def _encode_rows(
-    run: EncodeRun,
-    dat_f,
-    outputs,
-    codec,
-    start_offset: int,
-    block_size: int,
-    rows: int,
-    chunk: int,
-) -> None:
-    k = codec.data_shards
-    data = np.empty((k, chunk), dtype=np.uint8)
-    for row in range(rows):
-        row_start = start_offset + row * block_size * k
-        done = 0
-        while done < block_size:
-            this = min(chunk, block_size - done)
-            buf = data[:, :this] if this != chunk else data
-            with _ST_READ(run):
-                for i in range(k):
-                    _read_into(
-                        dat_f, buf[i], row_start + i * block_size + done
-                    )
-            with _ST_KERNEL(run):
-                parity = codec.encode(buf)
-            # contiguous-row memoryviews: BufferedWriter copies synchronously,
-            # so reusing `data` next iteration is safe and we skip a tobytes()
-            # copy of every byte written
-            with _ST_WRITE(run):
-                for i in range(k):
-                    if outputs[i] is not None:
-                        outputs[i].write(buf[i].data)
-                for p in range(codec.parity_shards):
-                    outputs[k + p].write(np.ascontiguousarray(parity[p]).data)
-            done += this
-
-
-def _encode_rows_mmap(
-    run: EncodeRun,
-    arr: np.ndarray,
-    outputs,
-    codec,
-    start_offset: int,
-    block_size: int,
-    rows: int,
-    chunk: int,
-) -> None:
-    """Same bytes as _encode_rows, with the .dat mmapped: data rows are
-    zero-copy views into the page cache handed to the codec as row pointers
-    (NativeRSCodec.encode_rows), and data-shard writes (when not spliced)
-    stream straight from the map. Only EOF-straddling tails get copied into
-    a scratch row. The single-core replacement for the reference's
-    read-copy-everything loop (ref ec_encoder.go:120-136)."""
-    k = codec.data_shards
-    dat_size = arr.size
-    scratch = np.empty((k, chunk), dtype=np.uint8)
-    zeros = np.zeros(chunk, dtype=np.uint8)
-    for row in range(rows):
-        row_start = start_offset + row * block_size * k
-        done = 0
-        while done < block_size:
-            this = min(chunk, block_size - done)
-            # on this mmapped route the .dat "read" is page faults taken
-            # INSIDE the kernel stage (encode touches the map) and the
-            # write stage (data shards stream from the map); the read
-            # stage only covers the view assembly + EOF-tail copies
-            with _ST_READ(run):
-                rows_v = []
-                for i in range(k):
-                    off = row_start + i * block_size + done
-                    end = off + this
-                    if off >= dat_size:
-                        rows_v.append(zeros[:this])
-                    elif end <= dat_size:
-                        rows_v.append(arr[off:end])
-                    else:
-                        s = scratch[i, :this]
-                        n = dat_size - off
-                        s[:n] = arr[off:dat_size]
-                        s[n:] = 0
-                        rows_v.append(s)
-            with _ST_KERNEL(run):
-                parity = np.ascontiguousarray(codec.encode_rows(rows_v))
-            with _ST_WRITE(run):
-                for i in range(k):
-                    if outputs[i] is not None:
-                        outputs[i].write(rows_v[i].data)
-                for p in range(codec.parity_shards):
-                    outputs[k + p].write(parity[p].data)
-            done += this
-
-
 def _stream_items(
     n_large: int, large_block: int, n_small: int, small_block: int,
     chunk: int, k: int, group: bool = True,
@@ -395,22 +285,19 @@ def _encode_streamed(
     splice_data,
     dat_path: str,
 ) -> tuple[bool, str, int]:
-    """The streamed, depth-N double-buffered encode pipeline (the route the
-    device codec prefers; any codec runs it with pipeline=True).
+    """The streamed, depth-N double-buffered encode pipeline: the one way
+    a .dat becomes shard files, whatever the codec.
 
     Chunked reads of the .dat feed a bounded ring of depth+2 REUSED host
     staging slots (the pinned-buffer pool a real device runtime would
-    register for DMA). Two input routes feed the ring:
-
-    - mmap (default when the host route race hasn't proven pread faster):
-      each chunk is a zero-copy strided (k, width) VIEW of the mapping —
-      per-shard rows are contiguous segments `block` apart — prefetched
-      with madvise(WILLNEED) one item ahead so page population overlaps
-      compute; the ring slot is then only a backpressure token. Only an
-      item whose source region crosses EOF stages through a copy (it needs
-      the zero tail materialized).
-    - preadv: every chunk is copied into a staging slot (no mapping
-      available, or calibration proved the guest fault path slow).
+    register for DMA). The input is the mapping of the .dat: each chunk is
+    a zero-copy strided (k, width) VIEW of it — per-shard rows are
+    contiguous segments `block` apart — prefetched with madvise(WILLNEED)
+    one item ahead so page population overlaps compute; the ring slot is
+    then only a backpressure token. Only an item whose source region
+    crosses EOF stages through a copy (it needs the zero tail
+    materialized). Where mmap refuses (an empty .dat, a file that cannot
+    be mapped) every chunk is copied into a staging slot with preadv.
 
     Each chunk's kernel dispatch (host->device upload + matmul + download,
     or the host-kernel dispatch the codec substitutes on the CPU stand-in)
@@ -465,9 +352,7 @@ def _encode_streamed(
         dat_size = 0
     mm = None
     mm_arr = None
-    # calibration ('sync' = pread beat everything mmap-backed on this
-    # host's fault path) is the only reason to copy when a mapping works
-    if dat_size > 0 and _HOST_ROUTE != "sync":
+    if dat_size > 0:
         try:
             mm = mmap_mod.mmap(
                 dat_f.fileno(), 0, access=mmap_mod.ACCESS_READ
@@ -727,273 +612,6 @@ def _fs_type_of(path: str) -> str:
         return ""  # unparsable mount table: let the splice heuristic pass
 
 
-_HOST_ROUTE: Optional[str] = None
-_ROUTE_LOCK = threading.Lock()
-_CALIBRATING = False
-
-
-def _calibrate_host_route(codec) -> Optional[str]:
-    """Race the host encode structures once per process and remember the
-    winner: 'onepass' (fused NT-store mmap outputs), 'mmap' (zero-copy
-    mmapped source + write() outputs), or 'sync' (pread + write()).
-
-    Why measure instead of infer: the ranking is hardware-dependent in
-    ways no cheap probe predicts — on bare metal the one-pass route's
-    halved memory traffic wins; on hypervisors with a slow guest fault
-    path, anything mmap-backed degrades (measured 0.37-5 GB/s page
-    population ON THE SAME VM depending on load) while pread stays flat.
-    One ~100MB interleaved race (<1s, cached for the process) picks
-    reliably where a point probe flip-flops. Serialized by a lock so
-    write_ec_files_multi's thread pool cannot run N contending races and
-    cache a contention-skewed winner; returns None (caller defaults to
-    plain flags) from a re-entrant call — the race's own legs must not
-    re-calibrate."""
-    global _HOST_ROUTE, _CALIBRATING
-    if _HOST_ROUTE is not None:
-        return _HOST_ROUTE
-    if _CALIBRATING:
-        return None  # a calibration leg re-entered (e.g. onepass's own
-        # mmap-flag resolution): run with plain defaults
-    with _ROUTE_LOCK:
-        if _HOST_ROUTE is not None:
-            return _HOST_ROUTE
-        _CALIBRATING = True
-        try:
-            return _run_route_race(codec)
-        finally:
-            _CALIBRATING = False
-
-
-def _run_route_race(codec) -> str:
-    global _HOST_ROUTE
-    import shutil
-    import tempfile
-    import time
-
-    from ... import native
-
-    size = 96 << 20
-    # peak usage: the .dat + one route's full shard set
-    needed = size * 5 // 2
-    use_dir = None
-    if os.path.isdir("/dev/shm"):
-        try:
-            if shutil.disk_usage("/dev/shm").free >= needed:
-                use_dir = "/dev/shm"
-        except OSError:
-            pass
-    if use_dir is None:
-        # constrained /dev/shm (e.g. Docker's 64MB default): race on the
-        # default tmp dir instead of silently pinning a slow route
-        try:
-            if shutil.disk_usage(tempfile.gettempdir()).free < needed:
-                size = 16 << 20  # still measure, just smaller
-        except OSError:
-            pass
-    # each leg runs exactly the structure production would (splice left to
-    # its own try-and-fall-back default, so spliced shards count for the
-    # routes that can splice)
-    routes = {
-        "sync": dict(pipeline=False, mmap_input=False, onepass=False),
-        "mmap": dict(pipeline=False, mmap_input=True, onepass=False),
-    }
-    if native.encode_copy_available():
-        routes["onepass"] = dict(onepass=True)
-    d = None
-    try:
-        d = tempfile.mkdtemp(prefix="ec_route_cal_", dir=use_dir)
-        base = os.path.join(d, "c")
-        block = b"\xa5\x5a\xc3" * (1 << 20)
-        with open(base + ".dat", "wb") as f:
-            left = size
-            while left > 0:
-                f.write(block[: min(left, len(block))])
-                left -= len(block)
-        best = ("sync", 0.0)
-        names = list(routes)
-        for rep in range(2):
-            for name in names if rep % 2 == 0 else names[::-1]:
-                for i in range(codec.total_shards):
-                    try:
-                        os.remove(base + to_ext(i))
-                    except OSError:
-                        pass
-                t0 = time.perf_counter()
-                try:
-                    write_ec_files(base, codec=codec, **routes[name])
-                except Exception:
-                    continue
-                g = size / max(time.perf_counter() - t0, 1e-9)
-                if g > best[1]:
-                    best = (name, g)
-        _HOST_ROUTE = best[0]
-    except Exception:
-        _HOST_ROUTE = "sync"
-    finally:
-        if d is not None:
-            shutil.rmtree(d, ignore_errors=True)
-    return _HOST_ROUTE
-
-
-def _encode_onepass(
-    base_file_name: str,
-    dat_path: str,
-    codec,
-    dat_size: int,
-    n_large: int,
-    large_block: int,
-    n_small: int,
-    small_block: int,
-    chunk: int = 4 * 1024 * 1024,
-) -> bool:
-    """Fused single-pass encode: ONE streaming read of the .dat produces all
-    14 shards — each 64-byte column is copied to its data-shard file AND
-    folded into the four parity accumulators in the same pass, with
-    non-temporal stores straight into the mmapped outputs (no RFO traffic,
-    no user->kernel write copies). Memory traffic per source byte drops from
-    ~4.8 (read + buffered data write + parity read-modify-write) to ~2.4,
-    which is the difference on bandwidth-bound hosts.
-
-    Source regions past EOF become file holes (zeros — byte-identical to
-    the written form). Returns False when the native fused kernel is
-    unavailable; the caller falls back to the split read/encode/write paths.
-    The reference streams every byte through a user-space 256KB buffer
-    instead (ref ec_encoder.go:57-58,120-136).
-
-    Multicore hosts split the chunk list across a small thread pool — the
-    native call releases the GIL and every (row, chunk) region is disjoint.
-    """
-    from ... import native
-
-    if not native.encode_copy_available():
-        return False
-    k = codec.data_shards
-    p = codec.parity_shards
-    if p > 8 or k > 32:
-        # the C kernel's register blocking caps the fused path (gf256.cpp
-        # kRowBlock / mats[]); wider geometries take the split paths
-        return False
-    matrix = np.ascontiguousarray(codec.parity_matrix, dtype=np.uint8)
-    shard_size = n_large * large_block + n_small * small_block
-    if shard_size == 0 or dat_size == 0:
-        return False
-
-    import mmap as mmap_mod
-
-    # (src_file_off, shard_off, block, length) per fused kernel call —
-    # shard j's source lives at src_off + j*block; the shard-local offset
-    # is row_start//k + done because every term of row_start carries a *k
-    def calls():
-        for row_start, block, done, width in _piece_iter(
-            n_large, large_block, n_small, small_block, chunk, k
-        ):
-            yield row_start + done, row_start // k + done, block, width
-
-    out_files = []
-    out_maps = []
-    aborted = False
-    dat_f = open(dat_path, "rb")
-    try:
-        dat_mm = mmap_mod.mmap(dat_f.fileno(), 0, access=mmap_mod.ACCESS_READ)
-        dat_arr = np.frombuffer(dat_mm, dtype=np.uint8)
-        src_base = int(dat_arr.ctypes.data)
-        out_arrs = []
-        for i in range(k + p):
-            f = open(base_file_name + to_ext(i), "wb+")
-            out_files.append(f)
-            # NT stores into the map fault pages in; without backing blocks
-            # that's a SIGBUS, not a catchable ENOSPC — reserve everything
-            # up front and fall back to the write() paths (which surface
-            # ENOSPC as OSError) when the reservation fails
-            try:
-                os.posix_fallocate(f.fileno(), 0, shard_size)
-            except OSError:
-                aborted = True
-                return False
-            mm = mmap_mod.mmap(
-                f.fileno(), shard_size, access=mmap_mod.ACCESS_WRITE
-            )
-            out_maps.append(mm)
-            out_arrs.append(np.frombuffer(mm, dtype=np.uint8))
-        out_base = [int(a.ctypes.data) for a in out_arrs]
-
-        def run_call(item):
-            src_off, dst_off, block, this = item
-            srcs = []
-            dsts = []
-            keep = []  # scratch rows alive across the native call
-            any_data = False
-            for j in range(k):
-                off = src_off + j * block
-                end = off + this
-                if off >= dat_size:
-                    srcs.append(None)
-                    dsts.append(None)
-                    continue
-                any_data = True
-                dsts.append(out_base[j] + dst_off)
-                if end <= dat_size:
-                    srcs.append(src_base + off)
-                else:  # EOF-straddling: zero-padded scratch row (rare —
-                    # at most one chunk per geometry section)
-                    s = np.zeros(this, dtype=np.uint8)
-                    nn = dat_size - off
-                    s[:nn] = dat_arr[off:dat_size]
-                    keep.append(s)
-                    srcs.append(int(s.ctypes.data))
-            if not any_data:
-                return  # all-zero columns: parity holes are correct zeros
-            parity = [out_base[k + r] + dst_off for r in range(p)]
-            ok = native.gf_encode_copy_native(matrix, srcs, dsts, parity, this)
-            if not ok:  # unreachable: geometry gated above, build probed
-                raise RuntimeError("fused encode kernel refused the call")
-
-        from ...util import available_cpus
-
-        ncpu = available_cpus()
-        items = list(calls())
-        if ncpu > 1 and len(items) > 1:
-            import concurrent.futures as cf
-
-            with cf.ThreadPoolExecutor(min(ncpu, 8)) as pool:
-                for f in [pool.submit(run_call, it) for it in items]:
-                    f.result()
-        else:
-            for item in items:
-                run_call(item)
-        return True
-    except Exception as e:
-        # anything unexpected mid-flight (mmap/scratch allocation under
-        # memory pressure, a SIGBUS-adjacent OSError...): remove the
-        # partial shards and let the proven split paths do the encode
-        from ...util.log import warning
-
-        warning("onepass encode aborted (%s); using split paths", e)
-        aborted = True
-        return False
-    finally:
-        out_arrs = None
-        dat_arr = None
-        for mm in out_maps:
-            try:
-                mm.close()
-            except (BufferError, ValueError):
-                pass
-        for f in out_files:
-            f.close()
-        try:
-            dat_mm.close()
-        except (BufferError, ValueError, NameError):
-            pass
-        dat_f.close()
-        if aborted:
-            for i in range(k + p):
-                try:
-                    os.remove(base_file_name + to_ext(i))
-                except OSError:
-                    pass
-
-
 def _splice_data_shards(
     dat_path: str,
     base_file_name: str,
@@ -1075,226 +693,70 @@ def write_ec_files(
     large_block_size: int = EC_LARGE_BLOCK_SIZE,
     small_block_size: int = EC_SMALL_BLOCK_SIZE,
     chunk: int = DEFAULT_CHUNK,
-    pipeline: Optional[bool] = None,
     splice_data: Optional[bool] = None,
-    mmap_input: Optional[bool] = None,
-    onepass: Optional[bool] = None,
 ) -> EncodeRun:
     """Generate .ec00-.ec13 from .dat (ref WriteEcFiles, ec_encoder.go:57).
 
-    pipeline=None follows the codec's preference: the TPU codec takes the
-    streamed depth-N double-buffered route (_encode_streamed: bounded ring
-    of reused staging buffers, overlapped read/kernel/write, in-order
-    .ecNN.tmp outputs renamed on success, stage budget in the returned
-    run); the CPU codec keeps the reference's synchronous
-    structure. The streamed route's chunk and depth are env-tunable:
-    SEAWEEDFS_TPU_EC_PIPELINE_CHUNK (bytes, default codec.preferred_chunk)
-    and SEAWEEDFS_TPU_EC_PIPELINE_DEPTH (default codec.pipeline_workers).
-    splice_data=None tries the kernel-side data-shard splice and falls
-    back to inline writes.
-    mmap_input=None picks the zero-copy mmapped-read path automatically
-    (row-pointer host codec, no pipeline); True forces it for a non-pipelined
-    host codec, False disables it.
+    One pipeline for every codec (device, native, numpy): _encode_streamed,
+    a bounded ring of reused staging buffers, overlapped read/kernel/write,
+    in-order .ecNN.tmp outputs renamed when the volume is whole. Its chunk
+    and depth are env-tunable: SEAWEEDFS_TPU_EC_PIPELINE_CHUNK (bytes,
+    default codec.preferred_chunk) and SEAWEEDFS_TPU_EC_PIPELINE_DEPTH
+    (default codec.pipeline_workers, else 2). splice_data=None tries the
+    kernel-side data-shard splice and falls back to inline writes; False
+    skips the attempt.
 
-    onepass=None routes a zero-copy host codec through the fused
-    single-pass native encoder (_encode_onepass: one .dat read, NT stores,
-    all 14 shards in one sweep) when nothing else was explicitly
-    configured; True forces the attempt, False disables it. Falls back to
-    the split paths when the fused kernel is unavailable.
-
-    With everything left at None on a zero-copy host codec, the structure
-    (onepass vs mmap vs pread) is picked by a one-time measured race on
-    this host (_calibrate_host_route) — the ranking is
-    hardware-dependent and point probes proved unreliable.
-
-    Returns the run's own EncodeRun (route taken, stage budget); the
-    module's LAST_ROUTE / LAST_STAGES are assigned from it once, at the end.
+    Returns the run's own EncodeRun: the route taken and the stage budget.
+    The .ecx is NOT written here (write_sorted_file_from_idx does that
+    during volume->EC conversion): `ecx_s` is 0 in the budget so that it
+    cannot be misread as omitted.
     """
-    global LAST_ROUTE, LAST_STAGES
-    run = EncodeRun()
-    try:
-        _write_ec_files(
-            run, base_file_name, codec, large_block_size, small_block_size,
-            chunk, pipeline, splice_data, mmap_input, onepass,
-        )
-    finally:
-        LAST_ROUTE, LAST_STAGES = run.route, run.stages()
-    return run
-
-
-def _write_ec_files(
-    run: EncodeRun,
-    base_file_name: str,
-    codec,
-    large_block_size: int,
-    small_block_size: int,
-    chunk: int,
-    pipeline: Optional[bool],
-    splice_data: Optional[bool],
-    mmap_input: Optional[bool],
-    onepass: Optional[bool],
-) -> None:
     import time as _time
 
-    _t_enter = _time.perf_counter()
+    t_enter = _time.perf_counter()
+    run = EncodeRun()
     codec = _get_codec(codec)
-    # structure flags left None = "pick for me", resolved PER FLAG from
-    # the calibrated route — an explicit pipeline=False or splice_data
-    # (e.g. write_ec_files_multi's per-volume host path) still gets the
-    # calibrated structure for the flags it didn't set
-    if pipeline is None:
-        pipeline = getattr(codec, "prefers_pipeline", False)
-    route = None
-    if (
-        (mmap_input is None or onepass is None)
-        and not pipeline
-        and getattr(codec, "zero_copy_rows", False)
-    ):
-        _t_cal = _time.perf_counter()
-        route = _calibrate_host_route(codec)
-        cal = _time.perf_counter() - _t_cal
-        if cal > 1e-3:
-            # first call per codec runs a measured race; disclose it so
-            # the stage sums still reconcile with total_s
-            run.extra["calibrate_s"] = round(cal, 3)
-    if onepass is None:
-        onepass = route == "onepass"
-    if mmap_input is None:
-        use_mmap = route == "mmap"
-    else:
-        use_mmap = (
-            mmap_input and not pipeline and hasattr(codec, "encode_rows")
-        )
-    if pipeline and chunk == DEFAULT_CHUNK:
+    if chunk == DEFAULT_CHUNK:
         chunk = _env_int(
             "SEAWEEDFS_TPU_EC_PIPELINE_CHUNK",
             getattr(codec, "preferred_chunk", chunk),
         )
-    k = codec.data_shards
+    depth = max(1, _env_int(
+        "SEAWEEDFS_TPU_EC_PIPELINE_DEPTH",
+        getattr(codec, "pipeline_workers", 2),
+    ))
     dat_path = base_file_name + ".dat"
-    dat_size = os.path.getsize(dat_path)
-    if dat_size == 0:
-        use_mmap = False
-
-    large_row = large_block_size * k
     n_large, n_small = _row_counts(
-        dat_size, k, large_block_size, small_block_size
+        os.path.getsize(dat_path), codec.data_shards,
+        large_block_size, small_block_size,
     )
-
-    if pipeline:
-        depth = max(1, _env_int(
-            "SEAWEEDFS_TPU_EC_PIPELINE_DEPTH",
-            getattr(codec, "pipeline_workers", 2),
-        ))
-        try:
-            with open(dat_path, "rb") as dat_f:
-                spliced, input_kind, writers = _encode_streamed(
-                    run, base_file_name, dat_f, codec,
-                    n_large, large_block_size, n_small, small_block_size,
-                    chunk, depth, splice_data, dat_path,
-                )
-            run.route = {
-                "route": "pipeline",
-                "spliced": spliced,
-                "input": input_kind,
-                "kernel": getattr(codec, "pipeline_dispatch_kind", "host"),
-                "pipeline_depth": depth,
-                "writers": writers,
-            }
-        finally:
-            total = _time.perf_counter() - _t_enter
-            run.extra["total_s"] = total
-            run.extra["pipeline_depth"] = depth
-            # coverage = the main-thread (blocking) stages over the wall:
-            # kernel/write are overlapped walls and deliberately NOT
-            # summed here — the PR 2 write-budget disclosure discipline
-            blocking = run.seconds(
-                "read", "slot_wait", "submit", "sync", "splice"
-            ) + run.extra.get("calibrate_s", 0.0)
-            run.extra["coverage_of_wall"] = round(
-                blocking / max(total, 1e-9), 3
-            )
-            run.extra["ecx_s"] = 0.0
-        return
-
-    if onepass and dat_size > 0:
-        if _encode_onepass(
-            base_file_name, dat_path, codec, dat_size,
-            n_large, large_block_size, n_small, small_block_size,
-            chunk=chunk,
-        ):
-            run.route = {"route": "onepass", "spliced": False}
-            # the fused native kernel interleaves read/encode/write in one
-            # sweep: stages aren't separable, disclose the fused total
-            fused = _time.perf_counter() - _t_enter
-            run.extra.update(fused_s=fused, total_s=fused, ecx_s=0.0)
-            return
-
-    spliced = False
-    if splice_data is None or splice_data:
-        # data shards carved kernel-side (copy_file_range/pwrite
-        # interleave): read+write of the data shards in one stage
-        with _ST_SPLICE(run):
-            spliced = _splice_data_shards(
-                dat_path, base_file_name, k,
-                n_large, large_block_size, n_small, small_block_size,
-            )
-    # introspection for benchmarks/diagnostics: which structure actually
-    # ran (the roofline model differs when data shards were spliced)
-    run.route = {
-        "route": "mmap" if use_mmap else "pread",
-        "spliced": spliced,
-    }
-
-    outputs = [
-        None if (spliced and i < k) else open(base_file_name + to_ext(i), "wb")
-        for i in range(codec.total_shards)
-    ]
     try:
         with open(dat_path, "rb") as dat_f:
-            small_chunk = min(chunk, small_block_size)
-            if use_mmap:
-                import mmap as mmap_mod
-
-                mm = None
-                arr = None
-                try:
-                    mm = mmap_mod.mmap(
-                        dat_f.fileno(), 0, access=mmap_mod.ACCESS_READ
-                    )
-                    arr = np.frombuffer(mm, dtype=np.uint8)
-                    _encode_rows_mmap(
-                        run, arr, outputs, codec, 0,
-                        large_block_size, n_large, chunk,
-                    )
-                    _encode_rows_mmap(
-                        run, arr, outputs, codec, n_large * large_row,
-                        small_block_size, n_small, small_chunk,
-                    )
-                finally:
-                    # drop the exported view before closing the map
-                    arr = None
-                    if mm is not None:
-                        mm.close()
-            else:
-                _encode_rows(
-                    run, dat_f, outputs, codec, 0,
-                    large_block_size, n_large, chunk,
-                )
-                _encode_rows(
-                    run, dat_f, outputs, codec, n_large * large_row,
-                    small_block_size, n_small, small_chunk,
-                )
+            spliced, input_kind, writers = _encode_streamed(
+                run, base_file_name, dat_f, codec,
+                n_large, large_block_size, n_small, small_block_size,
+                chunk, depth, splice_data, dat_path,
+            )
+        run.route = {
+            "route": "pipeline",
+            "spliced": spliced,
+            "input": input_kind,
+            "kernel": getattr(codec, "pipeline_dispatch_kind", "host"),
+            "pipeline_depth": depth,
+            "writers": writers,
+        }
     finally:
-        for f in outputs:
-            if f is not None:
-                f.close()
-        run.extra["total_s"] = _time.perf_counter() - _t_enter
-        # .ecx is NOT written here: write_ec_files produces .ec00-.ec13
-        # only (the sorted .ecx index comes from write_sorted_file_from_idx
-        # during volume->EC conversion) — stated so the stage breakdown
-        # can't be misread as omitting it
+        total = _time.perf_counter() - t_enter
+        run.extra["total_s"] = total
+        run.extra["pipeline_depth"] = depth
+        # coverage = the main-thread (blocking) stages over the wall:
+        # kernel/write are overlapped walls and deliberately NOT
+        # summed here — the PR 2 write-budget disclosure discipline
+        blocking = run.seconds("read", "slot_wait", "submit", "sync", "splice")
+        run.extra["coverage_of_wall"] = round(blocking / max(total, 1e-9), 3)
         run.extra["ecx_s"] = 0.0
+    return run
+
 
 
 def _row_counts(
@@ -1313,28 +775,6 @@ def _row_counts(
         n_small += 1
         remaining -= small_row
     return n_large, n_small
-
-
-def _piece_iter(
-    n_large: int,
-    large_block: int,
-    n_small: int,
-    small_block: int,
-    chunk: int,
-    k: int,
-):
-    """Yield (row_start, block_size, done, width) encode pieces in shard
-    stream order; a piece never spans a block boundary."""
-    processed = 0
-    for rows, block in ((n_large, large_block), (n_small, small_block)):
-        for row in range(rows):
-            row_start = processed + row * block * k
-            done = 0
-            while done < block:
-                width = min(chunk, block - done)
-                yield row_start, block, done, width
-                done += width
-        processed += rows * block * k
 
 
 def _mesh_encode(codec, mesh, buf: np.ndarray) -> np.ndarray:
@@ -1358,172 +798,82 @@ def _mesh_encode(codec, mesh, buf: np.ndarray) -> np.ndarray:
     return out[:, :n] if pad else out
 
 
+class _MeshCodec:
+    """`codec` with its parity computed over `mesh`: what
+    write_ec_files_multi(mesh=...) hands the pipeline. Everything but the
+    dispatch is the wrapped codec's."""
+
+    pipeline_dispatch_kind = "mesh"
+
+    def __init__(self, codec, mesh):
+        self._codec = codec
+        self._mesh = mesh
+
+    def __getattr__(self, name):
+        return getattr(self._codec, name)
+
+    def pipeline_encode(self, buf: np.ndarray) -> np.ndarray:
+        return _mesh_encode(self._codec, self._mesh, buf)
+
+
 def write_ec_files_multi(
     base_file_names,
     codec=None,
     large_block_size: int = EC_LARGE_BLOCK_SIZE,
     small_block_size: int = EC_SMALL_BLOCK_SIZE,
     chunk: int = DEFAULT_CHUNK,
-    workers: Optional[int] = None,
     mesh=None,
 ) -> list:
     """Encode MANY volumes' .dat files in one call (BASELINE.json config 3 —
     batched multi-volume ec.encode). Returns the EncodeRun that encoded
     each volume, in the order given: its route says which kernel ran.
 
-    A device codec's volumes go one after another through write_ec_files,
-    the streamed pipeline: each is committed by rename when it is whole,
-    with that route's stages and counters. Raced on the chip's host against
-    wide batches of four volumes' pieces (13 CPUs, shards to tmpfs, the
-    cell warm-rs10.4-maint.ec-encode-full4; PERF.md section 6, PR 28), in
-    turn won 7 of 7: a wide batch saves dispatches but has to be staged by
-    a copy, on a host whose writers are bound by the memory bus. A failure
-    raises with the runs of the volumes already whole as its `encoded`, so
-    a caller that falls back need not convert those again.
+    Every volume goes through write_ec_files, whatever the codec: each is
+    committed by rename when it is whole, with that pipeline's stages and
+    counters. A device codec's go one after another: raced on the chip's
+    host against wide batches of four volumes' pieces (13 CPUs, shards to
+    tmpfs, the cell warm-rs10.4-maint.ec-encode-full4; PERF.md section 6,
+    PR 28), in turn won 7 of 7: a wide batch saves dispatches but has to
+    be staged by a copy, on a host whose writers are bound by the memory
+    bus. A host codec's go at once, as many as there are CPUs: read by
+    hand on the same host and job (PERF.md section 6, PR 30), in turn was
+    16-19 % slower than at once had been. A failure raises with the runs
+    of the volumes before the first that failed as its `encoded`, so a
+    caller that falls back need not convert those again.
 
-    Host codecs encode whole volumes concurrently across cores (each on
-    the single-threaded zero-copy path).
-
-    A mesh takes shared wide encode batches, which are what it shards.
-    GF(2^8) parity is computed column-by-column, so pieces from different
-    volumes concatenated along the column axis and encoded in ONE call are
-    byte-identical to per-volume encodes. Each round takes the next piece
-    of every unfinished volume, groups by width, and pipelines read ->
-    batched encode -> ordered writes. No server path reaches this route;
-    it writes under the final names and has no stages (PERF.md section 7).
+    With a mesh, each dispatch's parity is computed over it (_mesh_encode);
+    the pipeline round the dispatch is the same.
     """
     import concurrent.futures as cf
-    from collections import deque
+
+    from ...util import available_cpus
 
     codec = _get_codec(codec)
-    k = codec.data_shards
     base_file_names = list(base_file_names)
 
-    if not getattr(codec, "is_device", False):
-        from ...util import available_cpus
-
-        n_workers = max(
-            1, min(len(base_file_names), workers or available_cpus())
+    def one(base: str) -> EncodeRun:
+        return write_ec_files(
+            base, codec=_MeshCodec(codec, mesh) if mesh is not None else codec,
+            large_block_size=large_block_size,
+            small_block_size=small_block_size,
+            chunk=chunk,
         )
 
-        def one(base: str) -> EncodeRun:
-            return write_ec_files(
-                base, codec=codec,
-                large_block_size=large_block_size,
-                small_block_size=small_block_size,
-                chunk=chunk, pipeline=False,
-            )
-
-        if n_workers == 1:  # no pool indirection when there's no parallelism
-            return [one(base) for base in base_file_names]
-        with cf.ThreadPoolExecutor(n_workers) as pool:
-            return list(pool.map(one, base_file_names))
-
-    if mesh is None:
-        runs: list = []
-        try:
-            for base in base_file_names:
-                runs.append(write_ec_files(
-                    base, codec=codec,
-                    large_block_size=large_block_size,
-                    small_block_size=small_block_size,
-                    chunk=chunk,
-                ))
-        except Exception as e:
-            e.encoded = runs
-            raise
-        return runs
-
-    width_cap = max(
-        small_block_size, getattr(codec, "preferred_chunk", chunk)
-    )
-
-    vols = []  # (dat_f, outputs, piece_iter)
+    if mesh is None and not getattr(codec, "is_device", False):
+        n_at_once = min(len(base_file_names), available_cpus())
+        with cf.ThreadPoolExecutor(max(1, n_at_once)) as pool:
+            pending = [pool.submit(one, base) for base in base_file_names]
+        results = (f.result() for f in pending)
+    else:
+        results = map(one, base_file_names)
+    runs: list = []
     try:
-        for base in base_file_names:
-            dat_size = os.path.getsize(base + ".dat")
-            n_large, n_small = _row_counts(
-                dat_size, k, large_block_size, small_block_size
-            )
-            dat_f = open(base + ".dat", "rb")
-            outputs = [
-                open(base + to_ext(i), "wb")
-                for i in range(codec.total_shards)
-            ]
-            pieces = _piece_iter(
-                n_large, large_block_size, n_small, small_block_size,
-                min(chunk, width_cap), k,
-            )
-            vols.append((dat_f, outputs, pieces))
-
-        def rounds():
-            active = list(vols)
-            while active:
-                produced = []
-                for v in active:
-                    p = next(v[2], None)
-                    if p is not None:
-                        produced.append((v, p))
-                if not produced:
-                    return
-                # group same-width pieces into shared batches, capped so one
-                # batch stays within the codec's preferred transfer size
-                by_width: dict = {}
-                for v, p in produced:
-                    by_width.setdefault(p[3], []).append((v, p))
-                for width, items in sorted(by_width.items()):
-                    per_batch = max(1, width_cap // width)
-                    for s in range(0, len(items), per_batch):
-                        yield width, items[s : s + per_batch]
-                active = [v for v, _ in produced]
-
-        def read_batch(width: int, items: list) -> np.ndarray:
-            buf = np.zeros((k, len(items) * width), dtype=np.uint8)
-            for j, ((dat_f, _outs, _it), (row_start, block, done, w)) in enumerate(
-                items
-            ):
-                c0 = j * width
-                for i in range(k):
-                    _read_into(
-                        dat_f,
-                        buf[i, c0 : c0 + w],
-                        row_start + i * block + done,
-                    )
-            return buf
-
-        def drain(entry) -> None:
-            width, items, buf, fut = entry
-            parity = np.ascontiguousarray(fut.result())
-            for j, ((_f, outputs, _it), _p) in enumerate(items):
-                sl = slice(j * width, (j + 1) * width)
-                for i in range(k):
-                    outputs[i].write(buf[i, sl].data)
-                for p in range(codec.parity_shards):
-                    outputs[k + p].write(parity[p, sl].data)
-
-        def encode_batch(buf: np.ndarray) -> np.ndarray:
-            return _mesh_encode(codec, mesh, buf)
-
-        depth = max(1, workers or 2)  # device pipeline depth
-        with cf.ThreadPoolExecutor(depth) as pool:
-            pending: deque = deque()
-            for width, items in rounds():
-                buf = read_batch(width, items)
-                pending.append(
-                    (width, items, buf, pool.submit(encode_batch, buf))
-                )
-                while len(pending) > depth:
-                    drain(pending.popleft())
-            while pending:
-                drain(pending.popleft())
-    finally:
-        for dat_f, outputs, _it in vols:
-            dat_f.close()
-            for f in outputs:
-                f.close()
-    run = EncodeRun()
-    run.route = {"route": "wide_batch", "kernel": "mesh", "volumes": len(vols)}
-    return [run] * len(vols)
+        for run in results:
+            runs.append(run)
+    except Exception as e:
+        e.encoded = runs
+        raise
+    return runs
 
 
 def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx") -> None:
@@ -1572,10 +922,9 @@ def _calibrate_rebuild_route(codec) -> str:
     'onepass' (fused NT-store decode into mmapped outputs), 'mmap'
     (zero-copy survivor views + write() outputs) or 'pread' (buffered reads).
 
-    Same rationale as the encode plane's _calibrate_host_route: the ranking
-    is hardware-dependent (on hypervisors with a slow guest fault path
-    anything mmap-backed degrades; on bare metal the fused sweep's halved
-    memory traffic wins) and a ~100MB measured race picks reliably where a
+    The ranking is hardware-dependent (on hypervisors with a slow guest
+    fault path anything mmap-backed degrades; on bare metal the fused
+    sweep's halved memory traffic wins) and a ~100MB measured race picks reliably where a
     point probe flip-flops. Serialized so concurrent rebuilds can't cache a
     contention-skewed winner."""
     global _REBUILD_HOST_ROUTE
@@ -1618,12 +967,7 @@ def _calibrate_rebuild_route(codec) -> str:
                 while left > 0:
                     f.write(block[: min(left, len(block))])
                     left -= len(block)
-            # explicit encode flags: the race must not trigger (or wait on)
-            # the encode plane's own calibration
-            write_ec_files(
-                base, codec=codec, pipeline=False, mmap_input=True,
-                onepass=False,
-            )
+            write_ec_files(base, codec=codec)
             os.remove(base + ".dat")
             missing = [0, 1, codec.total_shards - 3, codec.total_shards - 1]
             best = ("pread", 0.0)
@@ -1699,7 +1043,7 @@ def rebuild_ec_files(
       not all 13 present;
     - **pipelined** (pipeline=None -> on with >1 CPU or a device codec):
       double-buffered reader / decode pool / in-order writer, mirroring
-      _encode_rows_pipelined, with preadv into reused buffers (no per-chunk
+      _encode_streamed, with preadv into reused buffers (no per-chunk
       allocations) and zero-copy memoryview writes;
     - **atomic outputs** — rebuilt shards stream to .ecNN.tmp and are
       renamed into place only after the whole rebuild succeeds, so a
@@ -2181,7 +1525,7 @@ def _rebuild_pipelined(
 ) -> None:
     """Double-buffered rebuild loop: the main thread streams survivor reads
     (preadv into a recycled buffer ring) and in-order shard writes while a
-    small pool runs the decode matmul — the structure _encode_rows_pipelined
+    small pool runs the decode matmul — the structure _encode_streamed
     proved out, pointed at the decode matrix (ring discipline shared with
     the mmap route via _rebuild_ring)."""
     import time as _time

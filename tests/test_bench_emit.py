@@ -913,12 +913,12 @@ def test_encode_e2e_entry_discloses_stage_budget(tmp_path):
     data = rng.integers(0, 256, (4 << 20) + 12345, dtype=np.uint8)
     with open(base + ".dat", "wb") as f:
         f.write(data.tobytes())
-    enc.write_ec_files(
+    run = enc.write_ec_files(
         base, codec=TpuRSCodec(), large_block_size=1 << 20,
-        small_block_size=1 << 17, chunk=1 << 20, pipeline=True,
+        small_block_size=1 << 17, chunk=1 << 20,
     )
-    stages = dict(enc.LAST_STAGES)
-    route = dict(enc.LAST_ROUTE)
+    stages = run.stages()
+    route = dict(run.route)
     assert route["route"] == "pipeline"
 
     entry = bench._e2e_results(

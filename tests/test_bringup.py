@@ -265,7 +265,7 @@ def test_failed_arena_upload_is_a_device_error(tmp_path, monkeypatch):
 
 def test_encoded_bytes_are_counted_by_the_kernel_that_ran(tmp_path):
     """VolumeEcShardsGenerate feeds seaweedfs_tpu_ec_encoded_bytes_total
-    with backend=LAST_ROUTE["kernel"]: on this CPU the `tpu` codec's
+    with the `kernel` of the run's own route: on this CPU the `tpu` codec's
     streamed pipeline runs the host stand-in and says so."""
     from seaweedfs_tpu.storage.volume import Volume
     from seaweedfs_tpu.storage.needle import Needle

@@ -2,7 +2,8 @@
 chip that is described and not attached (v5e:2x2), at the widths the
 program really hands over: 16 MB per shard row (TpuRSCodec.preferred_chunk),
 65,536-probe batches, the arena's power-of-two row counts, and the [1->2, k,
-16 MB] batch `_mesh_encode` builds. The chip's compiler refuses here what it
+16 MB] and [1->2, k, 1 MiB] batches `_mesh_encode` builds (a chunk of a
+large block; a small-block row, which is every row of a volume under 10 GB). The chip's compiler refuses here what it
 would refuse there — a slice off the tiling, a program past HBM — at no
 chip time. Nothing runs: a compile that passes is not a chip run.
 
@@ -173,7 +174,9 @@ def test_ragged_dispatch_compiles_at_16m_rows(one_chip):
     )
 
 
-@pytest.mark.parametrize("what", ["encode", "reconstruct", "verify"])
+@pytest.mark.parametrize(
+    "what", ["encode", "reconstruct", "verify", "encode_small_row"]
+)
 def test_mesh_body_compiles_at_mesh_encode_width(mesh_rows, what):
     """The (vol, blk) bodies on host-packed uint32 words: with the bitcast
     on the device the encode at this width was refused outright (22.0 GB
@@ -187,6 +190,10 @@ def test_mesh_body_compiles_at_mesh_encode_width(mesh_rows, what):
     if what == "encode":
         body = se._apply_body(se._matrix_key(_parity(10, 4)), mesh)
         x = _cols(rows, v, 10, ROW_WORDS)
+    elif what == "encode_small_row":
+        # what the pipeline dispatches for a 1 MiB small-block row
+        body = se._apply_body(se._matrix_key(_parity(10, 4)), mesh)
+        x = _cols(rows, v, 10, (1 << 20) // 4)
     elif what == "reconstruct":
         body = se._apply_body(se._matrix_key(_decode_rows((2, 12))), mesh)
         x = _cols(rows, v, 10, ROW_WORDS)

@@ -1,7 +1,9 @@
-"""Streamed EC pipeline (ISSUE 17): the depth-N double-buffered encode
-must be byte-identical to the one-shot reference route across geometries,
-chunk sizes, and ragged final extents — and a mid-stream crash must leave
-only sweepable .ecNN.tmp files, never a torn shard that looks complete."""
+"""Streamed EC pipeline (ISSUE 17; the only encode route since ISSUE 30):
+the depth-N double-buffered encode must be byte-identical to the tests'
+own oracle (ec_oracle: rows laid out in memory, CpuRSCodec.encode) across
+codecs, geometries, chunk sizes, and ragged final extents — and a
+mid-stream crash must leave only sweepable .ecNN.tmp files, never a torn
+shard that looks complete."""
 
 import errno
 import os
@@ -13,8 +15,13 @@ import threading
 import numpy as np
 import pytest
 
+from ec_oracle import oracle_shards
 from seaweedfs_tpu.ops.rs_kernel import TpuRSCodec
-from seaweedfs_tpu.storage.erasure_coding import to_ext, write_ec_files
+from seaweedfs_tpu.storage.erasure_coding import (
+    to_ext,
+    write_ec_files,
+    write_ec_files_multi,
+)
 from seaweedfs_tpu.storage.erasure_coding import encoder as enc
 from seaweedfs_tpu.storage.erasure_coding.coder_cpu import CpuRSCodec
 from seaweedfs_tpu.storage.erasure_coding.encoder import rebuild_ec_files
@@ -38,6 +45,22 @@ def _read_shards(base, total):
     ]
 
 
+def _oracle(base, k=10, m=4):
+    return oracle_shards(base + ".dat", k, m, LARGE, SMALL)
+
+
+def _native_codec(k=10, m=4):
+    native = pytest.importorskip("seaweedfs_tpu.native")
+    if not native.available():
+        pytest.skip("native gf256 library unavailable")
+    from seaweedfs_tpu.storage.erasure_coding.coder_native import NativeRSCodec
+
+    return NativeRSCodec(k, m)
+
+
+CODECS = {"device": TpuRSCodec, "numpy": CpuRSCodec, "native": _native_codec}
+
+
 @pytest.mark.parametrize("k,m", [(10, 4), (6, 3), (12, 4)])
 @pytest.mark.parametrize(
     "size_rows,tail,chunk",
@@ -50,28 +73,20 @@ def _read_shards(base, total):
     ],
 )
 def test_streamed_matches_oneshot(tmp_path, k, m, size_rows, tail, chunk):
-    """Seeded property: pipeline=True (streamed, mmap-view input) produces
-    the same k+m shard bytes as the synchronous pread one-shot route, for
-    every geometry x extent x chunk combination."""
+    """Seeded property: the streamed route (mmap-view input) produces the
+    k+m shard bytes the oracle works out, for every geometry x extent x
+    chunk combination."""
     size = size_rows * LARGE * k + tail
     seed = hash((k, m, size, chunk)) & 0xFFFF
 
-    ref_base = str(tmp_path / "ref")
-    _write_dat(ref_base, size, seed)
-    write_ec_files(
-        ref_base, codec=CpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, pipeline=False, splice_data=False,
-        mmap_input=False, onepass=False,
-    )
-    expected = _read_shards(ref_base, k + m)
-
     got_base = str(tmp_path / "streamed")
     _write_dat(got_base, size, seed)
-    write_ec_files(
+    expected = _oracle(got_base, k, m)
+    run = write_ec_files(
         got_base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, chunk=chunk, pipeline=True,
+        small_block_size=SMALL, chunk=chunk,
     )
-    assert enc.LAST_ROUTE["route"] == "pipeline"
+    assert run.route["route"] == "pipeline" and run.route["input"] == "mmap"
     got = _read_shards(got_base, k + m)
     for i, (e, g) in enumerate(zip(expected, got)):
         assert e == g, f"shard {to_ext(i)} diverged ({k}.{m}, {size}B)"
@@ -80,29 +95,63 @@ def test_streamed_matches_oneshot(tmp_path, k, m, size_rows, tail, chunk):
     )
 
 
-def test_streamed_pread_staging_route_matches(tmp_path, monkeypatch):
-    """The copy-staging (pread) input route — what the pipeline falls back
-    to when calibration rules out the mmap fault path — is byte-identical
-    too, including the grouped small-row items mmap never exercises."""
-    monkeypatch.setattr(enc, "_HOST_ROUTE", "sync")
-    k, m = 10, 4
-    size = 2 * LARGE * k + 3 * SMALL * k + 517
+@pytest.mark.parametrize("codec", sorted(CODECS))
+def test_streamed_pread_staging_route_matches(tmp_path, monkeypatch, codec):
+    """The copy-staging (pread) input — what the pipeline falls back to
+    when the .dat cannot be mapped — is byte-identical too, including the
+    grouped small-row items mmap never exercises."""
+    import mmap
 
-    ref_base = str(tmp_path / "ref")
-    _write_dat(ref_base, size, 99)
-    write_ec_files(
-        ref_base, codec=CpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, pipeline=False, splice_data=False,
-        mmap_input=False, onepass=False,
-    )
+    def refuses(*_a, **_kw):
+        raise OSError(errno.ENODEV, "this file system maps nothing")
+
+    monkeypatch.setattr(mmap, "mmap", refuses)
+    k, m = 10, 4
     got_base = str(tmp_path / "streamed")
-    _write_dat(got_base, size, 99)
-    write_ec_files(
-        got_base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, chunk=1 << 14, pipeline=True,
+    _write_dat(got_base, 2 * LARGE * k + 3 * SMALL * k + 517, 99)
+    run = write_ec_files(
+        got_base, codec=CODECS[codec](k, m), large_block_size=LARGE,
+        small_block_size=SMALL, chunk=1 << 14,
     )
-    assert enc.LAST_ROUTE["input"] == "pread"
-    assert _read_shards(ref_base, k + m) == _read_shards(got_base, k + m)
+    assert run.route["route"] == "pipeline" and run.route["input"] == "pread"
+    assert _oracle(got_base) == _read_shards(got_base, k + m)
+
+
+@pytest.mark.parametrize("codec", sorted(CODECS))
+def test_an_empty_dat_gives_fourteen_empty_shards(tmp_path, codec):
+    """Nothing to map, nothing to dispatch: the pread fallback with no
+    item, and still a commit by rename."""
+    base = str(tmp_path / "v")
+    _write_dat(base, 0, 1)
+    run = write_ec_files(
+        base, codec=CODECS[codec](), large_block_size=LARGE,
+        small_block_size=SMALL,
+    )
+    assert run.route["route"] == "pipeline" and run.route["input"] == "pread"
+    assert _read_shards(base, 14) == [b""] * 14 == _oracle(base)
+    assert sorted(os.listdir(tmp_path)) == sorted(
+        ["v.dat"] + ["v" + to_ext(i) for i in range(14)]
+    )
+
+
+@pytest.mark.parametrize(
+    "fn,switch",
+    [
+        (write_ec_files, "pipeline"),
+        (write_ec_files, "mmap_input"),
+        (write_ec_files, "onepass"),
+        (write_ec_files_multi, "workers"),
+    ],
+    ids=lambda v: getattr(v, "__name__", v),
+)
+def test_the_route_switches_are_gone(tmp_path, fn, switch):
+    """One pipeline: nothing left to choose between (ISSUE 30)."""
+    base = str(tmp_path / "v")
+    _write_dat(base, 100, 1)
+    arg = base if fn is write_ec_files else [base]
+    with pytest.raises(TypeError, match=switch):
+        fn(arg, codec=CpuRSCodec(), **{switch: False})
+    assert os.listdir(tmp_path) == ["v.dat"]
 
 
 def _with_writers(monkeypatch, writers, depth=2):
@@ -124,24 +173,16 @@ def test_streamed_matches_oneshot_from_any_number_of_writers(
     large rows, small rows and a tail that straddles EOF mid-row."""
     k, m = 10, 4
     size = 2 * LARGE * k + 3 * SMALL * k + 2 * SMALL + 517
-    ref_base = str(tmp_path / "ref")
-    _write_dat(ref_base, size, 27)
-    write_ec_files(
-        ref_base, codec=CpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, pipeline=False, splice_data=False,
-        mmap_input=False, onepass=False,
-    )
     _with_writers(monkeypatch, writers)
     threads_before = set(threading.enumerate())
     got_base = str(tmp_path / "streamed")
     _write_dat(got_base, size, 27)
     run = write_ec_files(
         got_base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, chunk=1 << 14, pipeline=True,
-        splice_data=False,
+        small_block_size=SMALL, chunk=1 << 14, splice_data=False,
     )
     assert run.route["writers"] == writers
-    assert _read_shards(ref_base, k + m) == _read_shards(got_base, k + m)
+    assert _oracle(got_base) == _read_shards(got_base, k + m)
     assert not any(n.endswith(".tmp") for n in os.listdir(tmp_path))
     # every thread of the run is gone with it
     assert not [
@@ -198,8 +239,7 @@ def test_a_helper_threads_write_error_ends_the_run(
         try:
             write_ec_files(
                 base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
-                small_block_size=SMALL, chunk=1 << 14, pipeline=True,
-                splice_data=False,
+                small_block_size=SMALL, chunk=1 << 14, splice_data=False,
             )
             outcome.append(None)
         except BaseException as e:
@@ -222,7 +262,7 @@ def test_streamed_rebuild_roundtrip(tmp_path):
     _write_dat(base, 2 * LARGE * k + 31, 7)
     write_ec_files(
         base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, pipeline=True,
+        small_block_size=SMALL,
     )
     originals = _read_shards(base, k + m)
     for i in (0, 3, 11, 13):
@@ -239,36 +279,52 @@ _KILL_CHILD = """
 import sys, time
 sys.path.insert(0, {repo!r})
 import numpy as np
-from seaweedfs_tpu.ops.rs_kernel import TpuRSCodec
+from {module} import {cls}
 from seaweedfs_tpu.storage.erasure_coding import write_ec_files
 
-class SlowCodec(TpuRSCodec):
-    def pipeline_encode(self, data):
+class SlowCodec({cls}):
+    def {dispatch}(self, data):
         print("CHUNK", flush=True)
         time.sleep(0.4)  # hold the stream open so the parent kills mid-run
-        return super().pipeline_encode(data)
+        return super().{dispatch}(data)
 
 write_ec_files(
     {base!r}, codec=SlowCodec(), large_block_size={large},
-    small_block_size={small}, chunk={large}, pipeline=True,
-    splice_data=False,
+    small_block_size={small}, chunk={large}, splice_data=False,
 )
 print("DONE", flush=True)
 """
 
+# the codec's class, and the method the pipeline dispatches a chunk to
+_KILL_CODECS = {
+    "device": ("seaweedfs_tpu.ops.rs_kernel", "TpuRSCodec", "pipeline_encode"),
+    "numpy": (
+        "seaweedfs_tpu.storage.erasure_coding.coder_cpu", "CpuRSCodec",
+        "encode",
+    ),
+    "native": (
+        "seaweedfs_tpu.storage.erasure_coding.coder_native", "NativeRSCodec",
+        "encode",
+    ),
+}
 
-def test_kill_mid_stream_leaves_only_tmp(tmp_path):
+
+@pytest.mark.parametrize("codec", sorted(_KILL_CODECS))
+def test_kill_mid_stream_leaves_only_tmp(tmp_path, codec):
     """Kill-point: SIGKILL the encode after the second chunk dispatch. No
     finally-cleanup runs, so the crash site must hold only .ecNN.tmp files
     (the next run's sweep target) and never a final-named shard; a fresh
     encode over the crash site then succeeds byte-identically with no .tmp
-    leftovers."""
+    leftovers. Whatever the codec: there is one pipeline."""
     k, m = 10, 4
     base = str(tmp_path / "v")
     _write_dat(base, 4 * LARGE * k + 999, 21)
+    again = CODECS[codec](k, m)  # skips here where there is no native library
+    module, cls, dispatch = _KILL_CODECS[codec]
 
     code = _KILL_CHILD.format(
-        repo=REPO, base=base, large=LARGE, small=SMALL
+        repo=REPO, base=base, large=LARGE, small=SMALL,
+        module=module, cls=cls, dispatch=dispatch,
     )
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.Popen(
@@ -296,18 +352,9 @@ def test_kill_mid_stream_leaves_only_tmp(tmp_path):
 
     # recovery: the next encode sweeps the torn .tmp and rebuilds clean
     write_ec_files(
-        base, codec=TpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, pipeline=True,
+        base, codec=again, large_block_size=LARGE, small_block_size=SMALL,
     )
-    got = _read_shards(base, k + m)
-    ref_base = str(tmp_path / "ref")
-    _write_dat(ref_base, 4 * LARGE * k + 999, 21)
-    write_ec_files(
-        ref_base, codec=CpuRSCodec(k, m), large_block_size=LARGE,
-        small_block_size=SMALL, pipeline=False, splice_data=False,
-        mmap_input=False, onepass=False,
-    )
-    assert got == _read_shards(ref_base, k + m)
+    assert _read_shards(base, k + m) == _oracle(base)
     assert not any(
         n.endswith(".tmp") for n in os.listdir(tmp_path)
     )

@@ -92,6 +92,17 @@ def test_heartbeat_break_deletes_vids_from_clients(tmp_path):
                     break
                 await asyncio.sleep(0.1)
             assert ar.url in client.vid_map.lookup(vid)
+            # the client has the vid from the push the growth itself sends.
+            # What a broken stream takes away is what the node's heartbeat
+            # has listed: wait for the pulse that lists it
+            node = next(
+                n for n in cluster.master.topo.data_nodes() if n.url == ar.url
+            )
+            for _ in range(100):
+                if vid in node.volumes:
+                    break
+                await asyncio.sleep(0.05)
+            assert vid in node.volumes
 
             # kill the server holding the vid
             victim = cluster.server_for(ar.url)

@@ -19,6 +19,7 @@ import aiohttp
 import numpy as np
 import pytest
 
+from ec_oracle import oracle_shards
 from seaweedfs_tpu.shell import commands
 from seaweedfs_tpu.shell.ec_common import select_volumes_for_ec_encode
 from seaweedfs_tpu.storage.erasure_coding import (
@@ -144,11 +145,10 @@ def test_modification_time_is_the_same_after_a_reload(tmp_path, kind, last):
 
 # ------------------------------------------------- the batch, as a library
 class _DeviceLike(CpuRSCodec):
-    """The numpy codec with a device codec's preferences, so that
-    write_ec_files_multi sends its volumes through the streamed pipeline."""
+    """The numpy codec with a device codec's preferences and a name of its
+    own for the kernel that ran."""
 
     is_device = True
-    prefers_pipeline = True
     preferred_chunk = 16 * 1024
     pipeline_dispatch_kind = "device_like"
 
@@ -167,6 +167,10 @@ def _shards(base: str) -> list:
     return out
 
 
+def _oracle(base: str) -> list:
+    return oracle_shards(base + ".dat", 10, 4, LARGE, SMALL)
+
+
 def _stage_seconds() -> dict:
     return {dict(k)["stage"]: v for k, v in m.EC_ENCODE_STAGE_SECONDS._values.items()}
 
@@ -176,32 +180,25 @@ BATCH_SIZES = [LARGE * 10 * 2 + SMALL * 10 * 2 + 333, SMALL * 10 * 5, SMALL * 3 
 
 @pytest.mark.parametrize("kind", ["device_like", "host"])
 def test_batch_of_three_is_each_volume_alone_and_writes_the_one_volume_routes_stages(tmp_path, kind):
-    alone, batch = [], []
+    batch = []
     for j, size in enumerate(BATCH_SIZES):
-        for sub, acc in (("a", alone), ("b", batch)):
-            d = tmp_path / f"{sub}{j}"
-            d.mkdir()
-            _mk_dat(str(d / "1.dat"), size)
-            acc.append(str(d / "1"))
-    for base in alone:
-        write_ec_files(base, codec=CpuRSCodec(), large_block_size=LARGE, small_block_size=SMALL)
+        d = tmp_path / f"b{j}"
+        d.mkdir()
+        _mk_dat(str(d / "1.dat"), size)
+        batch.append(str(d / "1"))
     stages0, pieces0 = _stage_seconds(), sum(m.EC_ENCODE_BATCH_PIECES._values.values())
     calls0 = dict(m.EC_ENCODE_STAGE_CALLS._values)
     codec = _DeviceLike() if kind == "device_like" else CpuRSCodec()
     runs = write_ec_files_multi(batch, codec=codec, large_block_size=LARGE, small_block_size=SMALL)
-    for a, b, size in zip(alone, batch, BATCH_SIZES):
-        assert _shards(b) == _shards(a), size
+    for b, size in zip(batch, BATCH_SIZES):
+        assert _shards(b) == _oracle(b), size
     assert len({id(r) for r in runs}) == 3  # each volume its own run, in the order given
     # the stages are the one-volume route's, by name: no second set
     moved = {k for k, v in _stage_seconds().items() if v > stages0.get(k, 0)}
     assert set(_stage_seconds()) <= {"splice", "read", "slot_wait", "submit", "kernel", "parity_wait",
                                      "write", "write_thread", "sync"}
     pieces = sum(m.EC_ENCODE_BATCH_PIECES._values.values()) - pieces0
-    if kind == "host":  # across cores, each on the synchronous route: no dispatch of the pipeline's
-        assert all(r.route["route"] != "pipeline" for r in runs)
-        assert moved >= {"read", "kernel", "write"} and pieces == 0
-        return
-    assert all(r.route["route"] == "pipeline" and r.route["kernel"] == "device_like" for r in runs)
+    assert all(r.route["route"] == "pipeline" and r.route["kernel"] == kind for r in runs)
     assert moved >= {"read", "slot_wait", "submit", "kernel", "parity_wait", "write", "write_thread", "sync"}
     items = sum(v - calls0.get(k, 0) for k, v in m.EC_ENCODE_STAGE_CALLS._values.items()
                 if dict(k)["stage"] == "submit") - 3  # each volume's set-up adds one `since`
@@ -213,7 +210,7 @@ def test_one_volume_counts_one_piece_an_item(tmp_path):
     _mk_dat(str(tmp_path / "1.dat"), SMALL * 10 * 4)
     pieces0 = sum(m.EC_ENCODE_BATCH_PIECES._values.values())
     run = write_ec_files(str(tmp_path / "1"), codec=CpuRSCodec(), large_block_size=LARGE,
-                         small_block_size=SMALL, pipeline=True, chunk=SMALL)
+                         small_block_size=SMALL, chunk=SMALL)
     assert run.route["route"] == "pipeline"
     assert sum(m.EC_ENCODE_BATCH_PIECES._values.values()) - pieces0 == 4  # one a row
 
@@ -226,7 +223,6 @@ from seaweedfs_tpu.storage.erasure_coding.coder_cpu import CpuRSCodec
 
 class Dies(CpuRSCodec):
     is_device = True
-    prefers_pipeline = True
     preferred_chunk = 16 * 1024
     calls = 0
     def pipeline_encode(self, data):
@@ -267,11 +263,7 @@ def test_a_batch_killed_half_way_leaves_no_torn_final_name_and_the_next_run_swee
     for base in bases:
         names = os.listdir(os.path.dirname(base))
         assert not [n for n in names if n.endswith(".tmp")], names
-        alone = str(tmp_path / "alone")
-        os.link(base + ".dat", alone + ".dat")
-        write_ec_files(alone, codec=CpuRSCodec(), large_block_size=LARGE, small_block_size=SMALL)
-        assert _shards(base) == _shards(alone)
-        os.unlink(alone + ".dat")
+        assert _shards(base) == _oracle(base)
 
 
 @pytest.mark.parametrize("fail_at,whole", [(2, 0), (9, 1)], ids=["in_the_first_volume", "in_the_second"])
@@ -298,6 +290,35 @@ def test_a_batch_that_fails_in_this_process_sweeps_and_says_what_is_whole(tmp_pa
     for j, base in enumerate(bases):
         names = sorted(os.listdir(os.path.dirname(base)))
         assert names == (["1.dat"] + [f"1.ec{i:02d}" for i in range(14)] if j < whole else ["1.dat"])
+
+
+def test_a_host_codecs_batch_that_fails_says_what_is_whole_before_the_failure(tmp_path):
+    """A host codec's volumes go at once. The one that fails is swept, the
+    others finish, and `encoded` holds the runs up to the first failure, which
+    is the prefix `_ec_generate_batch` takes as done."""
+    mark = bytes(range(200, 208))
+
+    class FailsOnTheMarkedVolume(CpuRSCodec):  # no is_device: a host codec
+        def encode(self, data):
+            if bytes(data[0, :8]) == mark:
+                raise RuntimeError("this volume cannot be encoded")
+            return super().encode(data)
+
+    bases = []
+    for j in range(3):
+        d = tmp_path / f"v{j}"
+        d.mkdir()
+        _mk_dat(str(d / "1.dat"), SMALL * 10 * 6 + j)
+        bases.append(str(d / "1"))
+    with open(bases[1] + ".dat", "r+b") as f:
+        f.write(mark)
+    with pytest.raises(RuntimeError, match="cannot be encoded") as failed:
+        write_ec_files_multi(bases, codec=FailsOnTheMarkedVolume(), large_block_size=LARGE, small_block_size=SMALL)
+    assert [r.route["kernel"] for r in failed.value.encoded] == ["host"]
+    whole = ["1.dat"] + [f"1.ec{i:02d}" for i in range(14)]
+    assert [sorted(os.listdir(os.path.dirname(b))) for b in bases] == [whole, ["1.dat"], whole]
+    for b in (bases[0], bases[2]):
+        assert _shards(b) == _oracle(b)
 
 
 # ---------------------------------------------------- the batch, as the RPC
@@ -461,25 +482,32 @@ def test_ec_encode_without_volume_id_leaves_alone_what_is_not_full_or_not_quiet(
     asyncio.run(body())
 
 
-def test_a_mesh_keeps_its_wide_batches(tmp_path):
-    """write_ec_files_multi(mesh=...) lays pieces of several volumes side by
-    side and shards each wide batch over the mesh (virtual host mesh — the
-    path a TPU mesh takes): byte-identical, and it says which kernel ran."""
+def test_a_meshs_volumes_go_through_the_same_pipeline(tmp_path, monkeypatch):
+    """write_ec_files_multi(mesh=...) computes each dispatch's parity over the
+    mesh (virtual host mesh — the path a TPU mesh takes) and leaves the rest
+    to the one pipeline: byte-identical, its stages, a commit by rename, and
+    it says which kernel ran."""
     jax = pytest.importorskip("jax")
     from seaweedfs_tpu.ops.rs_kernel import TpuRSCodec
     from seaweedfs_tpu.parallel.sharded_ec import make_mesh
 
-    alone, batch = [], []
+    batch = []
     for j, size in enumerate(BATCH_SIZES):
-        for sub, acc in (("a", alone), ("b", batch)):
-            d = tmp_path / f"{sub}{j}"
-            d.mkdir()
-            _mk_dat(str(d / "1.dat"), size)
-            acc.append(str(d / "1"))
-    for base in alone:
-        write_ec_files(base, codec=CpuRSCodec(), large_block_size=LARGE, small_block_size=SMALL)
+        d = tmp_path / f"b{j}"
+        d.mkdir()
+        _mk_dat(str(d / "1.dat"), size)
+        batch.append(str(d / "1"))
+    renamed = []
+    real_replace = os.replace
+    monkeypatch.setattr(enc.os, "replace", lambda a, b: (renamed.append((a, b)), real_replace(a, b))[1])
     runs = write_ec_files_multi(batch, codec=TpuRSCodec(), large_block_size=LARGE, small_block_size=SMALL,
                                 mesh=make_mesh(devices=jax.devices("cpu")))
-    assert len(runs) == 3 and runs[0].route["kernel"] == "mesh" and runs[0].route["volumes"] == 3
-    for a, b, size in zip(alone, batch, BATCH_SIZES):
-        assert _shards(b) == _shards(a), size
+    assert len({id(r) for r in runs}) == 3
+    for run in runs:
+        assert run.route["route"] == "pipeline" and run.route["kernel"] == "mesh"
+        assert {"read_s", "slot_wait_s", "kernel_s", "parity_wait_s", "write_s", "sync_s"} <= set(run.stages())
+    for b, size in zip(batch, BATCH_SIZES):
+        assert _shards(b) == _oracle(b), size
+        assert not [n for n in os.listdir(os.path.dirname(b)) if n.endswith(".tmp")]
+    # every shard file was written under a temporary name and renamed when its volume was whole
+    assert renamed == [(b + to_ext(i) + ".tmp", b + to_ext(i)) for b in batch for i in range(14)]

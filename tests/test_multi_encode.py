@@ -10,9 +10,9 @@ import aiohttp
 import numpy as np
 import pytest
 
+from ec_oracle import oracle_shards
 from seaweedfs_tpu.storage.erasure_coding import (
     to_ext,
-    write_ec_files,
     write_ec_files_multi,
 )
 from seaweedfs_tpu.storage.erasure_coding.coder_cpu import CpuRSCodec
@@ -36,11 +36,15 @@ def _shards(base: str) -> list:
     return out
 
 
+def _oracle(base: str) -> list:
+    return oracle_shards(base + ".dat", 10, 4, LARGE, SMALL)
+
+
 @pytest.mark.parametrize("route", ["in_turn", "mesh"])
 def test_multi_device_batch_path_matches_oracle(tmp_path, route):
     """A device codec's batch — its volumes in turn through the streamed
-    pipeline, or shared wide batches over a mesh — must be byte-identical
-    to per-volume encodes across mixed geometries."""
+    pipeline, on one device or with each dispatch over a mesh — must be
+    byte-identical to the oracle across mixed geometries."""
     import jax
 
     from seaweedfs_tpu.ops.rs_kernel import TpuRSCodec
@@ -55,26 +59,21 @@ def test_multi_device_batch_path_matches_oracle(tmp_path, route):
         0,
         LARGE * 10 + 1,
     ]
-    singles, multis = [], []
+    multis = []
     for j, size in enumerate(sizes):
-        for sub, acc in (("ds", singles), ("dm", multis)):
-            d = tmp_path / f"{sub}{j}"
-            d.mkdir()
-            _mk_dat(str(d / "1.dat"), size)
-            acc.append(str(d / "1"))
-    for base in singles:
-        write_ec_files(
-            base, codec=CpuRSCodec(),
-            large_block_size=LARGE, small_block_size=SMALL,
-        )
-    codec = TpuRSCodec()
-    assert getattr(codec, "is_device", False)
-    write_ec_files_multi(
-        multis, codec=codec,
+        d = tmp_path / f"dm{j}"
+        d.mkdir()
+        _mk_dat(str(d / "1.dat"), size)
+        multis.append(str(d / "1"))
+    runs = write_ec_files_multi(
+        multis, codec=TpuRSCodec(),
         large_block_size=LARGE, small_block_size=SMALL, mesh=mesh,
     )
-    for s, m, size in zip(singles, multis, sizes):
-        assert _shards(m) == _shards(s), size
+    for m, size in zip(multis, sizes):
+        assert _shards(m) == _oracle(m), size
+    assert [r.route["route"] for r in runs] == ["pipeline"] * len(sizes)
+    if route == "mesh":
+        assert {r.route["kernel"] for r in runs} == {"mesh"}
 
 
 def test_multi_matches_per_volume_oracle(tmp_path):
@@ -86,25 +85,18 @@ def test_multi_matches_per_volume_oracle(tmp_path):
         0,
         LARGE * 10 + 1,
     ]
-    singles, multis = [], []
+    multis = []
     for j, size in enumerate(sizes):
-        for sub, acc in (("s", singles), ("m", multis)):
-            d = tmp_path / f"{sub}{j}"
-            d.mkdir()
-            _mk_dat(str(d / "1.dat"), size)
-            acc.append(str(d / "1"))
-    codec = CpuRSCodec()
-    for base in singles:
-        write_ec_files(
-            base, codec=codec,
-            large_block_size=LARGE, small_block_size=SMALL,
-        )
+        d = tmp_path / f"m{j}"
+        d.mkdir()
+        _mk_dat(str(d / "1.dat"), size)
+        multis.append(str(d / "1"))
     write_ec_files_multi(
-        multis, codec=codec,
+        multis, codec=CpuRSCodec(),
         large_block_size=LARGE, small_block_size=SMALL,
     )
-    for s, m, size in zip(singles, multis, sizes):
-        assert _shards(m) == _shards(s), size
+    for m, size in zip(multis, sizes):
+        assert _shards(m) == _oracle(m), size
 
 
 def test_multi_with_native_codec(tmp_path):
@@ -114,24 +106,18 @@ def test_multi_with_native_codec(tmp_path):
     from seaweedfs_tpu.storage.erasure_coding.coder_native import NativeRSCodec
 
     sizes = [SMALL * 10 * 3 + 100, SMALL * 10 * 3 + 100, SMALL * 2]
-    oracle, multis = [], []
+    multis = []
     for j, size in enumerate(sizes):
-        for sub, acc in (("o", oracle), ("m", multis)):
-            d = tmp_path / f"{sub}{j}"
-            d.mkdir()
-            _mk_dat(str(d / "1.dat"), size)
-            acc.append(str(d / "1"))
-    for base in oracle:
-        write_ec_files(
-            base, codec=CpuRSCodec(),
-            large_block_size=LARGE, small_block_size=SMALL,
-        )
+        d = tmp_path / f"m{j}"
+        d.mkdir()
+        _mk_dat(str(d / "1.dat"), size)
+        multis.append(str(d / "1"))
     write_ec_files_multi(
         multis, codec=NativeRSCodec(),
-        large_block_size=LARGE, small_block_size=SMALL, workers=3,
+        large_block_size=LARGE, small_block_size=SMALL,
     )
-    for o, m in zip(oracle, multis):
-        assert _shards(m) == _shards(o)
+    for m in multis:
+        assert _shards(m) == _oracle(m)
 
 
 def test_shell_ec_encode_batches_colocated_volumes(tmp_path):

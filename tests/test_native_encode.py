@@ -1,6 +1,7 @@
-"""Native-codec encode structures: mmap zero-copy rows, kernel-side data
-splice, pipelined workers, and the adaptive route — all byte-identical to the
-CpuRSCodec oracle (ref semantics: weed/storage/erasure_coding/ec_encoder.go).
+"""The native codec in the encode pipeline, with and without the
+kernel-side data splice, and the numpy codec beside it — all byte-identical
+to the tests' oracle (ec_oracle; ref semantics:
+weed/storage/erasure_coding/ec_encoder.go) — and the adaptive codec choice.
 """
 
 import os
@@ -8,6 +9,7 @@ import os
 import numpy as np
 import pytest
 
+from ec_oracle import oracle_shards
 from seaweedfs_tpu.storage.erasure_coding import to_ext, write_ec_files
 from seaweedfs_tpu.storage.erasure_coding.coder_cpu import CpuRSCodec
 
@@ -40,35 +42,24 @@ SIZES = [LARGE * 10 * 2 + SMALL * 10 * 3 + 700, SMALL * 4 + 17, 0, SMALL * 10]
 
 @pytest.mark.parametrize("size", SIZES)
 def test_mmap_and_splice_match_oracle(tmp_path, size):
-    oracle = tmp_path / "o"
-    oracle.mkdir()
-    _write_dat(str(oracle / "1.dat"), size)
-    write_ec_files(
-        str(oracle / "1"), codec=CpuRSCodec(),
-        large_block_size=LARGE, small_block_size=SMALL,
-    )
-    golden = _read_shards(str(oracle / "1"))
+    _write_dat(str(tmp_path / "1.dat"), size)
+    golden = oracle_shards(str(tmp_path / "1.dat"), 10, 4, LARGE, SMALL)
 
-    for label, kw in [
-        ("auto", {}),  # mmap (+ splice when the fs allows) on 1 core
-        ("mmap", {"pipeline": False, "mmap_input": True}),
-        ("mmap-no-splice", {"pipeline": False, "mmap_input": True,
-                            "splice_data": False}),
-        ("sync", {"pipeline": False, "splice_data": False,
-                  "mmap_input": False}),
-        ("pipelined", {"pipeline": True}),
-        # forced (bypasses the page-population viability probe): the fused
-        # GFNI one-pass NT-store path, when this build carries it
-        ("onepass", {"onepass": True}),
+    for label, codec, kw in [
+        ("auto", NativeRSCodec(), {}),  # the splice where the fs allows it
+        ("no-splice", NativeRSCodec(), {"splice_data": False}),
+        ("numpy codec", CpuRSCodec(), {}),
     ]:
         d = tmp_path / label
         d.mkdir()
-        os.link(str(oracle / "1.dat"), str(d / "1.dat"))
-        write_ec_files(
-            str(d / "1"), codec=NativeRSCodec(),
+        os.link(str(tmp_path / "1.dat"), str(d / "1.dat"))
+        run = write_ec_files(
+            str(d / "1"), codec=codec,
             large_block_size=LARGE, small_block_size=SMALL, **kw,
         )
         assert _read_shards(str(d / "1")) == golden, (label, size)
+        assert run.route["route"] == "pipeline", label
+        assert not (run.route["spliced"] and label == "no-splice")
 
 
 def test_encode_rows_pointer_api_matches_stacked():

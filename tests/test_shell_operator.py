@@ -49,6 +49,13 @@ def test_volume_balance(tmp_path):
                     await asyncio.sleep(0.1)
 
                 env = CommandEnv(cluster.master.address)
+                # the balance plans from what the heartbeats have listed,
+                # and a grown volume is listed a pulse after it is grown
+                for _ in range(100):
+                    nodes = await env.collect_data_nodes()
+                    if max(len(dn.get("volumes", [])) for dn in nodes) >= 6:
+                        break
+                    await asyncio.sleep(0.1)
                 # plan only (no -force): nothing moves
                 await run_command(env, "lock")
                 plan = await run_command(env, "volume.balance")
