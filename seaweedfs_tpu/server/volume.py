@@ -446,6 +446,7 @@ class VolumeServer(EcHandlers):
         self._stage_read_render = READ_STAGE_SECONDS.child(
             stage="read_render"
         )
+        self._stage_ec_read = READ_STAGE_SECONDS.child(stage="ec_read")
         mark_startup("index_build")
 
     def _group_committer(self, vid: int):
@@ -713,10 +714,13 @@ class VolumeServer(EcHandlers):
         protocol replays against the internal aiohttp app — semantics can
         never diverge, the fast tier only short-circuits what it completely
         covers. Reads may fall back at ANY point (no side effects); writes
-        only before the needle append. Counting and the server-side fault
-        seam live in the shared ServingCore; DETACHED responses count at
-        their completion callback via _count_fast so a gated read that
-        proxies to the full app is never double-counted."""
+        only before the needle append. A read of a plain volume and of an
+        EC volume mounted here (healthy or degraded) is answered here;
+        queries, ranges, manifests, compressed needles, tiered volumes and
+        volumes held elsewhere go to the aiohttp app. Counting and the
+        server-side fault seam live in the shared ServingCore; DETACHED
+        responses count at their completion callback via _count_fast so a
+        gated read that proxies to the full app is never double-counted."""
         method = req.method
         if method in ("GET", "HEAD"):
             return await self._fast_read(req)
@@ -742,8 +746,13 @@ class VolumeServer(EcHandlers):
             return FALLBACK  # /status, /ui, /metrics, bad fids...
         vid = fid.volume_id
         v = self.store.find_volume(vid)
-        if v is None or v.has_remote_file:
-            return FALLBACK  # EC / tiered / redirect paths
+        if v is None:
+            ev = self.store.find_ec_volume(vid)
+            if ev is None:
+                return FALLBACK  # not here: the redirect through the master
+            return await self._fast_read_ec(ev, fid, head_only)
+        if v.has_remote_file:
+            return FALLBACK  # tiered: blocking remote I/O, off the loop
         t0 = time.perf_counter()
         cache = self.read_cache
         if cache is not None:
@@ -789,6 +798,28 @@ class VolumeServer(EcHandlers):
             cache, v, vid, fid, n, off_units, size, out, head_only
         )
         self._stage_read_render.observe(time.perf_counter() - t0)
+        return out
+
+    async def _fast_read_ec(self, ev, fid, head_only):
+        """A read of a locally mounted EC volume, healthy or degraded: the
+        coroutine the aiohttp handler awaits, rendered as a plain volume's
+        needle is. Whatever it raises goes to the aiohttp tier, which
+        decides every status code the fast tier does not (a read has no
+        side effect to repeat)."""
+        t0 = time.perf_counter()
+        try:
+            n = await self.read_ec_needle(ev, fid.key)
+        except Exception:
+            return FALLBACK
+        if n is None:
+            out = render_response(
+                404, b'{"error": "not found"}', head_only=head_only
+            )
+        else:
+            out = self._render_needle(n, fid, head_only)
+            if out is _NEEDS_FULL_APP:
+                return FALLBACK
+        self._stage_ec_read.observe(time.perf_counter() - t0)
         return out
 
     def _maybe_cache_fill(

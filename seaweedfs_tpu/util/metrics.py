@@ -361,10 +361,10 @@ REQUEST_WAIT_SECONDS = REGISTRY.counter(
     "start of service (loop backlog + admission queue), by server/operation; "
     "full responses and FALLBACKs, not DETACHED ones",
 )
-# a request the fast tier does not fully understand (an EC read, a range,
-# /status) is replayed against the internal aiohttp listener over a new
-# loopback connection: the wall of that replay, which encloses the cold
-# tier's own request_seconds
+# a request the fast tier does not fully understand (a range, a query, a
+# manifest, /status) is replayed against the internal aiohttp listener over
+# a new loopback connection: the wall of that replay, which encloses the
+# cold tier's own request_seconds
 REQUEST_PROXY_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_request_proxy_seconds_total",
     "seconds fast tiers spent replaying FALLBACK requests against their "
@@ -461,7 +461,9 @@ READ_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_read_stage_seconds",
     "volume read path stage wall time, by stage (cache_hit = full request "
     "served from the hot-needle cache; read_render = map probe + pread + "
-    "parse + response render on a miss)",
+    "parse + response render on a miss; ec_read = a read of a locally "
+    "mounted EC volume answered by the fast tier: locate + intervals, "
+    "reconstructed where a shard is lost, + render)",
 )
 READ_CACHE_HITS = REGISTRY.counter(
     "seaweedfs_tpu_read_cache_hits_total",

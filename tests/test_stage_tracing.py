@@ -395,9 +395,13 @@ BATCH_METRICS = ["ec_batch.volumes_per_dispatch", "ec_batch.generate_share"]
 NO_HOLDER_METRICS = {
     "warm-rs10.4.degraded-get-c16": ["ec_read.no_holder_skip_share"],
 }
+# ISSUE 31's, last in BENCHMARK.json: how many GETs the fast tier still replays
+PROXIED_METRICS = {
+    "warm-rs10.4.degraded-get-c16": ["http.proxied_share"],
+}
 ALL_NEW_METRICS = [
     (cell, name)
-    for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS)
+    for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS, PROXIED_METRICS)
     for cell, names in cells.items()
     for name in names
 ]
@@ -432,8 +436,12 @@ async def _encode_lose_a_shard_and_get(tmp_path) -> tuple:
             if cluster.master.topo.data_nodes():
                 break
             await asyncio.sleep(0.1)
-        before = scrape()
         async with aiohttp.ClientSession() as session:
+            # as the harness waits for the server: the one replayed request
+            # that puts request_proxied_total on /metrics before the window
+            async with session.get(f"http://{vs.address}/status") as resp:
+                assert resp.status == 200
+            before = scrape()
             first = await assign_retry(cluster.master.address)
             vid = int(first.fid.split(",")[0])
             rng = np.random.default_rng(7)
@@ -481,12 +489,13 @@ def test_degraded_get_moves_the_read_stages_and_the_fast_tiers_clocks(degraded_g
     assert moved(before, after, "seaweedfs_tpu_request_seconds_sum", **volume_get) > 0
     assert moved(before, after, "seaweedfs_tpu_request_wait_seconds_total", **volume_get) > 0
     assert moved(before, after, "seaweedfs_tpu_event_loop_lag_ticks_total", server="volume") >= 3
-    # an EC read is not the fast tier's own: it is replayed against the
-    # aiohttp tier, whose _dispatch observes request_seconds; the replay's
-    # wall is the fast tier's to count
-    assert moved(before, after, "seaweedfs_tpu_request_proxied_total", server="volume") >= len(wrote)
-    assert moved(before, after, "seaweedfs_tpu_request_proxy_seconds_total", server="volume") >= moved(
-        before, after, "seaweedfs_tpu_request_seconds_sum", **volume_get)
+    # an EC read is the fast tier's own since ISSUE 31: ServingCore observes
+    # its request_seconds, the read is a stage of the fast tier's reads, and
+    # nothing is replayed against the aiohttp tier
+    assert moved(before, after, "seaweedfs_tpu_request_seconds_count", **volume_get) == len(wrote)
+    assert moved(before, after, "seaweedfs_tpu_read_stage_seconds_count", stage="ec_read") == len(wrote)
+    assert moved(before, after, "seaweedfs_tpu_request_proxied_total", server="volume") == 0
+    assert moved(before, after, "seaweedfs_tpu_request_proxy_seconds_total", server="volume") == 0
     # where the decode ran is on /metrics now: not on a device, here
     assert moved(before, after, "seaweedfs_tpu_rs_dispatches_total",
                  op="decode", backend="device_emulated") >= 1
@@ -543,6 +552,8 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
         # heartbeat knows the EC volume gets an error for its lookup, so the
         # table is not fresh and it counts `failed`: the first of the four may
         assert value in (75.0, 100.0)
+    elif name == "http.proxied_share":
+        assert value == 0.0  # every GET here is an EC read: none was replayed
     else:
         assert value > 0, value
     # with nothing recorded the metric is absent, never 0
@@ -552,6 +563,6 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
 def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     names = [e["name"] for e in common.benchmark_json()["per_layer"]]
     new = [name for _cell, name in ALL_NEW_METRICS]
-    new[-1:-1] = BATCH_METRICS  # before ISSUE 29's one
+    new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     assert len(json.dumps(common.benchmark_json())) < 64 << 10
