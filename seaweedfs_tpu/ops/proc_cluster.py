@@ -688,12 +688,56 @@ class ProcCluster:
         self.kill(name)
         child = self.children[name]
         child.close_log()
+        registers = child.spec.role == "volume"
+        if registers:
+            # the master drops the node when its heartbeat stream breaks;
+            # respawned before that, the new process's registration would
+            # be torn down with the old stream's
+            self._wait_listed(child, False, time.monotonic() + 5.0)
         if down_s > 0:
             self._stop_evt.wait(down_s)
         child.spawn()
         deadline = time.monotonic() + (ready_timeout or self.ready_timeout)
         self._wait_ready(child, deadline)
+        # listening is not registered: a lookup right after the restart
+        # races the first heartbeat, which carries the node's volumes
+        if registers and not self._wait_listed(child, True, deadline):
+            raise StartupError(
+                f"{child.name} is up but the master does not list it "
+                f"within {ready_timeout or self.ready_timeout}s"
+            )
         return child.proc.pid
+
+    def _wait_listed(self, child: Child, want: bool, deadline: float) -> bool:
+        """Until the master's topology lists (or no longer lists) the
+        volume server `child`; False at the deadline. A master that
+        cannot be asked (killed by the same fault schedule) ends the
+        wait as satisfied: there is nobody to register with."""
+        url = f"http://127.0.0.1:{self.master_port}/dir/status"
+        me = f"127.0.0.1:{child.spec.port}"
+        while True:
+            try:
+                with urllib.request.urlopen(url, timeout=2.0) as r:
+                    topo = json.load(r).get("Topology") or {}
+            except (urllib.error.URLError, OSError, ValueError):
+                if not any(
+                    c.alive() for c in self.children.values()
+                    if c.spec.role == "master"
+                ):
+                    return True
+                topo = None
+            if topo is not None:
+                listed = any(
+                    dn.get("url") == me
+                    for dc in topo.get("data_centers", ())
+                    for rack in dc.get("racks", ())
+                    for dn in rack.get("data_nodes", ())
+                )
+                if listed == want:
+                    return True
+            if time.monotonic() > deadline or self._stop_evt.is_set():
+                return False
+            time.sleep(0.05)
 
     def apply_fault(self, f: ProcessFault, epoch: float) -> dict:
         child = self.children.get(f.target)

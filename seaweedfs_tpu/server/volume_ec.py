@@ -37,6 +37,12 @@ from ..util.metrics import (
     EC_GENERATE_SECONDS,
     EC_RECONSTRUCTIONS,
     EC_REMOTE_ATTEMPTS,
+    EC_REMOTE_SHARD_READ_BYTES,
+    EC_REMOTE_SHARD_READS,
+    EC_SHARD_COPY_BYTES,
+    EC_SHARD_COPY_SECONDS,
+    EC_SHARD_READ_SERVED_BYTES,
+    REQUEST_HISTOGRAM,
     RETRY_COUNTER,
 )
 from ..storage.erasure_coding import (
@@ -94,9 +100,18 @@ def _read_stage(label: str, annotate: bool = True):
 # otherwise) and, only where the table names a holder of the shard, the
 # holders asked and the forced refreshes after they failed. A shard the
 # fresh table gives nobody costs a read the look-up alone; how often that
-# is, is ec_remote_attempts_total{outcome}
+# is, is ec_remote_attempts_total{outcome}. Inside survivor_read,
+# remote_read: the wall of each survivor fetched from another server (from
+# the asking for its VolumeEcShardRead stream to a holder the table lists
+# until its span is in, retries included), counted by
+# ec_remote_shard_reads_total{outcome}; under a sampled request a stream is
+# the child span `ec.read.remote_read`, which names shards and holders
 _ST_REMOTE_ATTEMPTS = _read_stage("remote_attempts", annotate=False)
 _ST_SURVIVOR_READ = _read_stage("survivor_read", annotate=False)
+_ST_REMOTE_READ = _read_stage("remote_read", annotate=False)
+_SHARD_READ_SERVED = REQUEST_HISTOGRAM.child(
+    server="volume", operation="VolumeEcShardRead"
+)
 _ST_PREAD = trace.stage("ec.read.pread")
 _ST_EXECUTOR_WAIT = _read_stage("executor_wait", annotate=False)
 _ST_DECODE = _read_stage("decode", annotate=False)
@@ -125,6 +140,11 @@ EC_DEGRADED_CACHE_BYTES = (
 # (readahead — neighbouring needles on the same dead shard land in one
 # reconstructed span)
 EC_DEGRADED_SPAN = 128 * 1024
+# the alignment of a reconstruct that has to fetch survivors from other
+# servers: every byte of readahead then crosses gRPC once a remote survivor
+# (six times a span of a 4/4/3/3 spread), where a local survivor's is a
+# page-cache pread; the reference reads the interval alone (store_ec.go:319)
+EC_REMOTE_SPAN = 16 * 1024
 
 
 class DegradedIntervalCache:
@@ -132,7 +152,8 @@ class DegradedIntervalCache:
     (volume_id, shard_id, span_start).
 
     A degraded read widens its interval to EC_DEGRADED_SPAN alignment
-    before reconstructing, caches the whole span, and serves any later
+    (EC_REMOTE_SPAN where survivors come from other servers) before
+    reconstructing, caches the whole span, and serves any later
     interval that falls inside a cached span — so a hot dead shard costs
     one fetch+decode per span instead of per needle. Tombstones invalidate
     the volume's spans (reconstructed bytes may include the deleted
@@ -151,24 +172,29 @@ class DegradedIntervalCache:
 
     @staticmethod
     def span_for(
-        offset: int, size: int, shard_size: Optional[int]
+        offset: int, size: int, shard_size: Optional[int],
+        align: int = EC_DEGRADED_SPAN,
     ) -> tuple[int, int]:
         """Aligned (span_start, span_size) covering [offset, offset+size);
         no readahead when the shard size is unknown (an over-long survivor
         fetch past EOF would read short and poison the reconstruction)."""
         if not shard_size or offset + size > shard_size:
             return offset, size
-        start = offset - (offset % EC_DEGRADED_SPAN)
+        start = offset - (offset % align)
         end = offset + size
-        end += (-end) % EC_DEGRADED_SPAN
+        end += (-end) % align
         return start, min(end, shard_size) - start
 
     def get(
         self, vid: int, shard_id: int, offset: int, size: int
     ) -> Optional[bytes]:
-        start = offset - (offset % EC_DEGRADED_SPAN)
+        starts = (
+            offset - (offset % EC_DEGRADED_SPAN),
+            offset - (offset % EC_REMOTE_SPAN),
+            offset,
+        )
         with self._lock:
-            for key in ((vid, shard_id, start), (vid, shard_id, offset)):
+            for key in ((vid, shard_id, start) for start in starts):
                 span = self._spans.get(key)
                 if span is not None and key[2] + len(span) >= offset + size:
                     self._spans.move_to_end(key)
@@ -544,6 +570,7 @@ class EcHandlers:
         )
         base = volume_base_name(loc.directory, collection, vid)
         stub = Stub(grpc_address(source), "volume")
+        t0 = time.perf_counter()
 
         async def pull(ext: str) -> None:
             tmp = base + ext + ".tmp"
@@ -560,6 +587,7 @@ class EcHandlers:
                     # with scrub + vacuum (one cap over all planes)
                     await self._charge_maintenance(len(chunk), plane=plane)
                     f.write(chunk)
+                    EC_SHARD_COPY_BYTES.inc(len(chunk))
             os.replace(tmp, base + ext)
 
         try:
@@ -579,6 +607,8 @@ class EcHandlers:
             return {}
         except Exception as e:
             return {"error": str(e)}
+        finally:
+            EC_SHARD_COPY_SECONDS.inc(time.perf_counter() - t0)
 
     async def _grpc_ec_delete(self, req, context) -> dict:
         """Remove local shard files; drop index files with the last shard
@@ -670,53 +700,58 @@ class EcHandlers:
 
     async def _grpc_ec_shard_read(self, req, context):
         """Stream bytes of one local shard (ref :270-325)."""
-        vid = int(req["volume_id"])
-        shard_id = int(req["shard_id"])
-        offset = int(req.get("offset", 0))
-        size = int(req.get("size", 0))
-        shard = self.store.find_ec_shard(vid, shard_id)
-        cold_ev = None
-        if shard is None:
-            # cold tier: a shard this server offloaded still streams to
-            # peers — through the read-through cache, so a repairing /
-            # degraded-reading neighbour doesn't force a recall
-            ev = self.store.find_ec_volume(vid)
-            if ev is not None and ev.remote_shard(shard_id) is not None:
-                cold_ev = ev
-            else:
-                yield {"error": f"ec shard {vid}.{shard_id} not found"}
-                return
-        # optional liveness check of the whole needle (ref :283-298)
-        if req.get("file_key"):
-            ev = self.store.find_ec_volume(vid)
-            if ev is not None:
-                try:
-                    _, nsize = ev.find_needle_from_ecx(int(req["file_key"]))
-                    if nsize == TOMBSTONE_FILE_SIZE:
-                        yield {"is_deleted": True}
-                        return
-                except NeedleNotFound:
-                    pass
-        remaining = size
-        pos = offset
-        while remaining > 0:
-            if cold_ev is not None:
-                chunk = await self._read_cold_interval(
-                    cold_ev, shard_id, pos, min(1 << 20, remaining)
-                )
-                if chunk is None:
-                    yield {
-                        "error": f"ec shard {vid}.{shard_id}: remote tier "
-                        "read failed"
-                    }
+        t0 = time.perf_counter()
+        try:
+            vid = int(req["volume_id"])
+            shard_id = int(req["shard_id"])
+            offset = int(req.get("offset", 0))
+            size = int(req.get("size", 0))
+            shard = self.store.find_ec_shard(vid, shard_id)
+            cold_ev = None
+            if shard is None:
+                # cold tier: a shard this server offloaded still streams to
+                # peers — through the read-through cache, so a repairing /
+                # degraded-reading neighbour doesn't force a recall
+                ev = self.store.find_ec_volume(vid)
+                if ev is not None and ev.remote_shard(shard_id) is not None:
+                    cold_ev = ev
+                else:
+                    yield {"error": f"ec shard {vid}.{shard_id} not found"}
                     return
-            else:
-                chunk = shard.read_at(min(1 << 20, remaining), pos)
-            if not chunk:
-                break
-            yield {"data": chunk}
-            pos += len(chunk)
-            remaining -= len(chunk)
+            # optional liveness check of the whole needle (ref :283-298)
+            if req.get("file_key"):
+                ev = self.store.find_ec_volume(vid)
+                if ev is not None:
+                    try:
+                        _, nsize = ev.find_needle_from_ecx(int(req["file_key"]))
+                        if nsize == TOMBSTONE_FILE_SIZE:
+                            yield {"is_deleted": True}
+                            return
+                    except NeedleNotFound:
+                        pass
+            remaining = size
+            pos = offset
+            while remaining > 0:
+                if cold_ev is not None:
+                    chunk = await self._read_cold_interval(
+                        cold_ev, shard_id, pos, min(1 << 20, remaining)
+                    )
+                    if chunk is None:
+                        yield {
+                            "error": f"ec shard {vid}.{shard_id}: remote tier "
+                            "read failed"
+                        }
+                        return
+                else:
+                    chunk = shard.read_at(min(1 << 20, remaining), pos)
+                if not chunk:
+                    break
+                EC_SHARD_READ_SERVED_BYTES.inc(len(chunk))
+                yield {"data": chunk}
+                pos += len(chunk)
+                remaining -= len(chunk)
+        finally:
+            _SHARD_READ_SERVED.observe(time.perf_counter() - t0)
 
     async def _grpc_ec_blob_delete(self, req, context) -> dict:
         """Tombstone a needle in the local .ecx/.ecj (ref :327-352)."""
@@ -887,6 +922,37 @@ class EcHandlers:
                         budget.on_success()
                     return result
         return None
+
+    async def _read_remote_survivor(
+        self, ev: EcVolume, shard_id: int, offset: int, size: int,
+        file_key: int, deadline: Optional[float],
+    ) -> Optional[bytes]:
+        """One survivor span of a reconstruct from whoever the location
+        table lists for the shard, counted by what came back."""
+        holders = self._remote_holders(ev, shard_id)
+        t0 = time.perf_counter()
+        with trace.span(
+            "ec.read.remote_read", shard=shard_id, holders=",".join(holders)
+        ):
+            try:
+                data = await self._read_remote_shard_interval(
+                    ev, shard_id, offset, size, file_key, deadline
+                )
+            except EcHandlers._Deleted:
+                data = None
+        if data is None and not holders:
+            # nobody to ask, nothing sent: no wait to count
+            EC_REMOTE_SHARD_READS.inc(outcome="no_holder")
+            return None
+        _ST_REMOTE_READ.since(t0)
+        if data is None:
+            EC_REMOTE_SHARD_READS.inc(outcome="failed")
+        elif len(data) != size:
+            EC_REMOTE_SHARD_READS.inc(outcome="short")
+        else:
+            EC_REMOTE_SHARD_READS.inc(outcome="ok")
+            EC_REMOTE_SHARD_READ_BYTES.inc(size)
+        return data
 
     async def _read_one_ec_interval(
         self,
@@ -1181,11 +1247,28 @@ class EcHandlers:
                 time.perf_counter() - t_start, result="cache_hit"
             )
             return hit
-        span_start, span_size = cache.span_for(
-            offset, size, ev.shard_size() or None
-        )
-
         total = ev.total_shards
+        candidates = [i for i in range(total) if i != missing_shard]
+        local = [i for i in candidates if ev.find_shard(i) is not None]
+        # survivors with somewhere to come from (the cold tier, a holder
+        # the location table lists) before those the table gives nobody:
+        # a lost shard among the first asked would cost a second round
+        remote = sorted(
+            (i for i in candidates if i not in local),
+            key=lambda i: not (
+                ev.remote_shard(i) is not None or self._remote_holders(ev, i)
+            ),
+        )
+        # local survivors are page-cache preads — take them all (spares are
+        # free); a remote survivor costs its span's bytes over gRPC and its
+        # holder's CPU, and the gather waits for the slowest of those asked:
+        # ask as many as the decode needs, none to spare, widen to the rest
+        # only on a shortfall, and read ahead only as far as EC_REMOTE_SPAN
+        needed = max(0, ev.data_shards - len(local))
+        span_start, span_size = cache.span_for(
+            offset, size, ev.shard_size() or None,
+            EC_REMOTE_SPAN if needed else EC_DEGRADED_SPAN,
+        )
         bufs: list[Optional[np.ndarray]] = [None] * total
 
         async def fetch(shard_id: int) -> None:
@@ -1200,24 +1283,13 @@ class EcHandlers:
                     ev, shard_id, span_start, span_size
                 )
             else:
-                try:
-                    b = await self._read_remote_shard_interval(
-                        ev, shard_id, span_start, span_size, file_key, deadline
-                    )
-                except EcHandlers._Deleted:
-                    b = None
+                b = await self._read_remote_survivor(
+                    ev, shard_id, span_start, span_size, file_key, deadline
+                )
             if b is not None and len(b) == span_size:
                 bufs[shard_id] = np.frombuffer(b, dtype=np.uint8)
 
-        candidates = [i for i in range(total) if i != missing_shard]
-        local = [i for i in candidates if ev.find_shard(i) is not None]
-        remote = [i for i in candidates if ev.find_shard(i) is None]
-        # local survivors are page-cache preads — take them all (spares are
-        # free); remote survivors cost span_size real network bytes each,
-        # so ask only as many holders as the decode needs plus one spare,
-        # widening to the rest only on a shortfall
-        needed = max(0, ev.data_shards - len(local))
-        first = remote[: needed + 1] if needed else []
+        first = remote[:needed]
         with _ST_SURVIVOR_READ():
             await asyncio.gather(*(fetch(i) for i in local + first))
             if sum(1 for b in bufs if b is not None) < ev.data_shards:

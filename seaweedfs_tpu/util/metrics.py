@@ -554,7 +554,9 @@ EC_DEGRADED_READ_STAGE_SECONDS = REGISTRY.counter(
     "degraded EC read stage wall seconds, by stage (remote_attempts = the "
     "TTL'd location refresh, and where a holder is listed the holders "
     "tried and the forced refreshes after them, before any reconstruct; of "
-    "a cold reconstruct: survivor_read/executor_wait/decode/cache_put)",
+    "a cold reconstruct: survivor_read/executor_wait/decode/cache_put; "
+    "remote_read = each survivor fetched from another server, inside "
+    "survivor_read: divide by ec_remote_shard_reads_total)",
 )
 # once for every EC interval that got past the local shard and the cold
 # tier: was there anyone to ask for it, and did they answer
@@ -565,6 +567,36 @@ EC_REMOTE_ATTEMPTS = REGISTRY.counter(
     "was reconstructed at once; served = a listed holder answered; failed "
     "= holders listed and none answered after the forced refreshes, or "
     "the table could not be refreshed: on to reconstruct)",
+)
+# a reconstruct's survivors that are on other servers, on the asking side:
+# one count a survivor fetched through the remote path (its wall is
+# ec_degraded_read_stage_seconds_total{stage="remote_read"}), and the bytes
+# of the spans that came back whole
+EC_REMOTE_SHARD_READS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_remote_shard_reads_total",
+    "survivor spans a reconstruct asked other servers for, by outcome (ok = "
+    "the whole span came back; short = a holder answered another length and "
+    "the survivor is not used; failed = holders listed and none answered, or "
+    "a tombstone; no_holder = the location table names nobody, nothing sent)",
+)
+EC_REMOTE_SHARD_READ_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_remote_shard_read_bytes_total",
+    "bytes of survivor spans read whole from other servers for reconstructs",
+)
+# the serving side of VolumeEcShardRead (its wall and count are
+# request_seconds{server="volume",operation="VolumeEcShardRead"}), and the
+# pulling side of VolumeEcShardsCopy
+EC_SHARD_READ_SERVED_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_shard_read_served_bytes_total",
+    "shard bytes streamed to other servers by VolumeEcShardRead",
+)
+EC_SHARD_COPY_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_shard_copy_bytes_total",
+    "bytes of shard and index files pulled by VolumeEcShardsCopy",
+)
+EC_SHARD_COPY_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_shard_copy_seconds_total",
+    "wall seconds in VolumeEcShardsCopy handlers",
 )
 EC_REBUILD_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_ec_rebuild_stage_seconds",

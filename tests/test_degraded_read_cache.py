@@ -14,6 +14,7 @@ import numpy as np
 from seaweedfs_tpu.server.volume_ec import (
     DegradedIntervalCache,
     EC_DEGRADED_SPAN,
+    EC_REMOTE_SPAN,
     EcHandlers,
 )
 from seaweedfs_tpu.storage.erasure_coding import to_ext, write_ec_files
@@ -93,9 +94,10 @@ def test_survivor_fetches_are_concurrent(tmp_path):
     with open(base + to_ext(3), "rb") as f:
         f.seek(4096)
         assert out == f.read(1024)
-    # remote fetch amplification is trimmed: only k+1 holders are asked
-    # (one spare), in ONE gather — not all 13 candidates
-    assert len(calls) == ev.data_shards + 1
+    # remote fetch amplification is trimmed: only k holders are asked (none
+    # to spare: the gather waits for the slowest of those asked, ISSUE 33),
+    # in ONE gather — not all 13 candidates
+    assert len(calls) == ev.data_shards
     # serial would be >= 11 * delay = 0.55s; concurrent ~= one delay
     assert wall < 7 * delay, f"survivor fetches look serialized: {wall:.3f}s"
     ev.close()
@@ -195,3 +197,25 @@ def test_interval_cache_span_alignment():
         9 * EC_DEGRADED_SPAN + 5, 64, 9 * EC_DEGRADED_SPAN + 100
     )
     assert start + size == 9 * EC_DEGRADED_SPAN + 100
+
+
+def test_interval_cache_finds_a_span_of_the_remote_alignment():
+    # a reconstruct whose survivors cross gRPC reads ahead to EC_REMOTE_SPAN
+    off = 3 * EC_DEGRADED_SPAN + 2 * EC_REMOTE_SPAN + 100
+    start, size = DegradedIntervalCache.span_for(
+        off, 64, 10 * EC_DEGRADED_SPAN, EC_REMOTE_SPAN
+    )
+    assert (start, size) == (off - 100, EC_REMOTE_SPAN)
+    # a record over the boundary takes both sides of it
+    assert DegradedIntervalCache.span_for(
+        off, EC_REMOTE_SPAN, 10 * EC_DEGRADED_SPAN, EC_REMOTE_SPAN
+    ) == (start, 2 * EC_REMOTE_SPAN)
+    cache = DegradedIntervalCache(capacity_bytes=4 * EC_DEGRADED_SPAN)
+    span = bytes(range(256)) * (EC_REMOTE_SPAN // 256)
+    cache.put(1, 3, start, span)
+    assert cache.get(1, 3, off, 64) == span[100:164]
+    assert cache.get(1, 3, start + EC_REMOTE_SPAN - 8, 8) == span[-8:]
+    # past the span's end, or in the wide span's other narrow spans: a miss
+    assert cache.get(1, 3, start + EC_REMOTE_SPAN - 8, 9) is None
+    assert cache.get(1, 3, start - EC_REMOTE_SPAN, 8) is None
+    assert cache.get(1, 3, 3 * EC_DEGRADED_SPAN, 8) is None

@@ -399,6 +399,13 @@ NO_HOLDER_METRICS = {
 PROXIED_METRICS = {
     "warm-rs10.4.degraded-get-c16": ["http.proxied_share"],
 }
+# ISSUE 33's: the spread cell reads the degraded cell's metrics too (its name
+# is appended to their lists), and four of its own come last in BENCHMARK.json
+SPREAD_CELL = "warm-rs10.4-spread4.server-lost-get-c16"
+SPREAD_METRICS = [
+    "ec_read.remote_read_ms", "ec_read.remote_survivors_per_reconstruct",
+    "ec_read.remote_kb_per_get", "peers.cpu_cores",
+]
 ALL_NEW_METRICS = [
     (cell, name)
     for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS, PROXIED_METRICS)
@@ -523,7 +530,7 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
     before, after, _wrote, _got = degraded_get
     spec = common.load("layer_metrics", name + ".json")
     entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == name)
-    also = [BATCH_CELL] if cell == "warm-rs10.4.ec-encode" else []
+    also = [BATCH_CELL] if cell == "warm-rs10.4.ec-encode" else [SPREAD_CELL]
     assert entry["workloads"] == [cell] + also
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == spec[key], key
@@ -564,5 +571,12 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     names = [e["name"] for e in common.benchmark_json()["per_layer"]]
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
+    new += SPREAD_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
+    per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
+    for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
+        assert per_layer[name]["workloads"] == [SPREAD_CELL]
+        spec = common.load("layer_metrics", name + ".json")
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert per_layer[name][key] == spec[key], (name, key)
     assert len(json.dumps(common.benchmark_json())) < 64 << 10

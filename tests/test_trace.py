@@ -385,10 +385,16 @@ def test_group_commit_flush_links_members(tmp_path):
                 worker.write(Needle(id=2, cookie=1, data=b"b" * 64)),
             )
             sp.finish()
-            flushes = [
-                s for s in trace.RECORDER.spans()
-                if s["name"] == "group_commit.flush" and s["trace"] == tid
-            ]
+            # the committer resolves the writes from its executor thread
+            # before the worker coroutine is back to close the flush span
+            for _ in range(200):
+                flushes = [
+                    s for s in trace.RECORDER.spans()
+                    if s["name"] == "group_commit.flush" and s["trace"] == tid
+                ]
+                if flushes:
+                    break
+                await asyncio.sleep(0.01)
             assert flushes, trace.RECORDER.spans()
             assert flushes[0]["links"]
             assert flushes[0]["tags"]["vid"] == 77
