@@ -38,6 +38,7 @@ from ..util.metrics import (
     EC_RECONSTRUCTIONS,
     EC_REMOTE_ATTEMPTS,
     EC_REMOTE_SHARD_READ_BYTES,
+    EC_REMOTE_SHARD_READ_STREAMS,
     EC_REMOTE_SHARD_READS,
     EC_SHARD_COPY_BYTES,
     EC_SHARD_COPY_SECONDS,
@@ -103,12 +104,17 @@ def _read_stage(label: str, annotate: bool = True):
 # is, is ec_remote_attempts_total{outcome}. Inside survivor_read,
 # remote_read: the wall of each survivor fetched from another server (from
 # the asking for its VolumeEcShardRead stream to a holder the table lists
-# until its span is in, retries included), counted by
-# ec_remote_shard_reads_total{outcome}; under a sampled request a stream is
-# the child span `ec.read.remote_read`, which names shards and holders
+# until its span is in, retries included), counted a survivor by
+# ec_remote_shard_reads_total{outcome} whichever stream carried it; the
+# streams themselves are ec_remote_shard_read_streams_total{shape}, and
+# under a sampled request each is the child span `ec.read.remote_read`,
+# which names its shards and its holder
 _ST_REMOTE_ATTEMPTS = _read_stage("remote_attempts", annotate=False)
 _ST_SURVIVOR_READ = _read_stage("survivor_read", annotate=False)
 _ST_REMOTE_READ = _read_stage("remote_read", annotate=False)
+_STREAMS_GROUPED = EC_REMOTE_SHARD_READ_STREAMS.child(shape="grouped")
+_STREAMS_SINGLE = EC_REMOTE_SHARD_READ_STREAMS.child(shape="single")
+_STREAMS_REGROUPED = EC_REMOTE_SHARD_READ_STREAMS.child(shape="regrouped")
 _SHARD_READ_SERVED = REQUEST_HISTOGRAM.child(
     server="volume", operation="VolumeEcShardRead"
 )
@@ -116,6 +122,19 @@ _ST_PREAD = trace.stage("ec.read.pread")
 _ST_EXECUTOR_WAIT = _read_stage("executor_wait", annotate=False)
 _ST_DECODE = _read_stage("decode", annotate=False)
 _ST_CACHE_PUT = _read_stage("cache_put")
+
+
+def _count_remote_survivor(t0: float, data: Optional[bytes], size: int) -> None:
+    """One survivor asked of another server at `t0`, by what came back."""
+    _ST_REMOTE_READ.since(t0)
+    if data is None:
+        EC_REMOTE_SHARD_READS.inc(outcome="failed")
+    elif len(data) != size:
+        EC_REMOTE_SHARD_READS.inc(outcome="short")
+    else:
+        EC_REMOTE_SHARD_READS.inc(outcome="ok")
+        EC_REMOTE_SHARD_READ_BYTES.inc(size)
+
 
 # total wall-clock budget for one EC needle read, across every interval,
 # remote attempt, location refresh and reconstruction; each remote RPC gets
@@ -698,26 +717,40 @@ class EcHandlers:
             self.store.note_ec_shards_changed(vid, "", ShardBits(), removed)
         return {}
 
+    def _served_shard(self, vid: int, shard_id: int) -> tuple:
+        """(shard, cold_ev) of a shard this server streams to others: the
+        mounted shard, or the volume of one it offloaded to the cold tier —
+        read through the read-through cache, so a repairing or
+        degraded-reading neighbour doesn't force a recall; (None, None)
+        where it has neither."""
+        shard = self.store.find_ec_shard(vid, shard_id)
+        if shard is not None:
+            return shard, None
+        ev = self.store.find_ec_volume(vid)
+        if ev is not None and ev.remote_shard(shard_id) is not None:
+            return None, ev
+        return None, None
+
     async def _grpc_ec_shard_read(self, req, context):
-        """Stream bytes of one local shard (ref :270-325)."""
+        """Stream bytes of one local shard (ref :270-325). With `shard_ids`
+        (our extension: the survivors of one reconstruct that this server
+        holds) the same [offset, offset+size) of each shard in turn over the
+        one stream, after one liveness check; every message then carries its
+        `shard_id`, and a shard this server does not hold is answered with
+        an `error` of its own and the stream goes on to the next."""
         t0 = time.perf_counter()
         try:
             vid = int(req["volume_id"])
-            shard_id = int(req["shard_id"])
             offset = int(req.get("offset", 0))
             size = int(req.get("size", 0))
-            shard = self.store.find_ec_shard(vid, shard_id)
-            cold_ev = None
-            if shard is None:
-                # cold tier: a shard this server offloaded still streams to
-                # peers — through the read-through cache, so a repairing /
-                # degraded-reading neighbour doesn't force a recall
-                ev = self.store.find_ec_volume(vid)
-                if ev is not None and ev.remote_shard(shard_id) is not None:
-                    cold_ev = ev
-                else:
-                    yield {"error": f"ec shard {vid}.{shard_id} not found"}
-                    return
+            group = req.get("shard_ids")
+            served = [
+                (int(s), self._served_shard(vid, int(s)))
+                for s in group or (req["shard_id"],)
+            ]
+            if not group and served[0][1] == (None, None):
+                yield {"error": f"ec shard {vid}.{served[0][0]} not found"}
+                return
             # optional liveness check of the whole needle (ref :283-298)
             if req.get("file_key"):
                 ev = self.store.find_ec_volume(vid)
@@ -729,27 +762,33 @@ class EcHandlers:
                             return
                     except NeedleNotFound:
                         pass
-            remaining = size
-            pos = offset
-            while remaining > 0:
-                if cold_ev is not None:
-                    chunk = await self._read_cold_interval(
-                        cold_ev, shard_id, pos, min(1 << 20, remaining)
-                    )
-                    if chunk is None:
-                        yield {
-                            "error": f"ec shard {vid}.{shard_id}: remote tier "
-                            "read failed"
-                        }
-                        return
-                else:
-                    chunk = shard.read_at(min(1 << 20, remaining), pos)
-                if not chunk:
-                    break
-                EC_SHARD_READ_SERVED_BYTES.inc(len(chunk))
-                yield {"data": chunk}
-                pos += len(chunk)
-                remaining -= len(chunk)
+            for shard_id, (shard, cold_ev) in served:
+                tag = {"shard_id": shard_id} if group else {}
+                if shard is None and cold_ev is None:
+                    yield {**tag, "error": f"ec shard {vid}.{shard_id} not found"}
+                    continue
+                remaining = size
+                pos = offset
+                while remaining > 0:
+                    if cold_ev is not None:
+                        chunk = await self._read_cold_interval(
+                            cold_ev, shard_id, pos, min(1 << 20, remaining)
+                        )
+                        if chunk is None:
+                            yield {
+                                **tag,
+                                "error": f"ec shard {vid}.{shard_id}: remote "
+                                "tier read failed",
+                            }
+                            break
+                    else:
+                        chunk = shard.read_at(min(1 << 20, remaining), pos)
+                    if not chunk:
+                        break
+                    EC_SHARD_READ_SERVED_BYTES.inc(len(chunk))
+                    yield {**tag, "data": chunk}
+                    pos += len(chunk)
+                    remaining -= len(chunk)
         finally:
             _SHARD_READ_SERVED.observe(time.perf_counter() - t0)
 
@@ -854,10 +893,12 @@ class EcHandlers:
 
     async def _read_remote_shard_once(
         self, ev: EcVolume, url: str, shard_id: int, offset: int, size: int,
-        file_key: int, deadline: Optional[float],
+        file_key: int, deadline: Optional[float], sent=None,
     ) -> bytes:
         stub = Stub(grpc_address(url), "volume")
         buf = bytearray()
+        if sent is not None:
+            sent.inc()
         async for msg in stub.server_stream(
             "VolumeEcShardRead",
             {
@@ -884,11 +925,13 @@ class EcHandlers:
         size: int,
         file_key: int,
         deadline: Optional[float] = None,
+        sent=None,
     ) -> Optional[bytes]:
         """Try each known holder of the shard; per-url transient failures
         get one jittered retry, and every RPC's timeout is the remaining
         read deadline (a stalled holder can no longer eat a bare 30s of a
-        15s read budget). Raises _Deleted on a tombstone answer."""
+        15s read budget). Raises _Deleted on a tombstone answer. `sent`
+        (a counter child) moves once a stream sent."""
         rng = getattr(self, "_backoff_rng", None)
         budget = shared_retry_budget()
         for url in self._remote_holders(ev, shard_id):
@@ -897,7 +940,8 @@ class EcHandlers:
                     return None
                 try:
                     result = await self._read_remote_shard_once(
-                        ev, url, shard_id, offset, size, file_key, deadline
+                        ev, url, shard_id, offset, size, file_key, deadline,
+                        sent,
                     )
                 except EcHandlers._Deleted:
                     raise
@@ -926,17 +970,23 @@ class EcHandlers:
     async def _read_remote_survivor(
         self, ev: EcVolume, shard_id: int, offset: int, size: int,
         file_key: int, deadline: Optional[float],
+        sent=_STREAMS_SINGLE, t0: Optional[float] = None,
     ) -> Optional[bytes]:
         """One survivor span of a reconstruct from whoever the location
-        table lists for the shard, counted by what came back."""
+        table lists for the shard, counted by what came back; `sent` counts
+        its streams. `t0` is when the survivor was first asked for, where
+        that was on a grouped stream and it did not arrive whole."""
         holders = self._remote_holders(ev, shard_id)
-        t0 = time.perf_counter()
+        if t0 is None:
+            t0 = time.perf_counter()
         with trace.span(
-            "ec.read.remote_read", shard=shard_id, holders=",".join(holders)
+            "ec.read.remote_read", shards=str(shard_id),
+            holder=",".join(holders),
         ):
             try:
                 data = await self._read_remote_shard_interval(
-                    ev, shard_id, offset, size, file_key, deadline
+                    ev, shard_id, offset, size, file_key, deadline,
+                    sent=sent,
                 )
             except EcHandlers._Deleted:
                 data = None
@@ -944,15 +994,82 @@ class EcHandlers:
             # nobody to ask, nothing sent: no wait to count
             EC_REMOTE_SHARD_READS.inc(outcome="no_holder")
             return None
-        _ST_REMOTE_READ.since(t0)
-        if data is None:
-            EC_REMOTE_SHARD_READS.inc(outcome="failed")
-        elif len(data) != size:
-            EC_REMOTE_SHARD_READS.inc(outcome="short")
-        else:
-            EC_REMOTE_SHARD_READS.inc(outcome="ok")
-            EC_REMOTE_SHARD_READ_BYTES.inc(size)
+        _count_remote_survivor(t0, data, size)
         return data
+
+    async def _read_remote_survivor_group(
+        self, ev: EcVolume, url: str, shard_ids: list[int], offset: int,
+        size: int, file_key: int, deadline: Optional[float],
+    ) -> dict[int, Optional[bytes]]:
+        """The same span of the survivors of a reconstruct that ONE holder
+        lists, over one VolumeEcShardRead stream (`shard_ids`: the call
+        costs a holder more than its bytes do), each counted as a survivor
+        of its own by what came back for it. `shard_id` is the first of
+        them, so a holder that does not know `shard_ids` serves that one as
+        it always did; a shard that came back with an error, short or not at
+        all, or whose stream raised, is asked for again alone
+        (_read_remote_survivor: every listed holder, the retry, the
+        deadline), and the ones that arrived whole are used. A tombstone
+        answers for them all."""
+        t0 = time.perf_counter()
+        parts: dict[int, list] = {s: [] for s in shard_ids}
+        deleted = False
+        budget = shared_retry_budget()
+        _STREAMS_GROUPED.inc()
+        with trace.span(
+            "ec.read.remote_read",
+            shards=",".join(map(str, shard_ids)), holder=url,
+        ):
+            try:
+                async for msg in Stub(grpc_address(url), "volume").server_stream(
+                    "VolumeEcShardRead",
+                    {
+                        "volume_id": ev.volume_id,
+                        "shard_id": shard_ids[0],
+                        "shard_ids": shard_ids,
+                        "offset": offset,
+                        "size": size,
+                        "file_key": file_key,
+                    },
+                    timeout=remaining(deadline, 30.0),
+                ):
+                    if msg.get("is_deleted"):
+                        deleted = True
+                        break
+                    # an untagged message is of `shard_id`: the holder does
+                    # not know `shard_ids`
+                    got = parts.get(int(msg.get("shard_id", shard_ids[0])))
+                    if got is not None:
+                        # None: the shard is not whole, whatever else came
+                        got.append(
+                            None if msg.get("error") else msg.get("data", b"")
+                        )
+            except Exception:
+                # what arrived whole before the stream broke is used; the
+                # rest is asked for again, each alone
+                if budget is not None:
+                    budget.on_failure()
+            else:
+                if budget is not None:
+                    budget.on_success()
+        out: dict[int, Optional[bytes]] = {}
+        again = []
+        for shard_id, got in parts.items():
+            whole = None not in got and sum(map(len, got)) == size
+            if whole or deleted:
+                # one message a span up to 1 MiB: the join is that message
+                out[shard_id] = b"".join(got) if whole else None
+                _count_remote_survivor(t0, out[shard_id], size)
+            else:
+                again.append(shard_id)
+        out.update(zip(again, await asyncio.gather(*(
+            self._read_remote_survivor(
+                ev, s, offset, size, file_key, deadline,
+                sent=_STREAMS_REGROUPED, t0=t0,
+            )
+            for s in again
+        ))))
+        return out
 
     async def _read_one_ec_interval(
         self,
@@ -1231,11 +1348,13 @@ class EcHandlers:
         """Reconstruct [offset, offset+size) of a shard nobody can serve:
         all survivor intervals are fetched CONCURRENTLY (local pread +
         remote streams in one gather — wall clock is the slowest survivor,
-        not the sum), decoded missing-row-only through the shared
-        decode-matrix LRU, and the whole readahead-widened span is kept in
-        the degraded-read cache so the next needle on this dead shard skips
-        the fetch+decode entirely (ref store_ec.go:319-373 fetches, then
-        reconstructs all rows, every time)."""
+        not the sum; the survivors one holder lists share one stream, a
+        holder's call costing more than its bytes), decoded
+        missing-row-only through the shared decode-matrix LRU, and the
+        whole readahead-widened span is kept in the degraded-read cache so
+        the next needle on this dead shard skips the fetch+decode entirely
+        (ref store_ec.go:319-373 fetches, then reconstructs all rows, every
+        time)."""
         import numpy as np
 
         t_start = time.perf_counter()
@@ -1253,11 +1372,12 @@ class EcHandlers:
         # survivors with somewhere to come from (the cold tier, a holder
         # the location table lists) before those the table gives nobody:
         # a lost shard among the first asked would cost a second round
+        holders = {
+            i: self._remote_holders(ev, i) for i in candidates if i not in local
+        }
         remote = sorted(
-            (i for i in candidates if i not in local),
-            key=lambda i: not (
-                ev.remote_shard(i) is not None or self._remote_holders(ev, i)
-            ),
+            holders,
+            key=lambda i: not (ev.remote_shard(i) is not None or holders[i]),
         )
         # local survivors are page-cache preads — take them all (spares are
         # free); a remote survivor costs its span's bytes over gRPC and its
@@ -1270,6 +1390,10 @@ class EcHandlers:
             EC_REMOTE_SPAN if needed else EC_DEGRADED_SPAN,
         )
         bufs: list[Optional[np.ndarray]] = [None] * total
+
+        def keep(shard_id: int, b: Optional[bytes]) -> None:
+            if b is not None and len(b) == span_size:
+                bufs[shard_id] = np.frombuffer(b, dtype=np.uint8)
 
         async def fetch(shard_id: int) -> None:
             shard = ev.find_shard(shard_id)
@@ -1286,12 +1410,29 @@ class EcHandlers:
                 b = await self._read_remote_survivor(
                     ev, shard_id, span_start, span_size, file_key, deadline
                 )
-            if b is not None and len(b) == span_size:
-                bufs[shard_id] = np.frombuffer(b, dtype=np.uint8)
+            keep(shard_id, b)
+
+        async def fetch_group(url: str, shard_ids: list[int]) -> None:
+            got = await self._read_remote_survivor_group(
+                ev, url, shard_ids, span_start, span_size, file_key, deadline
+            )
+            for shard_id, b in got.items():
+                keep(shard_id, b)
 
         first = remote[:needed]
+        # the survivors to fetch from other servers, by the holder the table
+        # names first for each: two or more of one holder ride one stream
+        by_holder: dict[str, list[int]] = {}
+        for i in first:
+            if holders[i] and ev.remote_shard(i) is None:
+                by_holder.setdefault(holders[i][0], []).append(i)
+        groups = {u: g for u, g in by_holder.items() if len(g) > 1}
+        grouped = {i for g in groups.values() for i in g}
         with _ST_SURVIVOR_READ():
-            await asyncio.gather(*(fetch(i) for i in local + first))
+            await asyncio.gather(
+                *(fetch(i) for i in local + first if i not in grouped),
+                *(fetch_group(url, g) for url, g in groups.items()),
+            )
             if sum(1 for b in bufs if b is not None) < ev.data_shards:
                 rest = [i for i in remote if i not in first]
                 if rest:

@@ -583,6 +583,17 @@ EC_REMOTE_SHARD_READ_BYTES = REGISTRY.counter(
     "seaweedfs_tpu_ec_remote_shard_read_bytes_total",
     "bytes of survivor spans read whole from other servers for reconstructs",
 )
+# the RPCs those survivors cost: the survivors one holder lists ride one
+# stream, so over ec_reconstructions_total{kind="cold"} this is the calls a
+# reconstruct made where ec_remote_shard_reads_total is the spans it asked
+EC_REMOTE_SHARD_READ_STREAMS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_remote_shard_read_streams_total",
+    "VolumeEcShardRead streams sent for a reconstruct's survivors, by shape "
+    "(grouped = one stream for the two or more survivors one holder lists; "
+    "single = one survivor's, every retry and further holder one more; "
+    "regrouped = a single stream sent because a survivor of a grouped "
+    "stream did not arrive whole)",
+)
 # the serving side of VolumeEcShardRead (its wall and count are
 # request_seconds{server="volume",operation="VolumeEcShardRead"}), and the
 # pulling side of VolumeEcShardsCopy

@@ -76,7 +76,7 @@ def test_survivor_fetches_are_concurrent(tmp_path):
     delay = 0.05
     calls = []
 
-    async def injected_remote_read(ev_, shard_id, offset, size, key, deadline=None):
+    async def injected_remote_read(ev_, shard_id, offset, size, key, deadline=None, sent=None):
         calls.append(shard_id)
         await asyncio.sleep(delay)  # injected network latency
         with open(base + to_ext(shard_id), "rb") as f:

@@ -5,7 +5,11 @@ held to the benchmark's plain reference (ISSUE 33): the spread against
 (a reconstruct whose survivors cross gRPC, equal to the reference codec's
 reconstruction from the ten survivors the plan names), the clean failure after
 two are lost, a holder that answers a short span, and the counters and stages
-of the remote survivor read against what the test counts by hand.
+of the remote survivor read against what the test counts by hand. The
+survivors one holder lists ride ONE `VolumeEcShardRead` stream (ISSUE 34):
+streams and spans counted apart, a holder that does not know `shard_ids`, a
+shard of a group that does not arrive, a tombstone, and a request without
+`shard_ids` answered message for message as the parent's handler answers it.
 
 Three clusters for the module's life, each on an event loop of its own thread:
 every server up, one holder stopped, two holders stopped. Every test awaits
@@ -21,13 +25,16 @@ import aiohttp
 import numpy as np
 import pytest
 
+from benchmarks.lib import common, metrics as layer_metrics
 from benchmarks.reference import ec_spread, rs_codec
 from seaweedfs_tpu.client.operation import upload_data
+from seaweedfs_tpu.pb import grpc_address
 from seaweedfs_tpu.server import volume_ec
 from seaweedfs_tpu.shell import CommandEnv, run_command
 from seaweedfs_tpu.storage.erasure_coding import to_ext
-from seaweedfs_tpu.storage.erasure_coding.ec_volume import EcVolumeShard
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import EcVolumeShard, NeedleNotFound
 from seaweedfs_tpu.storage.file_id import format_needle_id_cookie
+from seaweedfs_tpu.types import TOMBSTONE_FILE_SIZE
 from seaweedfs_tpu.util import trace
 
 from test_cluster import Cluster, assign_retry
@@ -42,6 +49,7 @@ SPAN = volume_ec.EC_REMOTE_SPAN  # a reconstruct with survivors on other servers
 IDX_ENTRY = np.dtype([("key", ">u8"), ("off", ">u4"), ("size", ">u4")])
 READS = "seaweedfs_tpu_ec_remote_shard_reads_total"
 READ_BYTES = "seaweedfs_tpu_ec_remote_shard_read_bytes_total"
+STREAMS = "seaweedfs_tpu_ec_remote_shard_read_streams_total"
 STAGE = "seaweedfs_tpu_ec_degraded_read_stage_seconds_total"
 ATTEMPTS = "seaweedfs_tpu_ec_remote_attempts_total"
 SERVED = "seaweedfs_tpu_request_seconds_count"
@@ -297,6 +305,18 @@ def plan(cluster, vs, missing: int) -> list:
     return sorted(own + listed[: K - len(own)])
 
 
+def streams_of(cluster, vs, missing: int) -> tuple:
+    """(grouped, single): the streams one reconstruct on `vs` sends by hand —
+    the planned survivors on other servers by their holder, two or more of
+    one holder in one stream. `grouped` is {holder: [shards]}."""
+    by_holder = {}
+    for s in plan(cluster, vs, missing):
+        if s not in cluster.shards_on(vs):
+            by_holder.setdefault(cluster.spread[s][0], []).append(s)
+    grouped = {url: shards for url, shards in by_holder.items() if len(shards) > 1}
+    return grouped, len(by_holder) - len(grouped)
+
+
 def test_a_reconstruct_is_the_reference_codecs(one_lost):
     vs = one_lost.source
     ev = vs.store.find_ec_volume(one_lost.vid)
@@ -357,14 +377,20 @@ def test_counters_and_stages_of_one_reconstruct_by_hand(one_lost):
     assert moved(before, after, READ_BYTES) == ok * SPAN
     assert moved(before, after, STAGE, stage="remote_read") > 0
     assert moved(before, after, STAGE, stage="survivor_read") > 0
-    # one span a survivor that was asked for, naming its shard and its holder
-    named = {int(s["tags"]["shard"]): s["tags"]["holders"] for s in spans}
-    assert len(spans) == len(named) == ok
-    assert all(one_lost.spread[shard] == [holder] for shard, holder in named.items())
-    assert not set(named) & (one_lost.lost_shards | own)
-    assert len(set(named.values())) == len(one_lost.live) - 1
-    # and on the serving side: one stream a survivor, of its span's bytes
-    assert moved(before, after, SERVED, **SHARD_READ) == ok
+    # one stream a holder (one a survivor where a holder serves one alone):
+    # a span each, naming its shards and its holder
+    grouped, single = streams_of(one_lost, vs, missing)
+    assert len(grouped) == 2 and sum(map(len, grouped.values())) + single == ok
+    named = {s["tags"]["holder"]: [int(x) for x in s["tags"]["shards"].split(",")] for s in spans}
+    assert len(spans) == len(named) == len(grouped) + single == len(one_lost.live) - 1
+    assert all(one_lost.spread[shard] == [holder] for holder, shards in named.items() for shard in shards)
+    assert {h: g for h, g in named.items() if len(g) > 1} == grouped
+    assert not {x for g in named.values() for x in g} & (one_lost.lost_shards | own)
+    assert moved(before, after, STREAMS, shape="grouped") == len(grouped)
+    assert moved(before, after, STREAMS, shape="single") == single
+    assert moved(before, after, STREAMS) == len(spans)
+    # and on the serving side: one stream a holder, every survivor's span in its bytes
+    assert moved(before, after, SERVED, **SHARD_READ) == len(spans) < ok
     assert moved(before, after, SERVED_BYTES) == ok * SPAN
 
 
@@ -395,6 +421,176 @@ def test_a_holder_that_answers_short_is_not_used(one_lost, monkeypatch):
         assert got is None  # four shards lost and a fifth unusable: no answer, not a wrong one
     assert moved(before, after, READS, outcome="short") == 1
     assert moved(before, after, READ_BYTES) == moved(before, after, READS, outcome="ok") * SPAN
+    # counted short once, as a survivor, though two streams carried it short:
+    # its group's, then one of its own (the others of its group were used)
+    grouped, _single = streams_of(one_lost, vs, missing)
+    was_grouped = any(short in shards for shards in grouped.values())
+    assert moved(before, after, STREAMS, shape="grouped") == len(grouped)
+    assert moved(before, after, STREAMS, shape="regrouped") == int(was_grouped)
+
+
+def test_one_reconstruct_sends_one_stream_a_live_holder(one_lost):
+    vs = one_lost.source
+    ev = vs.store.find_ec_volume(one_lost.vid)
+    missing = min(one_lost.lost_shards)
+    survivors = plan(one_lost, vs, missing)
+    asked = K - len(one_lost.shards_on(vs))
+    off = 3 * SPAN + 17
+
+    async def body():
+        vs._ec_degraded_cache().invalidate(one_lost.vid)
+        return await vs._recover_one_interval(ev, missing, off, 2000, 1)
+
+    before = scrape()
+    got = one_lost.run(body())
+    after = scrape()
+
+    def span(s):
+        with open(one_lost.shard_file(s), "rb") as f:
+            f.seek(3 * SPAN)
+            return np.frombuffer(f.read(SPAN), dtype=np.uint8)
+
+    want = rs_codec.Codec(K, M).recover({s: span(s) for s in survivors}, [missing])[0]
+    assert got == want.tobytes()[17:2017]
+    # two live holders, two streams: the spans are counted a survivor, the calls a holder
+    assert moved(before, after, SERVED, **SHARD_READ) == 2
+    assert moved(before, after, STREAMS, shape="grouped") == moved(before, after, STREAMS) == 2
+    assert moved(before, after, READS, outcome="ok") == moved(before, after, READS) == asked >= 6
+    assert moved(before, after, READ_BYTES) == moved(before, after, SERVED_BYTES) == asked * SPAN
+    assert moved(before, after, "seaweedfs_tpu_ec_reconstructions_total", kind="cold") == 1
+    # the benchmark's metric file, evaluated as a run evaluates it; a program
+    # without the family (the parent) leaves the metric out of the line
+    spec = common.load("layer_metrics", "ec_read.remote_streams_per_reconstruct.json")
+    assert layer_metrics.Observed(before, after, {}, {}, {}, {}, None, None, {}).value(spec) == 2.0
+    parents = [{k: v for k, v in page.items() if not k.startswith(STREAMS)} for page in (before, after)]
+    assert layer_metrics.Observed(*parents, {}, {}, {}, {}, None, None, {}).value(spec) is None
+
+
+async def _parents_shard_read(vs, req):
+    """`_grpc_ec_shard_read` as the parent commit has it (4ce3058: it knows
+    `shard_id` alone), less its cold-tier branch, which no shard here takes."""
+    vid, shard_id = int(req["volume_id"]), int(req["shard_id"])
+    offset, size = int(req.get("offset", 0)), int(req.get("size", 0))
+    shard = vs.store.find_ec_shard(vid, shard_id)
+    if shard is None:
+        yield {"error": f"ec shard {vid}.{shard_id} not found"}
+        return
+    if req.get("file_key"):
+        ev = vs.store.find_ec_volume(vid)
+        if ev is not None:
+            try:
+                _, nsize = ev.find_needle_from_ecx(int(req["file_key"]))
+                if nsize == TOMBSTONE_FILE_SIZE:
+                    yield {"is_deleted": True}
+                    return
+            except NeedleNotFound:
+                pass
+    left, pos = size, offset
+    while left > 0:
+        chunk = shard.read_at(min(1 << 20, left), pos)
+        if not chunk:
+            break
+        yield {"data": chunk}
+        pos += len(chunk)
+        left -= len(chunk)
+
+
+class _Routed:
+    """`volume_ec.Stub` for a test: a `VolumeEcShardRead` stream to a gRPC
+    address in `handlers` is answered on the asking loop by that handler (an
+    async generator of the request); every other call crosses the real wire."""
+
+    def __init__(self, handlers: dict):
+        self.handlers, self.real, self.sent = handlers, volume_ec.Stub, []
+
+    def __call__(self, address, service, channel=None):
+        stub = self.real(address, service, channel)
+        handler = self.handlers.get(address)
+        if handler is not None:
+            def server_stream(method, request, timeout=None):
+                assert method == "VolumeEcShardRead"
+                self.sent.append(request)
+                return handler(request)
+
+            stub.server_stream = server_stream
+        return stub
+
+
+def test_holders_that_ignore_shard_ids_still_give_every_needle_back(one_lost, monkeypatch):
+    vs = one_lost.source
+    old = {grpc_address(h.address): (lambda req, h=h: _parents_shard_read(h, req))
+           for h in one_lost.live if h is not vs}
+    wire = _Routed(old)
+    monkeypatch.setattr(volume_ec, "Stub", wire)
+
+    async def body():
+        vs._ec_degraded_cache().invalidate(one_lost.vid)
+        before = scrape()
+        for key, data in one_lost.body.items():
+            assert await one_lost.get(vs, key) == (200, data), key
+        return before, scrape()
+
+    before, after = one_lost.run(body(), timeout=120)
+    cold = moved(before, after, "seaweedfs_tpu_ec_reconstructions_total", kind="cold")
+    grouped = moved(before, after, STREAMS, shape="grouped")
+    assert cold > 0 and grouped == 2 * cold
+    assert grouped == sum("shard_ids" in req for req in wire.sent)
+    # an old holder serves the group's first shard; the others come each alone
+    asked = K - len(one_lost.shards_on(vs))
+    assert moved(before, after, STREAMS, shape="regrouped") == (asked - 2) * cold
+    assert moved(before, after, READS, outcome="ok") == moved(before, after, READS) == asked * cold
+
+
+@pytest.mark.parametrize("fault", ["error", "short"])
+def test_a_shard_of_a_group_that_does_not_arrive_is_fetched_again_alone(one_lost, monkeypatch, fault):
+    vs = one_lost.source
+    ev = vs.store.find_ec_volume(one_lost.vid)
+    missing = min(one_lost.lost_shards)
+    grouped, single = streams_of(one_lost, vs, missing)
+    url, group = sorted(grouped.items())[0]
+    holder = one_lost.cluster.server_for(url)
+    target = holder.store.find_ec_shard(one_lost.vid, group[1])
+    # the fault strikes the group's stream alone: one look-up, or the two reads
+    # of a stream's loop (a byte short, then nothing for the byte left)
+    left = [1 if fault == "error" else 2]
+
+    def struck(shard) -> bool:
+        if shard is target and left[0]:
+            left[0] -= 1
+            return True
+        return False
+
+    if fault == "error":  # the holder does not find the shard: `{"shard_id": s, "error": ...}`
+        inner = holder.store.find_ec_shard
+        monkeypatch.setattr(
+            holder.store, "find_ec_shard",
+            lambda vid, s: None if struck(inner(vid, s)) else inner(vid, s))
+    else:
+        inner = EcVolumeShard.read_at
+        monkeypatch.setattr(
+            EcVolumeShard, "read_at",
+            lambda self, size, offset: inner(self, size - struck(self), offset))
+
+    async def body():
+        vs._ec_degraded_cache().invalidate(one_lost.vid)
+        return await vs._recover_one_interval(ev, missing, 5 * SPAN, 4096, 1)
+
+    before = scrape()
+    got = one_lost.run(body())
+    after = scrape()
+    assert not left[0]
+    with open(one_lost.shard_file(missing), "rb") as f:
+        f.seek(5 * SPAN)
+        assert got == f.read(4096)
+    asked = K - len(one_lost.shards_on(vs))
+    # every survivor counted once and `ok`: the two that arrived whole were
+    # used, the third came over a stream of its own, and no second round ran
+    assert moved(before, after, READS, outcome="ok") == moved(before, after, READS) == asked
+    assert moved(before, after, READ_BYTES) == asked * SPAN
+    assert moved(before, after, STREAMS, shape="grouped") == len(grouped) == 2
+    assert moved(before, after, STREAMS, shape="regrouped") == 1
+    assert moved(before, after, STREAMS, shape="single") == single == 0
+    assert moved(before, after, SERVED, **SHARD_READ) == 3
 
 
 # ------------------------------------------------------------- two holders lost
@@ -438,15 +634,16 @@ def _one_server(tmp_path, mounted: int):
         ev.add_shard(EcVolumeShard(str(tmp_path), "", 1, shard))
     host, asked = _Host(), []
 
-    async def remote(_ev, shard_id, offset, size, key, deadline=None):
+    async def remote(_ev, shard_id, offset, size, key, deadline=None, sent=None):
         asked.append((shard_id, offset, size))
         with open(base + to_ext(shard_id), "rb") as f:
             f.seek(offset)
             return f.read(size)
 
     host._read_remote_shard_interval = remote
-    # every shard that is not mounted has a holder in the table
-    ev.shard_locations.update({s: ["127.0.0.1:1"] for s in range(K + M) if s != DEAD})
+    # every shard that is not mounted has a holder in the table, each its
+    # own: no two share a stream, so every survivor comes through the seam
+    ev.shard_locations.update({s: [f"127.0.0.1:{s + 1}"] for s in range(K + M) if s != DEAD})
     ev.shard_locations_refresh_time = 1e18
     return base, ev, host, asked
 
@@ -540,4 +737,110 @@ def test_a_span_cached_under_one_alignment_serves_a_read_of_the_other(tmp_path, 
     assert not asked and _cached_spans(host) == [want]
     assert moved(before, after, "seaweedfs_tpu_ec_reconstructions_total", kind="cache_hit") == 1
     assert moved(before, after, "seaweedfs_tpu_ec_reconstructions_total", kind="cold") == 0
+    ev.close()
+
+
+# ------------------------------------- the serving side of `VolumeEcShardRead`
+# One holder's handler alone, on a real EC volume with one needle (key 7) in
+# its .ecx: what it answers with and without `shard_ids`.
+HELD = [0, 1, 4, 9]
+
+
+class _ShardStore:
+    def __init__(self, ev):
+        self.ev = ev
+
+    def find_ec_volume(self, vid):
+        return self.ev
+
+    def find_ec_shard(self, vid, shard_id):
+        return self.ev.find_shard(shard_id)
+
+
+def _holder(tmp_path):
+    base, ev = _make_ec_volume(tmp_path)
+    for shard in HELD:
+        ev.add_shard(EcVolumeShard(str(tmp_path), "", 1, shard))
+    return base, ev, _Host(store=_ShardStore(ev))
+
+
+def _answer(handler, req: dict, limit_s: float = 30) -> list:
+    async def body():
+        return [msg async for msg in handler(req)]
+
+    return asyncio.run(asyncio.wait_for(body(), limit_s))
+
+
+@pytest.mark.parametrize("case", ["held", "not_held", "past_the_end", "tombstoned", "tombstoned_not_held"])
+def test_a_request_without_shard_ids_is_answered_as_the_parent_answers_it(tmp_path, case):
+    base, ev, host = _holder(tmp_path)
+    shard_bytes = os.path.getsize(base + to_ext(1))
+    req = {"volume_id": 1, "shard_id": 2 if "not_held" in case else 1, "offset": 4096,
+           "size": shard_bytes if case == "past_the_end" else 3000, "file_key": 7}
+    if "tombstoned" in case:
+        ev.delete_needle_from_ecx(7)
+    before = scrape()
+    got = _answer(lambda r: host._grpc_ec_shard_read(r, None), req)
+    after = scrape()
+    assert got == _answer(lambda r: _parents_shard_read(host, r), req)
+    assert all(set(msg) <= {"data", "error", "is_deleted"} for msg in got)  # no tag where none was asked for
+    assert [set(msg) for msg in got] == {
+        "held": [{"data"}], "not_held": [{"error"}], "past_the_end": [{"data"}],
+        "tombstoned": [{"is_deleted"}], "tombstoned_not_held": [{"error"}]}[case]
+    want = {"held": 3000, "past_the_end": shard_bytes - 4096}.get(case, 0)
+    assert moved(before, after, SERVED_BYTES) == sum(len(msg.get("data", b"")) for msg in got) == want
+    assert moved(before, after, SERVED, **SHARD_READ) == 1
+    ev.close()
+
+
+def test_a_grouped_request_is_answered_shard_by_shard_on_one_stream(tmp_path):
+    base, ev, host = _holder(tmp_path)
+    req = {"volume_id": 1, "shard_id": 1, "shard_ids": [1, 2, 9], "offset": SPAN, "size": SPAN, "file_key": 7}
+    before = scrape()
+    got = _answer(lambda r: host._grpc_ec_shard_read(r, None), req)
+    after = scrape()
+
+    def span(shard):
+        with open(base + to_ext(shard), "rb") as f:
+            f.seek(SPAN)
+            return f.read(SPAN)
+
+    # shard 2 is not here: an error of its own, and the stream goes on
+    assert got == [{"shard_id": 1, "data": span(1)},
+                   {"shard_id": 2, "error": "ec shard 1.2 not found"},
+                   {"shard_id": 9, "data": span(9)}]
+    assert moved(before, after, SERVED_BYTES) == 2 * SPAN
+    assert moved(before, after, SERVED, **SHARD_READ) == 1  # one observation a stream
+    ev.close()
+
+
+@pytest.mark.parametrize("shape", ["grouped", "single"])
+def test_a_tombstone_ends_a_grouped_stream_as_it_ends_a_single_one(tmp_path, monkeypatch, shape):
+    base, ev, host = _holder(tmp_path)
+    ev.delete_needle_from_ecx(7)
+    shards = [1, 4, 9] if shape == "grouped" else [1]
+    req = {"volume_id": 1, "shard_id": shards[0], "offset": 0, "size": SPAN, "file_key": 7}
+    if shape == "grouped":
+        req["shard_ids"] = shards
+    assert _answer(lambda r: host._grpc_ec_shard_read(r, None), req) == [{"is_deleted": True}]
+    # and the asking side takes it as the holder's last word on every shard
+    # of the stream: none is asked for again, each is counted `failed`
+    url = "127.0.0.1:7"
+    wire = _Routed({grpc_address(url): lambda r: host._grpc_ec_shard_read(r, None)})
+    monkeypatch.setattr(volume_ec, "Stub", wire)
+    ev.shard_locations.update({s: [url] for s in shards})
+    asker = _Host()
+    before = scrape()
+    if shape == "grouped":
+        got = asyncio.run(asyncio.wait_for(
+            asker._read_remote_survivor_group(ev, url, shards, 0, SPAN, 7, None), 30))
+    else:
+        got = {1: asyncio.run(asyncio.wait_for(
+            asker._read_remote_survivor(ev, 1, 0, SPAN, 7, None), 30))}
+    after = scrape()
+    assert got == dict.fromkeys(shards)
+    assert wire.sent == [req]
+    assert moved(before, after, READS, outcome="failed") == moved(before, after, READS) == len(shards)
+    assert moved(before, after, STREAMS, shape=shape) == moved(before, after, STREAMS) == 1
+    assert moved(before, after, READ_BYTES) == 0
     ev.close()

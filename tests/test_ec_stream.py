@@ -276,7 +276,7 @@ def test_streamed_rebuild_roundtrip(tmp_path):
 
 
 _KILL_CHILD = """
-import sys, time
+import os, sys, time
 sys.path.insert(0, {repo!r})
 import numpy as np
 from {module} import {cls}
@@ -284,7 +284,9 @@ from seaweedfs_tpu.storage.erasure_coding import write_ec_files
 
 class SlowCodec({cls}):
     def {dispatch}(self, data):
-        print("CHUNK", flush=True)
+        # one write a marker: the pool's two workers dispatch at once, and
+        # print() sends the word and its newline apart
+        os.write(1, b"CHUNK\\n")
         time.sleep(0.4)  # hold the stream open so the parent kills mid-run
         return super().{dispatch}(data)
 

@@ -400,11 +400,13 @@ PROXIED_METRICS = {
     "warm-rs10.4.degraded-get-c16": ["http.proxied_share"],
 }
 # ISSUE 33's: the spread cell reads the degraded cell's metrics too (its name
-# is appended to their lists), and four of its own come last in BENCHMARK.json
+# is appended to their lists), and four of its own come last in BENCHMARK.json,
+# then ISSUE 34's one: the streams those survivors rode
 SPREAD_CELL = "warm-rs10.4-spread4.server-lost-get-c16"
 SPREAD_METRICS = [
     "ec_read.remote_read_ms", "ec_read.remote_survivors_per_reconstruct",
     "ec_read.remote_kb_per_get", "peers.cpu_cores",
+    "ec_read.remote_streams_per_reconstruct",
 ]
 ALL_NEW_METRICS = [
     (cell, name)
