@@ -35,6 +35,10 @@ from ..util.metrics import (
     EC_ENCODE_BATCH_FALLBACKS,
     EC_ENCODE_BYTES,
     EC_GENERATE_SECONDS,
+    EC_NEEDLE_READS,
+    EC_READ_INTERVALS,
+    EC_READ_STAGE_SECONDS,
+    EC_RECONSTRUCT_SURVIVOR_BYTES,
     EC_RECONSTRUCTIONS,
     EC_REMOTE_ATTEMPTS,
     EC_REMOTE_SHARD_READ_BYTES,
@@ -48,6 +52,8 @@ from ..util.metrics import (
 )
 from ..storage.erasure_coding import (
     DATA_SHARDS_COUNT,
+    EC_LARGE_BLOCK_SIZE,
+    EC_SMALL_BLOCK_SIZE,
     TOTAL_SHARDS_COUNT,
     rebuild_ec_files,
     rebuild_ec_files_multi,
@@ -122,6 +128,30 @@ _ST_PREAD = trace.stage("ec.read.pread")
 _ST_EXECUTOR_WAIT = _read_stage("executor_wait", annotate=False)
 _ST_DECODE = _read_stage("decode", annotate=False)
 _ST_CACHE_PUT = _read_stage("cache_put")
+# a needle read whole, outside any reconstruct: each interval counted once
+# by what served it (_INTERVAL[source]), the needle once by kind, and the
+# two pieces of work the loop does itself: the synchronous pread of an
+# interval on a local shard (up to a block: 1 MiB of a chunk needle) and
+# the assembly (join, parse, CRC over the whole record). Both are annotated
+# leaves; under a sampled request each is a child span
+INTERVAL_SOURCES = ("local", "cold_tier", "remote", "reconstructed", "cache")
+_INTERVAL = {
+    source: EC_READ_INTERVALS.child(source=source) for source in INTERVAL_SOURCES
+}
+_NEEDLE_HEALTHY = EC_NEEDLE_READS.child(kind="healthy")
+_NEEDLE_DEGRADED = EC_NEEDLE_READS.child(kind="degraded")
+_SURVIVOR_BYTES_LOCAL = EC_RECONSTRUCT_SURVIVOR_BYTES.child(origin="local")
+_SURVIVOR_BYTES_REMOTE = EC_RECONSTRUCT_SURVIVOR_BYTES.child(origin="remote")
+_ST_LOCAL_INTERVAL = trace.stage(
+    "ec.read.local_interval",
+    EC_READ_STAGE_SECONDS.child(stage="local_interval"),
+    label="local_interval",
+)
+_ST_ASSEMBLE = trace.stage(
+    "ec.read.assemble",
+    EC_READ_STAGE_SECONDS.child(stage="assemble"),
+    label="assemble",
+)
 
 
 def _count_remote_survivor(t0: float, data: Optional[bytes], size: int) -> None:
@@ -1079,11 +1109,20 @@ class EcHandlers:
         size: int,
         file_key: int,
         deadline: Optional[float] = None,
+        recovered: Optional[list] = None,
     ) -> Optional[bytes]:
+        """One interval of a needle, counted by what served it; a shard id
+        is appended to `recovered` where the bytes came out of
+        `_recover_one_interval` (a reconstruct or its cache)."""
         shard = ev.find_shard(shard_id)
         if shard is not None:
             try:
-                return shard.read_at(size, offset)
+                with _ST_LOCAL_INTERVAL() as st:
+                    st.tag("shard", shard_id)
+                    st.tag("bytes", size)
+                    data = shard.read_at(size, offset)
+                _INTERVAL["local"].inc()
+                return data
             except OSError:
                 # offload race: the shard moved to the remote tier between
                 # find_shard and the pread (fd closed) — fall through to
@@ -1095,6 +1134,7 @@ class EcHandlers:
         # readahead span, then page-cache-priced hits)
         data = await self._read_cold_interval(ev, shard_id, offset, size)
         if data is not None:
+            _INTERVAL["cold_tier"].inc()
             return data
         if deadline is None:
             deadline = deadline_after(EC_READ_DEADLINE_SECONDS)
@@ -1113,6 +1153,8 @@ class EcHandlers:
             )
             if data is not None:
                 EC_REMOTE_ATTEMPTS.inc(outcome="no_holder")
+                if recovered is not None:
+                    recovered.append(shard_id)
                 return data
             # short of survivors as well: the table is up to a TTL old, and
             # a holder of this shard or of a survivor may have come back
@@ -1143,12 +1185,16 @@ class EcHandlers:
                 outcome="failed" if data is None else "served"
             )
             if data is not None:
+                _INTERVAL["remote"].inc()
                 return data
         # degraded: reconstruct from any DATA_SHARDS_COUNT other shards
         # (ref store_ec.go:319-373)
-        return await self._recover_one_interval(
+        data = await self._recover_one_interval(
             ev, shard_id, offset, size, file_key, deadline
         )
+        if data is not None and recovered is not None:
+            recovered.append(shard_id)
+        return data
 
     def codec_for(self, data_shards: int, parity_shards: int):
         """Geometry-specific codec on the configured backend, cached per
@@ -1362,6 +1408,7 @@ class EcHandlers:
         hit = cache.get(ev.volume_id, missing_shard, offset, size)
         if hit is not None:
             EC_RECONSTRUCTIONS.inc(kind="cache_hit")
+            _INTERVAL["cache"].inc()
             EC_DEGRADED_READ_SECONDS.observe(
                 time.perf_counter() - t_start, result="cache_hit"
             )
@@ -1379,25 +1426,33 @@ class EcHandlers:
             holders,
             key=lambda i: not (ev.remote_shard(i) is not None or holders[i]),
         )
-        # local survivors are page-cache preads — take them all (spares are
-        # free); a remote survivor costs its span's bytes over gRPC and its
-        # holder's CPU, and the gather waits for the slowest of those asked:
-        # ask as many as the decode needs, none to spare, widen to the rest
-        # only on a shortfall, and read ahead only as far as EC_REMOTE_SPAN
+        # local survivors are page-cache preads — all of them are read, the
+        # spares beyond data_shards with them (12 spans where 10 are used
+        # with one disk lost): ec_reconstruct_survivor_bytes_total{origin=
+        # "local"} over the cold reconstructs says what that reads. A remote
+        # survivor costs its span's bytes over gRPC and its holder's CPU,
+        # and the gather waits for the slowest of those asked: ask as many
+        # as the decode needs, none to spare, widen to the rest only on a
+        # shortfall, and read ahead only as far as EC_REMOTE_SPAN
         needed = max(0, ev.data_shards - len(local))
         span_start, span_size = cache.span_for(
             offset, size, ev.shard_size() or None,
             EC_REMOTE_SPAN if needed else EC_DEGRADED_SPAN,
         )
         bufs: list[Optional[np.ndarray]] = [None] * total
+        read = [0, 0]  # survivor bytes read here, and fetched from elsewhere
 
-        def keep(shard_id: int, b: Optional[bytes]) -> None:
-            if b is not None and len(b) == span_size:
-                bufs[shard_id] = np.frombuffer(b, dtype=np.uint8)
+        def keep(shard_id: int, b: Optional[bytes], origin: int = 1) -> None:
+            if b is not None:
+                read[origin] += len(b)
+                if len(b) == span_size:
+                    bufs[shard_id] = np.frombuffer(b, dtype=np.uint8)
 
         async def fetch(shard_id: int) -> None:
             shard = ev.find_shard(shard_id)
+            origin = 1
             if shard is not None:
+                origin = 0
                 with _ST_PREAD():
                     b = shard.read_at(span_size, span_start)
             elif ev.remote_shard(shard_id) is not None:
@@ -1410,7 +1465,7 @@ class EcHandlers:
                 b = await self._read_remote_survivor(
                     ev, shard_id, span_start, span_size, file_key, deadline
                 )
-            keep(shard_id, b)
+            keep(shard_id, b, origin)
 
         async def fetch_group(url: str, shard_ids: list[int]) -> None:
             got = await self._read_remote_survivor_group(
@@ -1437,6 +1492,10 @@ class EcHandlers:
                 rest = [i for i in remote if i not in first]
                 if rest:
                     await asyncio.gather(*(fetch(i) for i in rest))
+        if read[0]:
+            _SURVIVOR_BYTES_LOCAL.inc(read[0])
+        if read[1]:
+            _SURVIVOR_BYTES_REMOTE.inc(read[1])
         present = [i for i in range(total) if bufs[i] is not None]
         if len(present) < ev.data_shards:
             return None
@@ -1461,6 +1520,7 @@ class EcHandlers:
             span = np.ascontiguousarray(out).tobytes()
             cache.put(ev.volume_id, missing_shard, span_start, span)
         EC_RECONSTRUCTIONS.inc(kind="cold")
+        _INTERVAL["reconstructed"].inc()
         EC_DEGRADED_READ_SECONDS.observe(
             time.perf_counter() - t_start, result="cold"
         )
@@ -1488,19 +1548,24 @@ class EcHandlers:
         intervals = ev.intervals_for(offset_units, size)
         deadline = deadline_after(EC_READ_DEADLINE_SECONDS)
         chunks = []
+        recovered: list[int] = []
         for iv in intervals:
             shard_id, shard_offset = iv.to_shard_id_and_offset(
-                1024 * 1024 * 1024, 1024 * 1024
+                EC_LARGE_BLOCK_SIZE, EC_SMALL_BLOCK_SIZE
             )
             data = await self._read_one_ec_interval(
-                ev, shard_id, shard_offset, iv.size, key, deadline
+                ev, shard_id, shard_offset, iv.size, key, deadline, recovered
             )
             if data is None or len(data) != iv.size:
                 return None
             chunks.append(data)
-        blob = b"".join(chunks)
-        n = Needle()
-        n.read_bytes(blob, to_actual_offset(offset_units), size, ev.version)
+        with _ST_ASSEMBLE() as st:
+            blob = b"".join(chunks)
+            st.tag("intervals", len(chunks))
+            st.tag("bytes", len(blob))
+            n = Needle()
+            n.read_bytes(blob, to_actual_offset(offset_units), size, ev.version)
+        (_NEEDLE_DEGRADED if recovered else _NEEDLE_HEALTHY).inc()
         return n
 
     async def delete_ec_needle(self, ev: EcVolume, key: int) -> int:

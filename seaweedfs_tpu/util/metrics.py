@@ -609,6 +609,37 @@ EC_SHARD_COPY_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_ec_shard_copy_seconds_total",
     "wall seconds in VolumeEcShardsCopy handlers",
 )
+# a needle read of a mounted EC volume, healthy or degraded, by what it is
+# made of: a needle is one or more intervals (five for a 4 MiB chunk needle
+# over 1 MiB blocks), each served by exactly one source; what the two stages
+# outside a reconstruct take (a healthy interval's pread, the assembly)
+EC_READ_INTERVALS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_read_intervals_total",
+    "EC needle intervals read, once an interval by what served it (source: "
+    "local = a shard file here; cold_tier = a shard this server offloaded; "
+    "remote = a listed holder over VolumeEcShardRead; reconstructed = a "
+    "cold reconstruct from survivors; cache = the degraded-read span cache)",
+)
+EC_NEEDLE_READS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_needle_reads_total",
+    "EC needles read whole (every interval in, parsed, CRC checked), by kind "
+    "(degraded = at least one interval came from a reconstruct or the "
+    "degraded-read cache; healthy = none did)",
+)
+EC_READ_STAGE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_read_stage_seconds_total",
+    "EC needle read wall seconds outside any reconstruct, by stage "
+    "(local_interval = the synchronous pread of an interval on a local "
+    "shard: divide by ec_read_intervals_total{source=\"local\"}; assemble = "
+    "join + parse + CRC of the needle: divide by ec_needle_reads_total)",
+)
+EC_RECONSTRUCT_SURVIVOR_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total",
+    "bytes of survivor spans read for cold reconstructs, spares included, by "
+    "origin (local = a shard file here; remote = another server or the cold "
+    "tier): over ec_reconstructions_total{kind=\"cold\"} it is what one "
+    "reconstruct reads where data_shards spans are used",
+)
 EC_REBUILD_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_ec_rebuild_stage_seconds",
     "rebuild_ec_files per-stage wall seconds, by stage (read/decode/write; "

@@ -697,6 +697,11 @@ class _StageCM:
         self._t0 = _perf()
         return self
 
+    def tag(self, key: str, value) -> None:
+        """A tag on the stage's child span, where the request is sampled."""
+        if self._span is not None:
+            self._span.tag(key, value)
+
     def __exit__(self, et, ev, tb):
         dt = _perf() - self._t0
         if self._event is not None:

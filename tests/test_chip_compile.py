@@ -99,6 +99,12 @@ _GF_CASES = [
     ("encode-10.4-small-block", lambda: _parity(10, 4), (1 << 20) // 4),
     ("decode-one-loss", lambda: _decode_rows((3,)), ROW_WORDS),
     ("decode-four-loss", lambda: _decode_rows((0, 5, 11, 13)), ROW_WORDS),
+    # a chunk needle's lost interval (ISSUE 35): a span of 128 KiB to 1 MiB,
+    # padded to the kernel's 256 KiB granule, is one of four widths a row
+    *[
+        (f"decode-one-loss-{kib}k", lambda: _decode_rows((3,)), (kib << 10) // 4)
+        for kib in (256, 512, 768, 1024)
+    ],
 ]
 
 

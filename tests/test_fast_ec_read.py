@@ -258,7 +258,7 @@ def test_an_exception_inside_the_read_gives_the_aiohttp_tiers_answer(live, monke
     is replayed, and the aiohttp tier's status is what the client gets."""
     calls = []
 
-    async def broken(ev, shard_id, offset, size, key, deadline=None):
+    async def broken(ev, shard_id, offset, size, key, deadline=None, recovered=None):
         calls.append(shard_id)
         raise OSError("the shard's file is gone")
 

@@ -408,6 +408,16 @@ SPREAD_METRICS = [
     "ec_read.remote_kb_per_get", "peers.cpu_cores",
     "ec_read.remote_streams_per_reconstruct",
 ]
+# ISSUE 35's: the chunk cell reads the degraded cell's metrics too (its name
+# is appended to their lists, after the spread cell's), and seven of its own
+# come last in BENCHMARK.json (tests/test_ec_chunk_read.py reads them)
+CHUNK_CELL = "warm-rs10.4-filer4m.degraded-chunk-get-c16"
+CHUNK_METRICS = [
+    "http.body_mb_per_s", "ec_read.intervals_per_get",
+    "ec_read.degraded_get_share", "ec_read.local_interval_ms",
+    "ec_read.assemble_ms", "ec_read.survivor_mb_per_reconstruct",
+    "rs_decode_roofline",
+]
 ALL_NEW_METRICS = [
     (cell, name)
     for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS, PROXIED_METRICS)
@@ -532,7 +542,7 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
     before, after, _wrote, _got = degraded_get
     spec = common.load("layer_metrics", name + ".json")
     entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == name)
-    also = [BATCH_CELL] if cell == "warm-rs10.4.ec-encode" else [SPREAD_CELL]
+    also = [BATCH_CELL] if cell == "warm-rs10.4.ec-encode" else [SPREAD_CELL, CHUNK_CELL]
     assert entry["workloads"] == [cell] + also
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == spec[key], key
@@ -573,11 +583,16 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     names = [e["name"] for e in common.benchmark_json()["per_layer"]]
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
-    new += SPREAD_METRICS
+    new += SPREAD_METRICS + CHUNK_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
         assert per_layer[name]["workloads"] == [SPREAD_CELL]
+        spec = common.load("layer_metrics", name + ".json")
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert per_layer[name][key] == spec[key], (name, key)
+    for name in CHUNK_METRICS:  # read what the chunk deployment adds to the program
+        assert per_layer[name]["workloads"] == [CHUNK_CELL]
         spec = common.load("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
