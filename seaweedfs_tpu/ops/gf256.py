@@ -279,6 +279,17 @@ def count_rs_dispatch(
     padded.inc(rows * (padded_width - width))
 
 
+def row_granule(
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+    force_pallas: bool | None = None,
+    interpret: bool = False,
+) -> int:
+    """Bytes gf_matmul_bytes pads a row to before the upload: the Pallas
+    kernel's block (256 KiB), or one packed word off the chip."""
+    use_pallas = force_pallas if force_pallas is not None else on_tpu()
+    return block_rows * LANE * 4 if use_pallas or interpret else 4
+
+
 def gf_matmul_bytes(
     matrix: np.ndarray,
     data,
@@ -286,6 +297,7 @@ def gf_matmul_bytes(
     force_pallas: bool | None = None,
     interpret: bool = False,
     op: str = "apply",
+    width: int | None = None,
 ):
     """GF(2^8) matmul over flat byte rows: uint8[C, N] -> uint8[R, N].
 
@@ -297,14 +309,24 @@ def gf_matmul_bytes(
     compiled shapes instead of each compiling its own device-side pad and
     slice.
 
+    `width`: the caller's bytes a row where `data`'s rows may be wider,
+    because the caller filled the rows of an array of its own. Where that
+    array is a whole number of row_granule() wide it is uploaded as it is
+    (packing copies nothing) and the columns past `width` may hold anything:
+    the matmul is column by column, and they are cut off on return and
+    counted as padding, as zero fill is. Rows of any other width are cut to
+    `width` and padded like any.
+
     `op` (encode/decode/apply) labels the call's stages, dispatch and bytes
     on /metrics (RS_STAGE, count_rs_dispatch).
     """
     matrix = np.asarray(matrix, dtype=np.uint8)
     assert data.shape[0] == matrix.shape[1], (data.shape, matrix.shape)
-    n = data.shape[1]
+    n = data.shape[1] if width is None else width
     use_pallas = force_pallas if force_pallas is not None else on_tpu()
-    granule = block_rows * LANE * 4 if use_pallas or interpret else 4
+    granule = row_granule(block_rows, force_pallas, interpret)
+    if width is not None and data.shape[1] % granule:
+        data = data[:, :width]
     stages = RS_STAGE[op]
     with stages["pack"]():
         packed = pack_bytes_host(

@@ -21,7 +21,43 @@ from ..storage.erasure_coding.galois import (
     reconstruction_matrix,
 )
 from ..util.device import on_tpu
-from .gf256 import RS_STAGE, count_rs_dispatch, gf_matmul_bytes
+from .gf256 import RS_STAGE, count_rs_dispatch, gf_matmul_bytes, row_granule
+
+
+def rows_of_one_array(rows: Sequence) -> Optional[np.ndarray]:
+    """uint8[len(rows), stride], a view and no copy, where `rows` are the
+    equally wide starts of consecutive rows of ONE C-contiguous uint8 array
+    (a caller read its survivors into an array of its own: `a[j, :n]`, or
+    `a[j]` whole); None for rows that are anything else (separate arrays,
+    views of a bytes object, a gap where a shard is missing). Seen from the
+    rows' owner, addresses and widths, so no caller has to say it."""
+    first = rows[0]
+    owner = getattr(first, "base", None)
+    if (
+        not isinstance(owner, np.ndarray)
+        or owner.dtype != np.uint8
+        or not owner.flags.c_contiguous
+    ):
+        return None
+    at = []
+    for r in rows:
+        if (
+            getattr(r, "base", None) is not owner
+            or r.dtype != np.uint8
+            or r.shape != first.shape
+            or r.strides != (1,)
+        ):
+            return None
+        at.append(r.__array_interface__["data"][0])
+    n = first.shape[0]
+    stride = at[1] - at[0] if len(at) > 1 else n
+    if stride < n or any(a != at[0] + j * stride for j, a in enumerate(at)):
+        return None
+    start = at[0] - owner.__array_interface__["data"][0]
+    end = start + len(at) * stride
+    if end > owner.size:
+        return None  # the last row's stride would run past the owner's end
+    return owner.reshape(-1)[start:end].reshape(len(at), stride)
 
 
 class TpuRSCodec:
@@ -115,13 +151,23 @@ class TpuRSCodec:
             )
         return standin.encode(np.ascontiguousarray(data, dtype=np.uint8))
 
-    def _apply(self, matrix: np.ndarray, data, op: str) -> np.ndarray:
+    def row_granule(self) -> int:
+        """Bytes a row is padded to before its upload (CpuRSCodec.row_granule
+        has the contract): rows a caller made that wide are not copied."""
+        return row_granule(
+            force_pallas=self._force_pallas, interpret=self._interpret
+        )
+
+    def _apply(
+        self, matrix: np.ndarray, data, op: str, width: Optional[int] = None
+    ) -> np.ndarray:
         return gf_matmul_bytes(
             matrix,
             data,
             force_pallas=self._force_pallas,
             interpret=self._interpret,
             op=op,
+            width=width,
         )
 
     def encode(self, data) -> np.ndarray:
@@ -194,7 +240,10 @@ class TpuRSCodec:
         the survivor inverse, parity rows pre-multiplied host-side), cached
         per (survivor set, wanted rows) in the shared DECODE_ROWS_CACHE so
         steady rebuild/degraded-read traffic reuses both the matrix AND its
-        compiled kernel (jit caches per matrix shape)."""
+        compiled kernel (jit caches per matrix shape). Survivors that are
+        the consecutive rows of one array (rows_of_one_array) are uploaded as
+        that array: no stack, and no pad either where its rows are
+        row_granule() wide, whatever lies past the survivors' own width."""
         shards = list(shards)
         if len(shards) != self.total_shards:
             raise ValueError(f"expected {self.total_shards} shard slots")
@@ -208,11 +257,15 @@ class TpuRSCodec:
         if need:
             survivors = present[: self.data_shards]
             rows = DECODE_ROWS_CACHE.rows_for(self.matrix, survivors, need)
-            with RS_STAGE["decode"]["stack"]():
-                sub = np.stack(
-                    [np.asarray(shards[i], dtype=np.uint8) for i in survivors]
-                )
-            recovered = self._apply(rows, sub, "decode")
+            given = [shards[i] for i in survivors]
+            width = len(given[0])
+            sub = rows_of_one_array(given)
+            if sub is None:
+                with RS_STAGE["decode"]["stack"]():
+                    sub = np.stack(
+                        [np.asarray(s, dtype=np.uint8) for s in given]
+                    )
+            recovered = self._apply(rows, sub, "decode", width)
             if out is not None and len(need) == len(wanted):
                 out[:] = recovered  # device result lands in the recycled
                 recovered = out  # caller buffer (interface parity with CPU)

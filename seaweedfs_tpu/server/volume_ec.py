@@ -15,10 +15,14 @@ LookupEcVolume, cached with a TTL refresh.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import os
 import random
+import threading
 import time
 from typing import Optional
+
+import numpy as np
 
 from ..pb import grpc_address
 from ..pb.rpc import Stub
@@ -38,6 +42,7 @@ from ..util.metrics import (
     EC_NEEDLE_READS,
     EC_READ_INTERVALS,
     EC_READ_STAGE_SECONDS,
+    EC_RECONSTRUCT_LOCAL_READS,
     EC_RECONSTRUCT_SURVIVOR_BYTES,
     EC_RECONSTRUCTIONS,
     EC_REMOTE_ATTEMPTS,
@@ -98,10 +103,12 @@ def _read_stage(label: str, annotate: bool = True):
 # the stages of a cold degraded read (_recover_one_interval), bound once;
 # their count is ec_reconstructions_total{kind="cold"}. Counters only
 # where an await or a thread hand-off lets other requests run inside:
-# survivor_read is the wall of the gathers (its leaf, each synchronous
-# shard pread, is the `ec.read.pread` event), executor_wait runs from
+# survivor_read is the wall of the gathers of remote survivors on the loop
+# (none where every survivor is local) plus the worker's wall filling the
+# decode's input rows (its leaf, each shard read into a row, is the
+# `ec.read.pread` event, on the worker's thread), executor_wait runs from
 # run_in_executor to the worker's first line, decode is the worker's wall
-# around reconstruct_rows (its leaves are the codec's `rs.*` events).
+# around reconstruct_rows alone (its leaves are the codec's `rs.*` events).
 # Before a reconstruct is tried at all, remote_attempts: the TTL'd
 # location refresh (one LookupEcVolume a SHARD_LOCATION_TTL, a dict look-up
 # otherwise) and, only where the table names a holder of the shard, the
@@ -142,6 +149,12 @@ _NEEDLE_HEALTHY = EC_NEEDLE_READS.child(kind="healthy")
 _NEEDLE_DEGRADED = EC_NEEDLE_READS.child(kind="degraded")
 _SURVIVOR_BYTES_LOCAL = EC_RECONSTRUCT_SURVIVOR_BYTES.child(origin="local")
 _SURVIVOR_BYTES_REMOTE = EC_RECONSTRUCT_SURVIVOR_BYTES.child(origin="remote")
+# a local survivor span read for a cold reconstruct, by the thread the read
+# ran on, as the read itself sees it: one that runs an event loop, or not
+_LOCAL_READS = {
+    where: EC_RECONSTRUCT_LOCAL_READS.child(where=where)
+    for where in ("worker", "loop")
+}
 _ST_LOCAL_INTERVAL = trace.stage(
     "ec.read.local_interval",
     EC_READ_STAGE_SECONDS.child(stage="local_interval"),
@@ -194,6 +207,27 @@ EC_DEGRADED_SPAN = 128 * 1024
 # (six times a span of a 4/4/3/3 spread), where a local survivor's is a
 # page-cache pread; the reference reads the interval alone (store_ec.go:319)
 EC_REMOTE_SPAN = 16 * 1024
+
+
+_WORKER = threading.local()
+
+
+def _survivor_rows(k: int, width: int) -> np.ndarray:
+    """uint8[k, width], C-contiguous, for the calling worker thread to read
+    a reconstruct's survivors into and hand to the codec, which uploads it
+    as it is. The thread's own array, used again by its next reconstruct (a
+    decode has fetched its answer before it returns, so nothing reads the
+    rows after that; memory the runtime has seen before uploads faster than
+    fresh pages): what it held stays in it, so a row is valid as far as it
+    was filled and no further. Rows wider than a small block (an interval
+    inside a large block) get an array of their own, so that a thread keeps
+    k small blocks at most."""
+    if width > EC_SMALL_BLOCK_SIZE:
+        return np.empty((k, width), dtype=np.uint8)
+    buf = getattr(_WORKER, "rows", None)
+    if buf is None or buf.size < k * width:
+        buf = _WORKER.rows = np.empty(k * width, dtype=np.uint8)
+    return buf[: k * width].reshape(k, width)
 
 
 class DegradedIntervalCache:
@@ -1391,18 +1425,21 @@ class EcHandlers:
         self, ev: EcVolume, missing_shard: int, offset: int, size: int,
         file_key: int, deadline: Optional[float] = None,
     ) -> Optional[bytes]:
-        """Reconstruct [offset, offset+size) of a shard nobody can serve:
-        all survivor intervals are fetched CONCURRENTLY (local pread +
-        remote streams in one gather — wall clock is the slowest survivor,
-        not the sum; the survivors one holder lists share one stream, a
-        holder's call costing more than its bytes), decoded
+        """Reconstruct [offset, offset+size) of a shard nobody can serve
+        from the data_shards survivors with the lowest ids that can be had.
+        Those on other servers or the cold tier are fetched CONCURRENTLY on
+        the loop (one gather — wall clock is the slowest survivor, not the
+        sum; the survivors one holder lists share one stream, a holder's
+        call costing more than its bytes). Those on this server's disks are
+        read by the worker thread that decodes, each span straight into its
+        row of the array the codec uploads: no local read runs on the loop,
+        none is a task, no spare is read unless a read fails, and no copy
+        stands between the shard file and the upload. The decode is
         missing-row-only through the shared decode-matrix LRU, and the
         whole readahead-widened span is kept in the degraded-read cache so
         the next needle on this dead shard skips the fetch+decode entirely
         (ref store_ec.go:319-373 fetches, then reconstructs all rows, every
         time)."""
-        import numpy as np
-
         t_start = time.perf_counter()
         cache = self._ec_degraded_cache()
         hit = cache.get(ev.volume_id, missing_shard, offset, size)
@@ -1413,7 +1450,7 @@ class EcHandlers:
                 time.perf_counter() - t_start, result="cache_hit"
             )
             return hit
-        total = ev.total_shards
+        total, k = ev.total_shards, ev.data_shards
         candidates = [i for i in range(total) if i != missing_shard]
         local = [i for i in candidates if ev.find_shard(i) is not None]
         # survivors with somewhere to come from (the cold tier, a holder
@@ -1426,36 +1463,29 @@ class EcHandlers:
             holders,
             key=lambda i: not (ev.remote_shard(i) is not None or holders[i]),
         )
-        # local survivors are page-cache preads — all of them are read, the
-        # spares beyond data_shards with them (12 spans where 10 are used
-        # with one disk lost): ec_reconstruct_survivor_bytes_total{origin=
-        # "local"} over the cold reconstructs says what that reads. A remote
-        # survivor costs its span's bytes over gRPC and its holder's CPU,
-        # and the gather waits for the slowest of those asked: ask as many
-        # as the decode needs, none to spare, widen to the rest only on a
-        # shortfall, and read ahead only as far as EC_REMOTE_SPAN
-        needed = max(0, ev.data_shards - len(local))
+        # local survivors count toward the data_shards first and are read
+        # by the worker below, the lowest ids as far as they are needed
+        # (ten spans with one disk lost, the two spares only where a read
+        # comes short or raises). A remote survivor costs its span's bytes
+        # over gRPC and its holder's CPU, and the gather waits for the
+        # slowest of those asked: ask as many as the decode needs, none to
+        # spare, widen to the rest only on a shortfall, and read ahead only
+        # as far as EC_REMOTE_SPAN
+        needed = max(0, k - len(local))
         span_start, span_size = cache.span_for(
             offset, size, ev.shard_size() or None,
             EC_REMOTE_SPAN if needed else EC_DEGRADED_SPAN,
         )
-        bufs: list[Optional[np.ndarray]] = [None] * total
-        read = [0, 0]  # survivor bytes read here, and fetched from elsewhere
+        fetched: dict[int, bytes] = {}  # whole spans that came from elsewhere
 
-        def keep(shard_id: int, b: Optional[bytes], origin: int = 1) -> None:
+        def keep(shard_id: int, b: Optional[bytes]) -> None:
             if b is not None:
-                read[origin] += len(b)
+                _SURVIVOR_BYTES_REMOTE.inc(len(b))
                 if len(b) == span_size:
-                    bufs[shard_id] = np.frombuffer(b, dtype=np.uint8)
+                    fetched[shard_id] = b
 
         async def fetch(shard_id: int) -> None:
-            shard = ev.find_shard(shard_id)
-            origin = 1
-            if shard is not None:
-                origin = 0
-                with _ST_PREAD():
-                    b = shard.read_at(span_size, span_start)
-            elif ev.remote_shard(shard_id) is not None:
+            if ev.remote_shard(shard_id) is not None:
                 # cold tier: an offloaded survivor feeds reconstruction
                 # through the read-through cache (one ranged remote GET)
                 b = await self._read_cold_interval(
@@ -1465,7 +1495,7 @@ class EcHandlers:
                 b = await self._read_remote_survivor(
                     ev, shard_id, span_start, span_size, file_key, deadline
                 )
-            keep(shard_id, b, origin)
+            keep(shard_id, b)
 
         async def fetch_group(url: str, shard_ids: list[int]) -> None:
             got = await self._read_remote_survivor_group(
@@ -1474,49 +1504,95 @@ class EcHandlers:
             for shard_id, b in got.items():
                 keep(shard_id, b)
 
-        first = remote[:needed]
-        # the survivors to fetch from other servers, by the holder the table
-        # names first for each: two or more of one holder ride one stream
-        by_holder: dict[str, list[int]] = {}
-        for i in first:
-            if holders[i] and ev.remote_shard(i) is None:
-                by_holder.setdefault(holders[i][0], []).append(i)
-        groups = {u: g for u, g in by_holder.items() if len(g) > 1}
-        grouped = {i for g in groups.values() for i in g}
-        with _ST_SURVIVOR_READ():
-            await asyncio.gather(
-                *(fetch(i) for i in local + first if i not in grouped),
-                *(fetch_group(url, g) for url, g in groups.items()),
-            )
-            if sum(1 for b in bufs if b is not None) < ev.data_shards:
-                rest = [i for i in remote if i not in first]
-                if rest:
-                    await asyncio.gather(*(fetch(i) for i in rest))
-        if read[0]:
-            _SURVIVOR_BYTES_LOCAL.inc(read[0])
-        if read[1]:
-            _SURVIVOR_BYTES_REMOTE.inc(read[1])
-        present = [i for i in range(total) if bufs[i] is not None]
-        if len(present) < ev.data_shards:
-            return None
-        keep = present[: ev.data_shards]
-        trimmed: list[Optional[np.ndarray]] = [
-            bufs[i] if i in keep else None for i in range(total)
-        ]
-        codec = self.codec_for(ev.data_shards, ev.parity_shards)
+        first, rest = remote[:needed], remote[needed:]
+        if first:
+            # the survivors to fetch from other servers, by the holder the
+            # table names first for each: two or more of one holder ride
+            # one stream
+            by_holder: dict[str, list[int]] = {}
+            for i in first:
+                if holders[i] and ev.remote_shard(i) is None:
+                    by_holder.setdefault(holders[i][0], []).append(i)
+            groups = {u: g for u, g in by_holder.items() if len(g) > 1}
+            grouped = {i for g in groups.values() for i in g}
+            with _ST_SURVIVOR_READ():
+                await asyncio.gather(
+                    *(fetch(i) for i in first if i not in grouped),
+                    *(fetch_group(url, g) for url, g in groups.items()),
+                )
+        codec = self.codec_for(k, ev.parity_shards)
         loop = asyncio.get_event_loop()
-        t_submit = time.perf_counter()
 
-        def decode() -> list:
+        def fill() -> Optional[list]:
+            """The k survivors with the lowest ids among the local shards
+            and the fetched spans, row by row into the array the decode
+            uploads, as reconstruct_rows takes them: a slot a shard id.
+            None where fewer than k can be had: a local read that raises or
+            comes short leaves its row to the next survivor."""
+            granule = codec.row_granule()
+            rows = _survivor_rows(k, -(-span_size // granule) * granule)
+            shards: list[Optional[np.ndarray]] = [None] * total
+            where = _LOCAL_READS[
+                "worker" if asyncio._get_running_loop() is None else "loop"
+            ]
+            filled = read = 0
+            for shard_id in sorted(fetched.keys() | set(local)):
+                row = rows[filled, :span_size]
+                b = fetched.get(shard_id)
+                if b is not None:
+                    row[:] = np.frombuffer(b, dtype=np.uint8)
+                else:
+                    shard = ev.find_shard(shard_id)
+                    if shard is None:
+                        continue  # unmounted since the plan was made
+                    where.inc()
+                    try:
+                        with _ST_PREAD():
+                            got = shard.read_into(row, span_start)
+                    except OSError:
+                        continue
+                    read += got
+                    if got != span_size:
+                        continue
+                shards[shard_id] = row
+                filled += 1
+                if filled == k:
+                    break
+            if read:
+                _SURVIVOR_BYTES_LOCAL.inc(read)
+            return shards if filled == k else None
+
+        def rebuild(t_submit: float) -> Optional[list]:
             _ST_EXECUTOR_WAIT.since(t_submit)
+            with _ST_SURVIVOR_READ():
+                shards = fill()
+            if shards is None:
+                return None
             with _ST_DECODE():
-                return codec.reconstruct_rows(trimmed, [missing_shard])
+                return codec.reconstruct_rows(shards, [missing_shard])
 
-        rows = await loop.run_in_executor(None, decode)
-        out = rows[0]
+        decoded = None
+        for more in (rest, ()):
+            if len(local) + len(fetched) >= k:
+                # in the request's context, so that under a sampled request
+                # the worker's stages are child spans as the loop's are
+                decoded = await loop.run_in_executor(
+                    None, contextvars.copy_context().run, rebuild,
+                    time.perf_counter(),
+                )
+                if decoded is not None:
+                    break
+            if not more:
+                return None
+            # short of survivors: ask whoever has not been asked yet
+            with _ST_SURVIVOR_READ():
+                await asyncio.gather(*(fetch(i) for i in more))
+        out = decoded[0]
         if out is None:
             return None
         with _ST_CACHE_PUT():
+            # the span alone: what lay past it in the worker's rows was
+            # cut off by the codec
             span = np.ascontiguousarray(out).tobytes()
             cache.put(ev.volume_id, missing_shard, span_start, span)
         EC_RECONSTRUCTIONS.inc(kind="cold")

@@ -33,6 +33,13 @@ class CpuRSCodec:
         self.matrix = build_matrix(data_shards, self.total_shards)
         self.parity_matrix = self.matrix[data_shards:]
 
+    def row_granule(self) -> int:
+        """Bytes a survivor row is rounded up to before this codec computes
+        on it: a caller that reads survivors into one array of its own makes
+        the rows that wide (TpuRSCodec then uploads the array as it is). A
+        host codec takes rows of any width."""
+        return 1
+
     def _mat_apply(self, m: np.ndarray, data: np.ndarray) -> np.ndarray:
         """rows_out[i] = XOR_j MUL[m[i,j]] gathered over data[j]."""
         out = np.zeros((m.shape[0], data.shape[1]), dtype=np.uint8)

@@ -111,6 +111,12 @@ class EcVolumeShard:
     def read_at(self, size: int, offset: int) -> bytes:
         return os.pread(self._f.fileno(), size, offset)
 
+    def read_into(self, buf, offset: int) -> int:
+        """Fill the writable buffer `buf` from `offset` on; the bytes read,
+        fewer than `len(buf)` at the file's end. For a caller that owns
+        the array the bytes are wanted in (a reconstruct's survivor rows)."""
+        return os.preadv(self._f.fileno(), [buf], offset)
+
     def close(self) -> None:
         self._f.close()
 

@@ -545,10 +545,12 @@ EC_ENCODE_BATCH_FALLBACKS = REGISTRY.counter(
     "batched encodes that fell back to one volume at a time, by reason",
 )
 # where a degraded read's wall goes. Of a cold reconstruct: survivor_read
-# (the gathers of survivor fetches), executor_wait (submit -> the worker's
-# first line), decode (the worker's wall around reconstruct_rows),
-# cache_put; divide by ec_reconstructions_total{kind="cold"}. Before any
-# reconstruct, hit or cold: remote_attempts
+# (the gathers of remote survivor fetches on the loop, plus the worker's
+# wall filling the decode's input rows: local survivors read, fetched ones
+# copied in), executor_wait (submit -> the worker's first line), decode (the
+# worker's wall around reconstruct_rows alone), cache_put; divide by
+# ec_reconstructions_total{kind="cold"}. Before any reconstruct, hit or
+# cold: remote_attempts
 EC_DEGRADED_READ_STAGE_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_ec_degraded_read_stage_seconds_total",
     "degraded EC read stage wall seconds, by stage (remote_attempts = the "
@@ -635,10 +637,18 @@ EC_READ_STAGE_SECONDS = REGISTRY.counter(
 )
 EC_RECONSTRUCT_SURVIVOR_BYTES = REGISTRY.counter(
     "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total",
-    "bytes of survivor spans read for cold reconstructs, spares included, by "
-    "origin (local = a shard file here; remote = another server or the cold "
-    "tier): over ec_reconstructions_total{kind=\"cold\"} it is what one "
-    "reconstruct reads where data_shards spans are used",
+    "bytes of survivor spans read for cold reconstructs, by origin (local = "
+    "a shard file here: the spans the decode uses and no spare, unless a "
+    "read came short; remote = another server or the cold tier): over "
+    "ec_reconstructions_total{kind=\"cold\"} it is what one reconstruct "
+    "reads, data_shards spans",
+)
+EC_RECONSTRUCT_LOCAL_READS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_reconstruct_local_reads_total",
+    "survivor spans a cold reconstruct read from shard files here, by where "
+    "the read ran (worker = the executor thread that then decodes, straight "
+    "into the decode's input array; loop = a thread that runs an event "
+    "loop, where a read holds every other request up)",
 )
 EC_REBUILD_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_ec_rebuild_stage_seconds",

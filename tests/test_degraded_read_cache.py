@@ -1,6 +1,8 @@
 """Degraded-read fast path (ISSUE 3): concurrent survivor fetches, the
 reconstructed-interval cache, its .ecj-delete invalidation, and the
-cold-vs-cache-hit split of seaweedfs_tpu_ec_reconstructions_total.
+cold-vs-cache-hit split of seaweedfs_tpu_ec_reconstructions_total; and the
+survivors read into the decode's own input array (ISSUE 36): a rebuilt span
+against tests/ec_oracle.py at every shape a span can have, under every codec.
 
 The harness drives EcHandlers._recover_one_interval directly against a
 real on-disk EC volume; "remote" shard holders are a fault-injection seam
@@ -10,7 +12,11 @@ import asyncio
 import time
 
 import numpy as np
+import pytest
 
+from ec_oracle import oracle_shards
+from seaweedfs_tpu.ops.rs_kernel import TpuRSCodec
+from seaweedfs_tpu.server import volume_ec
 from seaweedfs_tpu.server.volume_ec import (
     DegradedIntervalCache,
     EC_DEGRADED_SPAN,
@@ -219,3 +225,92 @@ def test_interval_cache_finds_a_span_of_the_remote_alignment():
     assert cache.get(1, 3, start + EC_REMOTE_SPAN - 8, 9) is None
     assert cache.get(1, 3, start - EC_REMOTE_SPAN, 8) is None
     assert cache.get(1, 3, 3 * EC_DEGRADED_SPAN, 8) is None
+
+
+# ------------------------------- survivors read into the decode's input array
+CODECS = {
+    "numpy": CpuRSCodec,  # rows of any width
+    "jnp": TpuRSCodec,  # rows of whole packed words: 4 bytes
+    # rows of the Pallas kernel's block, 256 KiB (interpreted here)
+    "pallas": lambda: TpuRSCodec(force_pallas=True, interpret=True),
+}
+# (offset, size, the shard size the volume reports or None for its own, shards
+# mounted here) -> the span the read path rebuilds
+SHAPES = {
+    # two aligned spans: as wide as the kernel's block, nothing to pad
+    "block_wide": (3 * EC_DEGRADED_SPAN - 10, 20, None, 13),
+    # one aligned span: half a block
+    "half_a_block": (3 * EC_DEGRADED_SPAN + 513, 2048, None, 13),
+    # the span's end cut off at the shard's end, at an odd width
+    "clipped": (7 * EC_DEGRADED_SPAN + 5, 64, (1 << 20) - 1001, 13),
+    # no shard here: no shard size, so the interval alone, every survivor fetched
+    "interval_alone": (4099, 1001, None, 0),
+    # four here and six fetched, as on a 4/4/3/3 spread
+    "mixed": (5 * EC_REMOTE_SPAN + 77, 900, None, 4),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("codec", sorted(CODECS))
+def test_a_rebuilt_span_is_the_oracles_whatever_its_width(tmp_path, monkeypatch, codec, shape):
+    """The worker's rows hold whatever the last decode left (here 0xA5) past
+    the span's width, and the codec computes on it: none of that reaches the
+    caller or the span cache."""
+    off, size, shard_size, mounted = SHAPES[shape]
+    dead = 3
+    base, ev = _make_ec_volume(tmp_path)
+    for i in [s for s in range(14) if s != dead][:mounted]:
+        ev.add_shard(EcVolumeShard(str(tmp_path), "", 1, i))
+    if shard_size is not None:
+        monkeypatch.setattr(ev, "shard_size", lambda: shard_size)
+    host = _Host()
+    host.codec = CODECS[codec]()
+
+    async def remote(_ev, shard_id, offset, length, key, deadline=None, sent=None):
+        with open(base + to_ext(shard_id), "rb") as f:
+            f.seek(offset)
+            return f.read(length)
+
+    host._read_remote_shard_interval = remote
+    widths = []
+    inner = volume_ec._survivor_rows
+
+    def stale_rows(k, width):
+        rows = inner(k, width)
+        rows[:] = 0xA5
+        widths.append(width)
+        return rows
+
+    monkeypatch.setattr(volume_ec, "_survivor_rows", stale_rows)
+    got = asyncio.run(asyncio.wait_for(host._recover_one_interval(ev, dead, off, size, 0), 120))
+    want = oracle_shards(base + ".dat")[dead]
+    assert got == want[off : off + size]
+    (key, span), = host._ec_degraded_cache()._spans.items()
+    align = EC_DEGRADED_SPAN if mounted >= 10 else EC_REMOTE_SPAN
+    start, length = DegradedIntervalCache.span_for(off, size, ev.shard_size() or None, align)
+    assert key == (1, dead, start) and span == want[start : start + length]
+    # the rows are as wide as this codec uploads them: the span rounded up
+    granule = host.codec.row_granule()
+    assert widths == [-(-length // granule) * granule]
+    assert (widths[0] > length) == {
+        "numpy": False, "jnp": length % 4 != 0,
+        "pallas": shape != "block_wide"}[codec]
+    ev.close()
+
+
+def test_a_workers_rows_are_its_own_and_used_again():
+    """One array a thread, grown to the widest span it has rebuilt and handed
+    out again; a span wider than a small block gets an array that is not kept."""
+    import threading
+
+    a = volume_ec._survivor_rows(10, 1 << 17)
+    b = volume_ec._survivor_rows(10, 1 << 16)
+    assert a.shape == (10, 1 << 17) and b.shape == (10, 1 << 16)
+    assert a.flags.c_contiguous and b.flags.c_contiguous and np.shares_memory(a, b)
+    wide = volume_ec._survivor_rows(10, (1 << 20) + 4)
+    assert not np.shares_memory(wide, a) and np.shares_memory(volume_ec._survivor_rows(10, 8), a)
+    other = []
+    t = threading.Thread(target=lambda: other.append(volume_ec._survivor_rows(10, 1 << 17)))
+    t.start()
+    t.join(30)
+    assert not np.shares_memory(other[0], a)

@@ -27,6 +27,7 @@ from seaweedfs_tpu.server.master import MasterServer
 from seaweedfs_tpu.server.volume import VolumeServer
 from seaweedfs_tpu.server.volume_ec import EC_DEGRADED_SPAN
 from seaweedfs_tpu.storage.erasure_coding import to_ext
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import EcVolumeShard
 from seaweedfs_tpu.storage.erasure_coding.locate import locate_data
 from seaweedfs_tpu.storage.file_id import format_needle_id_cookie
 from seaweedfs_tpu.storage.needle import Needle, get_actual_size
@@ -47,6 +48,9 @@ NEEDLES = "seaweedfs_tpu_ec_needle_reads_total"
 STAGES = "seaweedfs_tpu_ec_read_stage_seconds_total"
 SURVIVOR_BYTES = "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total"
 COLD = "seaweedfs_tpu_ec_reconstructions_total"
+LOCAL_READS = "seaweedfs_tpu_ec_reconstruct_local_reads_total"
+RS_SECONDS = "seaweedfs_tpu_rs_dispatch_seconds_total"
+GET_CELLS = ["warm-rs10.4.degraded-get-c16", "warm-rs10.4-spread4.server-lost-get-c16", CHUNK_CELL]
 PROXIED = "seaweedfs_tpu_request_proxied_total"
 GRANULE = DEFAULT_BLOCK_ROWS * LANE * 4  # bytes a row the Pallas kernel pads to
 
@@ -136,28 +140,47 @@ def test_locate_data_at_the_real_block_sizes_by_hand(dat, offset, size, want):
 
 
 # ------------------------------------------- the decode's four padded widths
+@pytest.mark.parametrize("rows", ["separate", "one_array", "one_array_upload_wide"])
 @pytest.mark.parametrize("blocks", [1, 2, 3, 4])
-def test_each_padded_decode_width_gives_the_reference_codecs_row(blocks):
+def test_each_padded_decode_width_gives_the_reference_codecs_row(blocks, rows):
     """A lost interval is widened to 128 KiB and a decode's row padded to the
     kernel's 256 KiB: spans of 128 KiB to 1 MiB land on four widths. Each,
-    through the Pallas kernel (interpreted), is the plain codec's lost row."""
+    through the Pallas kernel (interpreted), is the plain codec's lost row,
+    whatever form the survivors come in: arrays of their own (rebuild, scrub:
+    stacked as ever), the rows of one array (not stacked), or the starts of
+    the rows of one array that is already as wide as the upload, with
+    anything at all past the survivors' width (a degraded read: neither
+    stacked nor padded). The bytes counted real and padded are the same."""
     width = blocks * GRANULE - EC_DEGRADED_SPAN  # 128, 384, 640, 896 KiB
     rng = np.random.default_rng(blocks)
     data = rng.integers(0, 256, (10, width), dtype=np.uint8)
     plain = rs_codec.Codec(10, 4)
     full = np.concatenate([data, plain.encode(data)])
     codec = TpuRSCodec(force_pallas=True, interpret=True)
-    shards = [full[i] if i not in (3, 11) else None for i in range(14)]
+    assert codec.row_granule() == GRANULE
+    used = [i for i in range(14) if i not in (3, 11)][:10]
+    shards = [None] * 14
+    if rows == "separate":
+        for i in used:
+            shards[i] = full[i].copy()
+    else:
+        wide = blocks * GRANULE if rows == "one_array_upload_wide" else width
+        one = np.full((10, wide), 0xA5, dtype=np.uint8)
+        for j, i in enumerate(used):
+            one[j, :width] = full[i]
+            shards[i] = one[j, :width]
     before = scrape()
     got = codec.reconstruct_rows(shards, [3])[0]
     after = scrape()
-    assert np.array_equal(got, data[3])
-    survivors = {i: full[i] for i in range(14) if i not in (3, 11)}
+    assert got.shape == (width,) and np.array_equal(got, data[3])
+    survivors = {i: full[i] for i in used}
     assert np.array_equal(got, plain.recover(survivors, [3])[0])
     labels = dict(op="decode", backend="device_emulated")
     family = "seaweedfs_tpu_rs_dispatch_bytes_total"
     assert moved(before, after, family, kind="real", **labels) == 11 * width
     assert moved(before, after, family, kind="padded", **labels) == 11 * EC_DEGRADED_SPAN
+    stacked = moved(before, after, RS_SECONDS, op="decode", stage="stack")
+    assert (stacked > 0) == (rows == "separate")
 
 
 # ------------------------------------------------------------ the live volume
@@ -328,13 +351,16 @@ def test_every_needle_reads_back_byte_for_byte_with_a_shard_lost(live, lost):
     assert moved(before, after, INTERVALS, source="cold_tier") == 0
     assert moved(before, after, NEEDLES, kind="degraded") == needles
     assert moved(before, after, NEEDLES, kind="healthy") == len(live.body) - needles
-    # a cold reconstruct reads the same span of every local survivor, the spares too
+    # a cold reconstruct reads the same span of the ten survivors the decode
+    # uses and of no spare (thirteen or twelve are mounted), each on the worker
+    # that decodes and none on the loop's thread
     cold = moved(before, after, COLD, kind="cold")
     assert cold == moved(before, after, INTERVALS, source="reconstructed") > 0
     read = moved(before, after, SURVIVOR_BYTES, origin="local")
     assert moved(before, after, SURVIVOR_BYTES, origin="remote") == 0
-    survivors = 14 - len(lost)
-    assert read % survivors == 0 and rebuilt[1] <= read // survivors <= cold * MB
+    assert read % 10 == 0 and rebuilt[1] <= read // 10 <= cold * MB
+    assert moved(before, after, LOCAL_READS, where="worker") == 10 * cold
+    assert moved(before, after, LOCAL_READS) == 10 * cold
 
 
 def test_a_rebuilt_interval_is_the_reference_codecs_bytes(live):
@@ -430,6 +456,82 @@ def test_the_new_stages_are_child_spans_of_a_sampled_request(live):
     assert {s["parent"] for s in local + [assemble]} == {root["span"]}
 
 
+def test_no_survivor_is_read_on_the_loops_thread(live, monkeypatch):
+    """Ten `read_into`s a cold reconstruct, each on an executor thread; the
+    loop's thread does the healthy intervals' `read_at`s and nothing else."""
+    key = next(k for k, body in live.body.items() if len(body) == 4 * MB
+               and any(s == 3 for s, _o, _n in live.reference_intervals(k)))
+    seen = {"read_into": [], "read_at": []}
+    for name in seen:
+        def spy(self, *args, _inner=getattr(EcVolumeShard, name), _name=name):
+            seen[_name].append((self.shard_id, threading.get_ident()))
+            return _inner(self, *args)
+
+        monkeypatch.setattr(EcVolumeShard, name, spy)
+
+    async def read():
+        await live.lose([3, 11])
+        try:
+            before = scrape()
+            n = await live.vs.read_ec_needle(live.ev, key)
+            return bytes(n.data), before, scrape()
+        finally:
+            await live.mount([3, 11])
+
+    body, before, after = live.run(read())
+    assert body == live.body[key]
+    loop_thread = live.thread.ident
+    assert [s for s, _t in seen["read_into"]] == [0, 1, 2, 4, 5, 6, 7, 8, 9, 10]
+    assert loop_thread not in {t for _s, t in seen["read_into"]}
+    assert {t for _s, t in seen["read_at"]} == {loop_thread} and len(seen["read_at"]) == 4
+    assert moved(before, after, LOCAL_READS, where="worker") == 10
+    assert moved(before, after, LOCAL_READS, where="loop") == 0
+    # the cell's metric file, as a run evaluates it, and on a tree without the family
+    spec = common.load("layer_metrics", "ec_read.worker_read_share.json")
+    entry = common.benchmark_json()["per_layer"][-1]
+    assert entry["name"] == spec["name"] and entry["workloads"] == GET_CELLS
+    for field in ("unit", "better", "source", "layer", "moves"):
+        assert entry[field] == spec[field], field
+    assert layer_metrics.Observed(before, after, {}, {}, {}, {}, None, None, {}).value(spec) == 100.0
+    parents = [{k: v for k, v in page.items() if not k.startswith(LOCAL_READS)} for page in (before, after)]
+    assert layer_metrics.Observed(*parents, {}, {}, {}, {}, None, None, {}).value(spec) is None
+
+
+def test_a_decode_altered_as_the_benchmarks_control_alters_it_fails_the_crc(live, monkeypatch):
+    """`benchmarks/lib/server_child.py --fault ec_decode_byte` lays a wrapper
+    over `TpuRSCodec.reconstruct_rows`; the read path still decodes through
+    that method, so a needle with a rebuilt interval fails its CRC and a
+    needle without one reads back."""
+    from benchmarks.lib import server_child
+
+    monkeypatch.setattr(live.vs, "_codec", TpuRSCodec())
+    monkeypatch.setattr(TpuRSCodec, "reconstruct_rows", TpuRSCodec.reconstruct_rows)
+    server_child.fault_ec_decode_byte()
+    on_lost = [k for k in live.body if any(s == 3 for s, _o, _n in live.reference_intervals(k))]
+    healthy = [k for k in live.body if k not in on_lost]
+    assert on_lost and healthy
+
+    async def read():
+        await live.lose([3, 11])
+        try:
+            before = scrape()
+            got = {}
+            for key in (on_lost[0], healthy[0]):
+                try:
+                    n = await live.vs.read_ec_needle(live.ev, key)
+                    got[key] = None if n is None else bytes(n.data)
+                except Exception as e:
+                    got[key] = e
+            return got, before, scrape()
+        finally:
+            await live.mount([3, 11])
+
+    got, before, after = live.run(read())
+    assert isinstance(got[on_lost[0]], Exception) and "CRC" in str(got[on_lost[0]]).upper()
+    assert got[healthy[0]] == live.body[healthy[0]]
+    assert moved(before, after, "seaweedfs_tpu_rs_dispatches_total", op="decode") == 1
+
+
 # ------------------------------------------------ the cell's per-layer metrics
 @pytest.mark.parametrize("name", CHUNK_METRICS[:-1])
 def test_each_chunk_metric_file_reads_what_the_program_wrote(live, name):
@@ -452,7 +554,7 @@ def test_each_chunk_metric_file_reads_what_the_program_wrote(live, name):
     elif name == "ec_read.degraded_get_share":
         assert value == 100.0 * needles / len(live.body)
     elif name == "ec_read.survivor_mb_per_reconstruct":
-        assert 12 * EC_DEGRADED_SPAN / 1e6 <= value <= 12 * MB / 1e6
+        assert 10 * EC_DEGRADED_SPAN / 1e6 <= value <= 10 * MB / 1e6
     elif name == "http.body_mb_per_s":
         assert value == client["bytes_good"] / 2.0 / 1e6
     else:

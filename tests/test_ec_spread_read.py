@@ -57,6 +57,8 @@ SERVED_BYTES = "seaweedfs_tpu_ec_shard_read_served_bytes_total"
 COPY_BYTES = "seaweedfs_tpu_ec_shard_copy_bytes_total"
 COPY_SECONDS = "seaweedfs_tpu_ec_shard_copy_seconds_total"
 SHARD_READ = dict(server="volume", operation="VolumeEcShardRead")
+LOCAL_READS = "seaweedfs_tpu_ec_reconstruct_local_reads_total"
+SURVIVOR_BYTES = "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total"
 
 
 class Spread:
@@ -341,6 +343,60 @@ def test_a_reconstruct_is_the_reference_codecs(one_lost):
     want = rs_codec.Codec(K, M).recover({s: span(s) for s in survivors}, [missing])[0]
     assert got == want.tobytes()
     assert got == span(missing).tobytes()  # the stopped server's file is still on its disk
+
+
+def test_local_and_remote_survivors_land_in_the_rows_of_one_array(one_lost, monkeypatch):
+    """What the codec is handed for a reconstruct on a server that holds four
+    of the ten survivors: each survivor's own span under its shard id, the
+    four read from disk and the six that crossed gRPC alike, and the ten of
+    them the consecutive rows of ONE array, lowest shard id first."""
+    from seaweedfs_tpu.ops.rs_kernel import rows_of_one_array
+
+    vs = one_lost.source
+    ev = vs.store.find_ec_volume(one_lost.vid)
+    missing = min(one_lost.lost_shards)
+    survivors = plan(one_lost, vs, missing)
+    codec = vs.codec_for(K, M)
+    handed = []
+    inner = codec.reconstruct_rows
+
+    def reconstruct_rows(shards, wanted, *args, **kw):
+        sub = rows_of_one_array([s for s in shards if s is not None])
+        handed.append(([None if s is None else bytes(s) for s in shards], list(wanted),
+                       None if sub is None else sub.shape))
+        return inner(shards, wanted, *args, **kw)
+
+    monkeypatch.setattr(codec, "reconstruct_rows", reconstruct_rows, raising=False)
+    off = 7 * SPAN + 300
+
+    async def body():
+        vs._ec_degraded_cache().invalidate(one_lost.vid)
+        return await vs._recover_one_interval(ev, missing, off, 1000, 1)
+
+    before = scrape()
+    got = one_lost.run(body())
+    after = scrape()
+    (shards, wanted, shape), = handed
+    assert wanted == [missing] and shape is not None and shape[0] == K and shape[1] >= SPAN
+
+    def span(s):
+        with open(one_lost.shard_file(s), "rb") as f:
+            f.seek(7 * SPAN)
+            return f.read(SPAN)
+
+    assert [s for s, b in enumerate(shards) if b is not None] == survivors
+    for s in survivors:
+        assert shards[s] == span(s), s
+    assert got == span(missing)[300:1300]
+    own = len(one_lost.shards_on(vs) - {missing})
+    assert moved(before, after, LOCAL_READS, where="worker") == moved(before, after, LOCAL_READS) == own
+    assert moved(before, after, SURVIVOR_BYTES, origin="local") == own * SPAN
+    assert moved(before, after, SURVIVOR_BYTES, origin="remote") == (K - own) * SPAN
+    # the benchmark's metric file, as a run evaluates it, and on the parent
+    spec = common.load("layer_metrics", "ec_read.worker_read_share.json")
+    assert layer_metrics.Observed(before, after, {}, {}, {}, {}, None, None, {}).value(spec) == 100.0
+    parents = [{k: v for k, v in page.items() if not k.startswith(LOCAL_READS)} for page in (before, after)]
+    assert layer_metrics.Observed(*parents, {}, {}, {}, {}, None, None, {}).value(spec) is None
 
 
 def test_counters_and_stages_of_one_reconstruct_by_hand(one_lost):
@@ -649,15 +705,25 @@ def _one_server(tmp_path, mounted: int):
 
 
 def _spy_preads(monkeypatch) -> list:
-    """[(offset, size)] of every shard read from here on."""
-    preads = []
-    inner = EcVolumeShard.read_at
+    """[(offset, size)] of every survivor span a reconstruct reads from a
+    shard file here from now on (`read_into`: the worker that decodes reads
+    each into its row of the decode's input), and on `.shards` and `.threads`
+    the shard and the thread of each."""
 
-    def read_at(self, size, offset):
-        preads.append((offset, size))
-        return inner(self, size, offset)
+    class Preads(list):
+        pass
 
-    monkeypatch.setattr(EcVolumeShard, "read_at", read_at)
+    preads = Preads()
+    preads.shards, preads.threads = [], []
+    inner = EcVolumeShard.read_into
+
+    def read_into(self, buf, offset):
+        preads.append((offset, len(buf)))
+        preads.shards.append(self.shard_id)
+        preads.threads.append(threading.get_ident())
+        return inner(self, buf, offset)
+
+    monkeypatch.setattr(EcVolumeShard, "read_into", read_into)
     return preads
 
 
@@ -685,7 +751,14 @@ def test_with_every_survivor_local_the_span_is_the_wide_one(tmp_path, monkeypatc
     after = scrape()
     assert got == _shard_bytes(base, off, 2048)
     assert not asked and moved(before, after, READS) == 0
-    assert set(preads) == {(2 * WIDE, WIDE)} and len(preads) == 13
+    # the ten lowest of the thirteen survivors, each once, and no spare; none
+    # on the thread that runs the loop (here the test's own)
+    assert set(preads) == {(2 * WIDE, WIDE)}
+    assert preads.shards == [s for s in range(K + M) if s != DEAD][:K]
+    assert threading.get_ident() not in preads.threads
+    assert moved(before, after, LOCAL_READS, where="worker") == moved(before, after, LOCAL_READS) == K
+    assert moved(before, after, SURVIVOR_BYTES, origin="local") == K * WIDE
+    assert moved(before, after, SURVIVOR_BYTES, origin="remote") == 0
     assert _cached_spans(host) == [(2 * WIDE, WIDE)]
     ev.close()
 
@@ -705,9 +778,69 @@ def test_with_a_survivor_on_another_server_the_span_is_the_narrow_one(tmp_path, 
     # as many of the others as the decode needs, none to spare, each the span
     assert len(asked) == K - mounted and {a[1:] for a in asked} == {narrow}
     assert set(preads) <= {narrow} and len(preads) == mounted
+    assert threading.get_ident() not in preads.threads
+    assert moved(before, after, LOCAL_READS, where="worker") == moved(before, after, LOCAL_READS) == mounted
+    assert moved(before, after, SURVIVOR_BYTES, origin="local") == mounted * narrow[1]
+    assert moved(before, after, SURVIVOR_BYTES, origin="remote") == (K - mounted) * narrow[1]
     assert moved(before, after, READS, outcome="ok") == K - mounted
     assert moved(before, after, READ_BYTES) == (K - mounted) * narrow[1]
     assert _cached_spans(host) == [narrow]
+    ev.close()
+
+
+@pytest.mark.parametrize("fault", ["short", "raises"])
+@pytest.mark.parametrize("spare", ["local", "remote", "none"])
+def test_a_local_survivor_that_cannot_be_read_is_replaced_by_the_next_one(
+        tmp_path, monkeypatch, fault, spare):
+    """The lowest survivor's read comes a byte short, or raises: its row goes
+    to the next local spare, read the same way; with no spare on this server
+    the survivors not asked yet are (the second round), and with none of
+    those either there is no answer, not a wrong one."""
+    mounted = 13 if spare == "local" else K
+    base, ev, host, asked = _one_server(tmp_path, mounted)
+    if spare == "none":
+        async def nobody(*a, **kw):
+            return None
+
+        host._read_remote_shard_interval = nobody
+    preads = _spy_preads(monkeypatch)
+    spied = EcVolumeShard.read_into
+    bad = min(s for s in range(K + M) if s != DEAD)
+
+    def read_into(self, buf, offset):
+        if self.shard_id != bad:
+            return spied(self, buf, offset)
+        if fault == "raises":
+            preads.shards.append(bad)
+            raise OSError(5, "injected")
+        return spied(self, buf[:-1], offset)
+
+    monkeypatch.setattr(EcVolumeShard, "read_into", read_into)
+    off = 4 * WIDE + 777
+    before = scrape()
+    got = _recover(host, ev, off, 3000)
+    after = scrape()
+    survivors = [s for s in range(K + M) if s != DEAD]
+    if spare == "none":
+        assert got is None and not _cached_spans(host)
+        assert moved(before, after, "seaweedfs_tpu_ec_reconstructions_total") == 0
+    else:
+        assert got == _shard_bytes(base, off, 3000)
+        assert _cached_spans(host) == [(4 * WIDE, WIDE)]
+        assert moved(before, after, "seaweedfs_tpu_ec_reconstructions_total", kind="cold") == 1
+    if spare == "local":
+        # eleven reads: the ten lowest, the bad one among them, and one spare
+        assert not asked and preads.shards == survivors[: K + 1]
+    else:
+        # the ten local ones, then the three others, then the local ones again
+        assert preads.shards == survivors[:K] * 2
+        assert len(asked) == (3 if spare == "remote" else 0)
+    assert threading.get_ident() not in preads.threads
+    short = (len(preads.shards) // K) * (WIDE - 1) if fault == "short" else 0
+    good = sum(s != bad for s in preads.shards) * WIDE
+    assert moved(before, after, SURVIVOR_BYTES, origin="local") == good + short
+    assert moved(before, after, LOCAL_READS, where="worker") == len(preads.shards)
+    assert moved(before, after, LOCAL_READS, where="loop") == 0
     ev.close()
 
 

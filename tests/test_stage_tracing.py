@@ -418,6 +418,9 @@ CHUNK_METRICS = [
     "ec_read.assemble_ms", "ec_read.survivor_mb_per_reconstruct",
     "rs_decode_roofline",
 ]
+# ISSUE 36's one, last: read in the three GET cells (tests/test_ec_chunk_read.py
+# and tests/test_ec_spread_read.py evaluate it)
+WORKER_READ_METRICS = ["ec_read.worker_read_share"]
 ALL_NEW_METRICS = [
     (cell, name)
     for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS, PROXIED_METRICS)
@@ -583,7 +586,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     names = [e["name"] for e in common.benchmark_json()["per_layer"]]
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
-    new += SPREAD_METRICS + CHUNK_METRICS
+    new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
