@@ -220,6 +220,15 @@ async def _run_forever(*servers) -> None:
             await s.stop()
 
 
+def _serve(*servers) -> None:
+    """Run servers until the process is told to end, on ONE loop whose
+    selector reads the clock (serving_core.LoopClock: where the loop's
+    second goes, on /metrics)."""
+    from ..server.serving_core import new_event_loop
+
+    asyncio.run(_run_forever(*servers), loop_factory=new_event_loop)
+
+
 def _pulse_kwargs() -> dict:
     """SEAWEEDFS_TPU_PULSE_SECONDS -> pulse_seconds for master/volume.
     The heartbeat cadence is an in-process constructor knob the bench
@@ -280,7 +289,7 @@ def cmd_master(argv: list[str]) -> int:
         **_pulse_kwargs(),
     )
     print(f"master listening on {args.ip}:{args.port}")
-    asyncio.run(_run_forever(ms))
+    _serve(ms)
     return 0
 
 
@@ -294,7 +303,7 @@ def cmd_volume(argv: list[str]) -> int:
     from ..util.profiling import Profiler
 
     with Profiler(args.cpuprofile, args.memprofile):
-        asyncio.run(_run_forever(vs))
+        _serve(vs)
     return 0
 
 
@@ -430,7 +439,7 @@ def cmd_server(argv: list[str]) -> int:
     from ..util.profiling import Profiler
 
     with Profiler(args.cpuprofile, args.memprofile):
-        asyncio.run(_run_forever(*servers))
+        _serve(*servers)
     return 0
 
 
@@ -570,7 +579,7 @@ def cmd_filer(argv: list[str]) -> int:
         follow_source=args.followSource,
     )
     print(f"filer listening on {args.ip}:{args.port}")
-    asyncio.run(_run_forever(fs))
+    _serve(fs)
     return 0
 
 
@@ -606,7 +615,7 @@ def cmd_s3(argv: list[str]) -> int:
     )
     s3 = S3Server(fs, host=args.ip, port=args.port, iam=iam)
     print(f"s3 gateway on {args.ip}:{args.port} (filer on :{args.filerPort})")
-    asyncio.run(_run_forever(fs, s3))
+    _serve(fs, s3)
     return 0
 
 
@@ -624,7 +633,7 @@ def cmd_blob(argv: list[str]) -> int:
 
     bs = BlobServer(args.dir, args.port, host=args.ip)
     print(f"blob server listening on {args.ip}:{args.port}")
-    asyncio.run(_run_forever(bs))
+    _serve(bs)
     return 0
 
 
@@ -641,7 +650,7 @@ def cmd_webdav(argv: list[str]) -> int:
     fs = FilerServer(master=args.master, host=args.ip, port=args.filerPort)
     dav = WebDavServer(fs, host=args.ip, port=args.port)
     print(f"webdav on {args.ip}:{args.port} (filer on :{args.filerPort})")
-    asyncio.run(_run_forever(fs, dav))
+    _serve(fs, dav)
     return 0
 
 
@@ -659,7 +668,7 @@ def cmd_msg_broker(argv: list[str]) -> int:
 
     broker = MessageBroker(host=args.ip, port=args.port, filer=args.filer)
     print(f"message broker gRPC on {args.ip}:{args.port + 10000}")
-    asyncio.run(_run_forever(broker))
+    _serve(broker)
     return 0
 
 

@@ -242,7 +242,7 @@ def _matmul_packed_on_device(
 
 
 RS_OPS = ("encode", "decode", "apply")
-RS_STAGES = ("stack", "pack", "put", "dispatch", "fetch", "unpack")
+RS_STAGES = ("stack", "pack", "put", "dispatch", "fetch")
 # the stages of a TpuRSCodec call, bound once: RS_STAGE[op][stage]. Leaves
 # of work, each an `rs.<stage>` event in a profiler trace; `rs.fetch`, the
 # blocking fetch of the device's answer, is the host's side of upload +
@@ -341,8 +341,7 @@ def gf_matmul_bytes(
         )
     with stages["fetch"]():
         out = np.asarray(out)
-    with stages["unpack"]():
-        out = unpack_bytes_host(out, n)
+    out = unpack_bytes_host(out, n)
     count_rs_dispatch(
         op,
         "device" if use_pallas and on_tpu() else "device_emulated",

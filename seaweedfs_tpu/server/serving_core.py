@@ -36,7 +36,7 @@ two-tier shape instead of re-wiring it by hand:
 - a uniform observability surface on the cold tier of every server type:
   ``/metrics`` (Prometheus exposition + exemplars), ``/debug/traces``
   (flight-recorder JSONL, ``?status=1`` for counters) and the on-demand
-  ``/debug/pprof/{start,stop,dump,profile,heap}`` handlers. These paths
+  ``/debug/pprof/{start,stop,dump,profile,heap,device}`` handlers. These paths
   are reserved: the fast tiers FALLBACK them, and the middleware answers
   before any route (including the S3 bucket router) sees them.
 """
@@ -44,8 +44,13 @@ two-tier shape instead of re-wiring it by hand:
 from __future__ import annotations
 
 import asyncio
+import functools
 import os
+import resource
+import selectors
+import threading
 import time
+import weakref
 from typing import Optional
 
 from aiohttp import web
@@ -55,15 +60,21 @@ from ..util.fasthttp import (
     DETACHED,
     FALLBACK,
     FastHTTPServer,
+    TierStages,
     render_response,
 )
 from ..util.metrics import (
+    EVENT_LOOP_CPU_SECONDS,
     EVENT_LOOP_LAG_SECONDS,
     EVENT_LOOP_LAG_TICKS,
+    EVENT_LOOP_SELECT_SECONDS,
+    EVENT_LOOP_STALL_KERNEL_SECONDS,
+    EVENT_LOOP_STALL_SECONDS,
+    EVENT_LOOP_STALLS,
+    EVENT_LOOP_TURN_SECONDS,
+    EVENT_LOOP_TURNS,
     REQUEST_COUNTER,
     REQUEST_HISTOGRAM,
-    REQUEST_PROXIED,
-    REQUEST_PROXY_SECONDS,
     REQUEST_WAIT_SECONDS,
 )
 
@@ -75,6 +86,256 @@ _set_tenant = tenancy.set_current
 _reset_tenant = tenancy.reset_current
 
 LOOP_LAG_PROBE_SECONDS = 0.010
+# a tick this late (four ticks) is a stall of the loop: counted, and kept as
+# a `loop.stall` span with the kernel's account of the interval
+LOOP_STALL_SECONDS = 0.040
+# the kernel's account is read anew every tenth tick, so a stall's deltas
+# cover at most 100 ms before it
+_BASELINE_SECONDS = 10 * LOOP_LAG_PROBE_SECONDS
+
+
+class LoopClock(selectors.DefaultSelector):
+    """The selector of a serving loop, reading the clock on both sides of
+    the system call: where the loop's second goes. `select` is the wall
+    inside it (`poll` where the loop passed timeout 0 because callbacks
+    were ready: the kernel returns at once, so that wall is the loop
+    taking the interpreter lock BACK after giving it up at the call;
+    `wait` otherwise: idle until an event), a turn the wall from one
+    select's return to the next one's call. The sums are plain attributes;
+    `publish` moves them to /metrics with the thread's CPU time, so turn +
+    select is the loop's wall at every reading. Made on the thread that
+    runs the loop, as `asyncio.run(..., loop_factory=new_event_loop)`
+    makes it."""
+
+    def __init__(self):
+        super().__init__()
+        self.turns = 0
+        self.turn_s = self.poll_s = self.wait_s = 0.0
+        self._returned = _perf()
+        self._cpu = time.thread_time()
+
+    def select(self, timeout=None):
+        t0 = _perf()
+        self.turn_s += t0 - self._returned
+        self.turns += 1
+        events = _select(self, timeout)  # no super() lookup a turn
+        t1 = self._returned = _perf()
+        if timeout == 0:
+            self.poll_s += t1 - t0
+        else:
+            self.wait_s += t1 - t0
+        return events
+
+    def publish(self) -> dict:
+        """On the loop's thread (a probe's tick, the /metrics render).
+        Returns what it moved, in ms: the loop's own account of the time
+        since the last publish, which a stall's span carries."""
+        now = _perf()
+        cpu = time.thread_time()
+        turn_s = self.turn_s + now - self._returned  # this turn so far
+        moved = {
+            "loop_cpu_ms": round((cpu - self._cpu) * 1e3, 3),
+            "loop_turn_ms": round(turn_s * 1e3, 3),
+            "loop_poll_ms": round(self.poll_s * 1e3, 3),
+            "loop_wait_ms": round(self.wait_s * 1e3, 3),
+            "loop_turns": self.turns,
+        }
+        _LOOP_TURN.inc(turn_s)
+        _LOOP_TURNS.inc(self.turns)
+        _LOOP_POLL.inc(self.poll_s)
+        _LOOP_WAIT.inc(self.wait_s)
+        _LOOP_CPU.inc(cpu - self._cpu)
+        self.turns = 0
+        self.turn_s = self.poll_s = self.wait_s = 0.0
+        self._returned, self._cpu = now, cpu
+        return moved
+
+
+_select = selectors.DefaultSelector.select
+_LOOP_TURN = EVENT_LOOP_TURN_SECONDS.child()
+_LOOP_TURNS = EVENT_LOOP_TURNS.child()
+_LOOP_POLL = EVENT_LOOP_SELECT_SECONDS.child(mode="poll")
+_LOOP_WAIT = EVENT_LOOP_SELECT_SECONDS.child(mode="wait")
+_LOOP_CPU = EVENT_LOOP_CPU_SECONDS.child()
+_STALLS = EVENT_LOOP_STALLS.child()
+_STALL_SECONDS = EVENT_LOOP_STALL_SECONDS.child()
+
+
+# ---- the kernel's account of an interval: each source returns cumulative
+# readings by tag (`*_s` seconds, the rest counts) and raises OSError on a
+# host that lacks it
+class _KeptFile:
+    """A /proc or cgroup file read anew by ONE `pread` of a descriptor kept
+    open: a read on the loop's thread gives the interpreter lock up once,
+    where open + fstat + read + read + close would five times (on the
+    chip's host, beside a thread that holds the lock, 5 ms against 16)."""
+
+    def __init__(self, path: str):
+        self.path = path
+        self._fd: Optional[int] = None
+
+    def read(self) -> bytes:
+        if self._fd is None:
+            self._fd = os.open(self.path, os.O_RDONLY)
+            weakref.finalize(self, os.close, self._fd)
+        return os.pread(self._fd, 8192, 0)
+
+
+@functools.cache
+def _cgroup_cpu_stat() -> str:
+    """The process's cgroup's cpu.stat (v2: `0::<path>`)."""
+    with open("/proc/self/cgroup") as f:
+        for line in f:
+            if line.startswith("0::"):
+                return "/sys/fs/cgroup" + line[3:].strip().rstrip("/") + "/cpu.stat"
+    raise FileNotFoundError("no cgroup v2 entry")
+
+
+_CLK_TCK = os.sysconf("SC_CLK_TCK")
+
+
+def kernel_sources() -> dict:
+    """The five sources of a LoopWatch, by name. Made on the loop's thread
+    (`/proc/thread-self` is the opener's)."""
+    stat = _KeptFile("/proc/stat")
+    schedstat = _KeptFile("/proc/thread-self/schedstat")
+    pressure = _KeptFile("/proc/pressure/cpu")
+    cpu_stat: list = []  # the cgroup's file, looked up at the first read
+
+    def steal() -> dict:
+        # cpu user nice system idle iowait irq softirq STEAL ..., all CPUs
+        return {"steal_s": int(stat.read().split(None, 9)[8]) / _CLK_TCK}
+
+    def throttled() -> dict:
+        if not cpu_stat:
+            cpu_stat.append(_KeptFile(_cgroup_cpu_stat()))
+        for line in cpu_stat[0].read().splitlines():
+            if line.startswith(b"throttled_usec"):
+                return {"throttled_s": int(line.split()[1]) / 1e6}
+        raise FileNotFoundError("cpu.stat has no throttled_usec")
+
+    def runqueue() -> dict:
+        # of the opening thread: on-CPU ns, run-queue delay ns, timeslices
+        return {"runqueue_s": int(schedstat.read().split()[1]) / 1e9}
+
+    def pressure_some() -> dict:
+        some = pressure.read().split(b"\n", 1)[0]
+        return {"pressure_s": int(some.rsplit(b"total=", 1)[1]) / 1e6}
+
+    def rusage() -> dict:
+        # the process's own account: CPU of all its threads, faults, switches
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        return {"process_cpu_s": time.process_time(), "minflt": ru.ru_minflt,
+                "majflt": ru.ru_majflt, "nivcsw": ru.ru_nivcsw}
+
+    return {"steal": steal, "throttled": throttled, "runqueue": runqueue,
+            "pressure": pressure_some, "rusage": rusage}
+
+
+# the sources with a child of event_loop_stall_kernel_seconds_total
+_KERNEL_SECONDS = ("steal", "throttled", "runqueue")
+
+
+class LoopWatch:
+    """One a LOOP, shared by the lag probes of every ServingCore on it:
+    the loop's clock (None for a loop the CLI did not make) and the stall
+    recorder. A tick LOOP_STALL_SECONDS late is a stall, counted once
+    whichever probes saw it, with what the kernel counted meanwhile and
+    what the loop's clock did, as deltas against a baseline refreshed at
+    most every 100 ms while ticks run (never on an idle server): a ROOT
+    span `loop.stall` in the flight recorder (kept at any sampling rate,
+    as a shed is), and a `loop.stall` mark on the profiler's clock where
+    the stall ENDED."""
+
+    def __init__(self, clock: Optional[LoopClock] = None, sources=None):
+        self.clock = clock
+        self._sources = sources  # None: kernel_sources(), at the first tick
+        self._kernel: dict = {}  # source -> its kernel_seconds child
+        self._base: Optional[dict] = None
+        self._base_at = float("-inf")
+        self._stalled_until = float("-inf")
+        _STALLS.inc(0.0)  # on /metrics before the first stall
+        _STALL_SECONDS.inc(0.0)
+
+    def _account(self) -> dict:
+        if self._sources is None:
+            self._sources = kernel_sources()
+        seen = {}
+        for name, read in list(self._sources.items()):
+            try:
+                seen.update(read())
+            except (OSError, ValueError, IndexError):
+                del self._sources[name]  # the host lacks it: left out
+                continue
+            if name in _KERNEL_SECONDS and name not in self._kernel:
+                child = EVENT_LOOP_STALL_KERNEL_SECONDS.child(source=name)
+                child.inc(0.0)  # on /metrics before the first stall
+                self._kernel[name] = child
+        if trace.GC_WATCH is not None:
+            seen["gc_s"] = trace.GC_WATCH.seconds
+        return seen
+
+    def tick(self, due: float, now: float, late: float) -> None:
+        """A probe's tick, due at `due`, ran at `now` (the loop's clock)."""
+        if late >= LOOP_STALL_SECONDS:
+            if due > self._stalled_until:  # else another probe's: the same
+                self._stall(now, late)
+            self._stalled_until = now
+        elif now - self._base_at >= _BASELINE_SECONDS:
+            self._base, self._base_at = self._account(), now
+            if self.clock is not None:
+                self.clock.publish()
+
+    def _stall(self, now: float, late: float) -> None:
+        _STALLS.inc()
+        _STALL_SECONDS.inc(late)
+        tags = {"late_ms": round(late * 1e3, 3),
+                "threads": threading.active_count(),
+                "cpus": os.cpu_count()}  # steal and pressure sum all of them
+        base, age = self._base, now - self._base_at
+        seen = self._account()
+        self._base, self._base_at = seen, now
+        if self.clock is not None:
+            # what the loop itself did meanwhile: on a CPU all the while (a
+            # long turn of its own work), in a turn without one (held off
+            # at a system call inside a callback), or in a poll (held off
+            # at select)
+            tags.update(self.clock.publish())
+        if base is not None:
+            tags["baseline_age_ms"] = round(age * 1e3, 3)
+            for key, value in seen.items():
+                if key not in base:
+                    continue
+                delta = value - base[key]
+                if key.endswith("_s"):
+                    tags[key[:-2] + "_ms"] = round(delta * 1e3, 3)
+                    child = self._kernel.get(key[:-2])
+                    if child is not None:
+                        child.inc(min(max(delta, 0.0), late))
+                else:
+                    tags[key] = delta
+        if trace.RECORDER.enabled:
+            trace.RECORDER.promote_fault("loop.stall", "stall", late, **tags)
+        trace.mark("loop.stall", late_ms=tags["late_ms"])
+
+
+_WATCHES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def new_event_loop() -> asyncio.AbstractEventLoop:
+    """`loop_factory` of every server the CLI runs: a selector loop whose
+    selector is a LoopClock."""
+    clock = LoopClock()
+    loop = asyncio.SelectorEventLoop(clock)
+    _WATCHES[loop] = LoopWatch(clock)
+    return loop
+
+
+def loop_watch(loop) -> LoopWatch:
+    watch = _WATCHES.get(loop)
+    if watch is None:
+        watch = _WATCHES[loop] = LoopWatch()
+    return watch
 
 
 class LoopLagProbe:
@@ -86,19 +347,22 @@ class LoopLagProbe:
     (`kick`); a tick that saw no request since the last one does not
     re-arm, so an idle server has no timer (on the chip's host two cores
     ticking an idle loop cost a conversion 3 % more CPU: PERF.md, PR 26).
-    It ends with the tier it measures (`alive`), whoever stops that."""
+    It ends with the tier it measures (`alive`), whoever stops that. Each
+    tick is also handed to the loop's LoopWatch."""
 
     def __init__(self, server: str, alive):
         self._seconds = EVENT_LOOP_LAG_SECONDS.child(server=server)
         self._ticks = EVENT_LOOP_LAG_TICKS.child(server=server)
         self._alive = alive
         self._loop = None
+        self._watch: Optional[LoopWatch] = None
         self._handle = None
         self._due = 0.0
         self._kicked = False
 
     def start(self, loop) -> None:
         self._loop = loop
+        self._watch = loop_watch(loop)
 
     def kick(self) -> None:
         self._kicked = True
@@ -113,8 +377,11 @@ class LoopLagProbe:
         self._handle = None
         if self._loop is None or not self._alive():
             return
-        self._seconds.inc(max(0.0, self._loop.time() - self._due))
+        now = self._loop.time()
+        late = max(0.0, now - self._due)
+        self._seconds.inc(late)
         self._ticks.inc()
+        self._watch.tick(self._due, now, late)
         if self._kicked:
             self._kicked = False
             self._arm()
@@ -187,6 +454,10 @@ async def _serve_debug(name: str, address: str, request, path: str,
     if path == "/metrics":
         from ..util.metrics import REGISTRY
 
+        watch = _WATCHES.get(asyncio.get_running_loop())
+        if watch is not None and watch.clock is not None:
+            watch.clock.publish()  # this handler runs on the loop's thread
+
         # content negotiation: exemplars are only legal in the
         # OpenMetrics exposition — classic text/plain parsers reject a
         # '#' after the sample value, so a stock Prometheus scrape must
@@ -255,6 +526,7 @@ async def _serve_debug(name: str, address: str, request, path: str,
             "/debug/pprof/start": profiling.handle_pprof_start,
             "/debug/pprof/stop": profiling.handle_pprof_stop,
             "/debug/pprof/dump": profiling.handle_pprof_dump,
+            "/debug/pprof/device": profiling.handle_pprof_device,
         }.get(path)
         if handler_fn is None:
             return web.json_response(
@@ -330,13 +602,9 @@ class ServingCore:
         self.internal_port = site._server.sockets[0].getsockname()[1]
         self.fast_server = FastHTTPServer(
             self._dispatch, backend=("127.0.0.1", self.internal_port),
-            proxy_stage=trace.stage(
-                "http.proxy",
-                REQUEST_PROXY_SECONDS.child(server=self.name),
-                REQUEST_PROXIED.child(server=self.name),
-                annotate=False,  # held across awaits: a counter only
-            ),
+            stages=TierStages(self.name),
         )
+        trace.watch_gc()
         self._lag_probe.start(asyncio.get_running_loop())
         await self.fast_server.start(self.host, self.port)
 

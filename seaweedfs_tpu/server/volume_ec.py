@@ -36,6 +36,7 @@ from ..util import trace
 from ..util.metrics import (
     EC_DEGRADED_READ_SECONDS,
     EC_DEGRADED_READ_STAGE_SECONDS,
+    EC_DEGRADED_READ_WORKER_SECONDS,
     EC_ENCODE_BATCH_FALLBACKS,
     EC_ENCODE_BYTES,
     EC_GENERATE_SECONDS,
@@ -108,7 +109,10 @@ def _read_stage(label: str, annotate: bool = True):
 # decode's input rows (its leaf, each shard read into a row, is the
 # `ec.read.pread` event, on the worker's thread), executor_wait runs from
 # run_in_executor to the worker's first line, decode is the worker's wall
-# around reconstruct_rows alone (its leaves are the codec's `rs.*` events).
+# around reconstruct_rows alone (its leaves are the codec's `rs.*` events),
+# loop_resume runs from the worker's last line to the coroutine's first line
+# after the hop (the loop coming round to the finished future). With
+# cache_put they add up to ec_degraded_read_seconds{result="cold"}.
 # Before a reconstruct is tried at all, remote_attempts: the TTL'd
 # location refresh (one LookupEcVolume a SHARD_LOCATION_TTL, a dict look-up
 # otherwise) and, only where the table names a holder of the shard, the
@@ -134,13 +138,19 @@ _SHARD_READ_SERVED = REQUEST_HISTOGRAM.child(
 _ST_PREAD = trace.stage("ec.read.pread")
 _ST_EXECUTOR_WAIT = _read_stage("executor_wait", annotate=False)
 _ST_DECODE = _read_stage("decode", annotate=False)
+_ST_LOOP_RESUME = _read_stage("loop_resume", annotate=False)
 _ST_CACHE_PUT = _read_stage("cache_put")
+# the worker's whole wall beside its CPU: wall without CPU is the worker
+# waiting (the interpreter lock, the device, the disk)
+_WORKER_WALL = EC_DEGRADED_READ_WORKER_SECONDS.child(clock="wall")
+_WORKER_CPU = EC_DEGRADED_READ_WORKER_SECONDS.child(clock="cpu")
 # a needle read whole, outside any reconstruct: each interval counted once
 # by what served it (_INTERVAL[source]), the needle once by kind, and the
-# two pieces of work the loop does itself: the synchronous pread of an
-# interval on a local shard (up to a block: 1 MiB of a chunk needle) and
-# the assembly (join, parse, CRC over the whole record). Both are annotated
-# leaves; under a sampled request each is a child span
+# three pieces of work the loop does itself: the .ecx binary search of
+# preads that locates the needle, the synchronous pread of an interval on a
+# local shard (up to a block: 1 MiB of a chunk needle) and the assembly
+# (join, parse, CRC over the whole record). All are annotated leaves; under
+# a sampled request each is a child span
 INTERVAL_SOURCES = ("local", "cold_tier", "remote", "reconstructed", "cache")
 _INTERVAL = {
     source: EC_READ_INTERVALS.child(source=source) for source in INTERVAL_SOURCES
@@ -155,6 +165,11 @@ _LOCAL_READS = {
     where: EC_RECONSTRUCT_LOCAL_READS.child(where=where)
     for where in ("worker", "loop")
 }
+_ST_LOCATE = trace.stage(
+    "ec.read.locate",
+    EC_READ_STAGE_SECONDS.child(stage="locate"),
+    label="locate",
+)
 _ST_LOCAL_INTERVAL = trace.stage(
     "ec.read.local_interval",
     EC_READ_STAGE_SECONDS.child(stage="local_interval"),
@@ -1562,24 +1577,32 @@ class EcHandlers:
                 _SURVIVOR_BYTES_LOCAL.inc(read)
             return shards if filled == k else None
 
-        def rebuild(t_submit: float) -> Optional[list]:
-            _ST_EXECUTOR_WAIT.since(t_submit)
+        def rebuild(t_submit: float) -> tuple:
+            """-> (the decoded rows, or None short of survivors; when the
+            worker was done, which is where loop_resume starts)."""
+            t0 = _ST_EXECUTOR_WAIT.since(t_submit)
+            cpu0 = time.thread_time()
+            rows = None
             with _ST_SURVIVOR_READ():
                 shards = fill()
-            if shards is None:
-                return None
-            with _ST_DECODE():
-                return codec.reconstruct_rows(shards, [missing_shard])
+            if shards is not None:
+                with _ST_DECODE():
+                    rows = codec.reconstruct_rows(shards, [missing_shard])
+            _WORKER_CPU.inc(time.thread_time() - cpu0)
+            t_done = time.perf_counter()
+            _WORKER_WALL.inc(t_done - t0)
+            return rows, t_done
 
         decoded = None
         for more in (rest, ()):
             if len(local) + len(fetched) >= k:
                 # in the request's context, so that under a sampled request
                 # the worker's stages are child spans as the loop's are
-                decoded = await loop.run_in_executor(
+                decoded, t_done = await loop.run_in_executor(
                     None, contextvars.copy_context().run, rebuild,
                     time.perf_counter(),
                 )
+                _ST_LOOP_RESUME.since(t_done)
                 if decoded is not None:
                     break
             if not more:
@@ -1604,7 +1627,8 @@ class EcHandlers:
 
     async def read_ec_needle(self, ev: EcVolume, key: int) -> Optional[Needle]:
         try:
-            offset_units, size = ev.find_needle_from_ecx(key)
+            with _ST_LOCATE():
+                offset_units, size = ev.find_needle_from_ecx(key)
         except NeedleNotFound:
             return None
         if size == TOMBSTONE_FILE_SIZE:

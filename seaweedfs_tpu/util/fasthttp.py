@@ -33,6 +33,15 @@ from typing import Awaitable, Callable, Optional
 
 from . import faults, overload, tenancy, trace
 from .backoff import shared_retry_budget
+from .metrics import (
+    REQUEST_PARSE_SECONDS,
+    REQUEST_PROXIED,
+    REQUEST_PROXY_SECONDS,
+    RESPONSE_BUFFERED_BYTES,
+    RESPONSE_BYTES,
+    RESPONSE_WRITE_SECONDS,
+    RESPONSE_WRITES,
+)
 
 _perf = time.perf_counter  # bound once: stamped per parsed request
 _cur_tenant = tenancy.current  # bound once: read per client request
@@ -245,8 +254,9 @@ class FastHTTPProtocol(asyncio.Protocol):
         await w
 
     def data_received(self, data: bytes):
-        self.buf += data
-        self._pump()
+        with self.server.stages.parse():
+            self.buf += data
+            self._pump()
         # backpressure: stop reading while too much is queued (never on a
         # transport _fail() just closed — pause_reading would raise and
         # asyncio's fatal-error path discards the buffered 400)
@@ -552,7 +562,14 @@ class FastHTTPProtocol(asyncio.Protocol):
                             500, b'{"error":"internal error"}')
                     )
                     continue
-                self.transport.write(out)
+                stages = self.server.stages
+                with stages.write():
+                    self.transport.write(out)
+                if stages.sent is not None:
+                    stages.sent.inc(len(out))
+                    pending = self.transport.get_write_buffer_size()
+                    if pending:  # a small answer goes out at once
+                        stages.buffered.inc(pending)
                 if self.transport.is_closing():
                     return
         except asyncio.CancelledError:
@@ -562,7 +579,7 @@ class FastHTTPProtocol(asyncio.Protocol):
                 self.transport.close()
 
     async def _proxy(self, req: FastRequest) -> bool:
-        with self.server.proxy_stage():
+        with self.server.stages.proxy():
             resp, has_len = await proxy_request(
                 self.server.backend, req, transport=self.transport
             )
@@ -752,18 +769,44 @@ def finish_detached_proxy(server: "FastHTTPServer", req: FastRequest) -> None:
     t.add_done_callback(server._detached_tasks.discard)
 
 
+class TierStages:
+    """What a fast tier times and counts of its own work on the loop,
+    bound once a server (`server` None: events alone, no /metrics
+    children): `parse`, slicing requests out of what a socket delivered
+    (`http.parse`); `write`, handing a full answer to the transport
+    (`http.write`), with the answer's bytes (`sent`) and what the
+    transport still buffered right after (`buffered`: what did not go out
+    at once and leaves over later turns of the loop); `proxy`, the replay
+    of a FALLBACK against the cold tier (held across awaits: a counter
+    only)."""
+
+    __slots__ = ("parse", "write", "proxy", "sent", "buffered")
+
+    def __init__(self, server: Optional[str] = None):
+        def child(family):
+            return None if server is None else family.child(server=server)
+
+        self.parse = trace.stage("http.parse", child(REQUEST_PARSE_SECONDS))
+        self.write = trace.stage(
+            "http.write", child(RESPONSE_WRITE_SECONDS), child(RESPONSE_WRITES)
+        )
+        self.proxy = trace.stage(
+            "http.proxy", child(REQUEST_PROXY_SECONDS), child(REQUEST_PROXIED),
+            annotate=False,
+        )
+        self.sent = child(RESPONSE_BYTES)
+        self.buffered = child(RESPONSE_BUFFERED_BYTES)
+
+
 class FastHTTPServer:
     """Owns the public listening socket; `handler` is the fast tier,
     `backend` (host, port) the full aiohttp app for everything else."""
 
-    def __init__(self, handler: Handler, backend=None, proxy_stage=None):
+    def __init__(self, handler: Handler, backend=None,
+                 stages: Optional[TierStages] = None):
         self.handler = handler
         self.backend = backend
-        # times the replay of a FALLBACK against `backend` (a
-        # util/trace.Stage of the owning ServingCore; a wait, so a counter)
-        self.proxy_stage = proxy_stage or trace.stage(
-            "http.proxy", annotate=False
-        )
+        self.stages = stages or TierStages()
         self._server: Optional[asyncio.AbstractServer] = None
         self._conns: set = set()
         self._detached_tasks: set = set()  # strong refs (loop holds weak)

@@ -387,6 +387,97 @@ EVENT_LOOP_LAG_TICKS = REGISTRY.counter(
     "seaweedfs_tpu_event_loop_lag_ticks_total",
     "loop-lag probe callbacks run, by server",
 )
+# the serving loop's own clock (serving_core.LoopClock, the selector of a
+# loop the CLI makes): two clock reads a turn split the loop's wall into
+# select (inside the system call: `wait` with nothing ready, idle until an
+# event; `poll` with callbacks ready and timeout 0, where the kernel returns
+# at once and the wall is the loop taking the interpreter lock back) and
+# turn (from one select's return to the next one's call: the callbacks and
+# task steps a socket that became readable waits behind). turn + select is
+# the loop's wall; cpu of it the thread ran; turn + select{poll} - cpu it
+# had work and did not run (the interpreter lock, the kernel's run queue);
+# select{wait} it had none. One loop a process: no `server` label. Advanced
+# at every tenth tick of a lag probe and when /metrics is rendered
+EVENT_LOOP_SELECT_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_select_seconds_total",
+    "wall seconds the serving loop spent inside select, by mode (wait = "
+    "nothing ready, idle until an event; poll = callbacks ready, timeout 0: "
+    "the loop taking the interpreter lock back after the system call)",
+)
+EVENT_LOOP_TURN_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_turn_seconds_total",
+    "wall seconds of the serving loop between one select's return and the "
+    "next one's call (the callbacks and task steps of its turns); with "
+    "event_loop_select_seconds_total it is the loop's whole wall",
+)
+EVENT_LOOP_TURNS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_turns_total",
+    "turns of the serving loop (select calls)",
+)
+EVENT_LOOP_CPU_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_cpu_seconds_total",
+    "CPU seconds of the serving loop's thread (time.thread_time): over turn "
+    "+ select the share of its wall the loop ran",
+)
+# a lag-probe tick LOOP_STALL_SECONDS late or later is a stall, counted once
+# a loop whichever servers share it; beside it the kernel's account of the
+# same interval, each delta capped at the lateness (a source the host lacks
+# has no sample): steal = /proc/stat's steal column (all CPUs), throttled =
+# the cgroup's cpu.stat throttled_usec, runqueue = the loop thread's
+# run-queue delay (/proc/thread-self/schedstat)
+EVENT_LOOP_STALLS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_stalls_total",
+    "lag-probe ticks of the serving loop that ran 40 ms late or later, once "
+    "a loop (each is a loop.stall span in /debug/traces)",
+)
+EVENT_LOOP_STALL_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_stall_seconds_total",
+    "seconds those ticks ran late",
+)
+EVENT_LOOP_STALL_KERNEL_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_stall_kernel_seconds_total",
+    "the kernel's account of the stalls' intervals, by source (steal = "
+    "hypervisor steal of /proc/stat; throttled = the cgroup's CPU quota; "
+    "runqueue = the loop thread's run-queue delay), each capped at the "
+    "stall's lateness",
+)
+# the collector's pauses (gc.callbacks, util/trace.watch_gc): every thread
+# that needs the interpreter lock waits through one
+GC_PAUSE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_gc_pause_seconds_total",
+    "wall seconds inside garbage collections, by generation",
+)
+GC_COLLECTIONS = REGISTRY.counter(
+    "seaweedfs_tpu_gc_collections_total",
+    "garbage collections run, by generation",
+)
+# the fast tier's own work on the loop a request (util/fasthttp.py): slicing
+# requests out of what a socket delivered, and handing a full answer to the
+# transport; what the transport still buffers right after the write leaves
+# over later turns of the loop
+REQUEST_PARSE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_request_parse_seconds_total",
+    "seconds fast tiers spent slicing requests out of received bytes "
+    "(data_received -> parsed and queued), by server",
+)
+RESPONSE_WRITE_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_response_write_seconds_total",
+    "seconds fast tiers spent in transport.write of full answers, by "
+    "server; divide by response_writes_total",
+)
+RESPONSE_WRITES = REGISTRY.counter(
+    "seaweedfs_tpu_response_writes_total",
+    "full answers fast tiers wrote, by server",
+)
+RESPONSE_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_response_bytes_total",
+    "bytes of those answers, by server",
+)
+RESPONSE_BUFFERED_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_response_buffered_bytes_total",
+    "bytes the transport still buffered right after each of those writes "
+    "(what did not go out at once), by server",
+)
 # set once at start-up: where the seconds from process start to the first
 # served request go (imports/device/store_load/index_build/listening)
 STARTUP_SECONDS = REGISTRY.gauge(
@@ -490,13 +581,13 @@ READ_CACHE_EVICTIONS = REGISTRY.counter(
 # their sum can exceed the rebuild wall), degraded-read interval latency
 # split cold vs cache-served, and the decode-matrix LRU's hit rate
 # host-stage attribution of the codec's device path (util/trace.stage):
-# every TpuRSCodec call is pack -> put -> dispatch -> fetch -> unpack
+# every TpuRSCodec call is pack -> put -> dispatch -> fetch
 # (+ stack on a reconstruct), by op (encode/decode/apply). Stages run on
 # pool threads, so their sum can exceed the wall.
 RS_DISPATCH_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_rs_dispatch_seconds_total",
     "host seconds of a TpuRSCodec call, by op (encode/decode/apply) and "
-    "stage (stack/pack/put/dispatch/fetch/unpack)",
+    "stage (stack/pack/put/dispatch/fetch)",
 )
 RS_DISPATCHES = REGISTRY.counter(
     "seaweedfs_tpu_rs_dispatches_total",
@@ -548,15 +639,17 @@ EC_ENCODE_BATCH_FALLBACKS = REGISTRY.counter(
 # (the gathers of remote survivor fetches on the loop, plus the worker's
 # wall filling the decode's input rows: local survivors read, fetched ones
 # copied in), executor_wait (submit -> the worker's first line), decode (the
-# worker's wall around reconstruct_rows alone), cache_put; divide by
-# ec_reconstructions_total{kind="cold"}. Before any reconstruct, hit or
+# worker's wall around reconstruct_rows alone), loop_resume (the worker's
+# last line -> the coroutine's first line after the hop: the loop coming
+# round to it), cache_put; divide by ec_reconstructions_total{kind="cold"}. Before any reconstruct, hit or
 # cold: remote_attempts
 EC_DEGRADED_READ_STAGE_SECONDS = REGISTRY.counter(
     "seaweedfs_tpu_ec_degraded_read_stage_seconds_total",
     "degraded EC read stage wall seconds, by stage (remote_attempts = the "
     "TTL'd location refresh, and where a holder is listed the holders "
     "tried and the forced refreshes after them, before any reconstruct; of "
-    "a cold reconstruct: survivor_read/executor_wait/decode/cache_put; "
+    "a cold reconstruct: survivor_read/executor_wait/decode/loop_resume/"
+    "cache_put, which add up to ec_degraded_read_seconds{result=cold}; "
     "remote_read = each survivor fetched from another server, inside "
     "survivor_read: divide by ec_remote_shard_reads_total)",
 )
@@ -633,7 +726,8 @@ EC_READ_STAGE_SECONDS = REGISTRY.counter(
     "EC needle read wall seconds outside any reconstruct, by stage "
     "(local_interval = the synchronous pread of an interval on a local "
     "shard: divide by ec_read_intervals_total{source=\"local\"}; assemble = "
-    "join + parse + CRC of the needle: divide by ec_needle_reads_total)",
+    "join + parse + CRC of the needle, locate = the .ecx binary search: "
+    "divide both by ec_needle_reads_total)",
 )
 EC_RECONSTRUCT_SURVIVOR_BYTES = REGISTRY.counter(
     "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total",
@@ -649,6 +743,14 @@ EC_RECONSTRUCT_LOCAL_READS = REGISTRY.counter(
     "the read ran (worker = the executor thread that then decodes, straight "
     "into the decode's input array; loop = a thread that runs an event "
     "loop, where a read holds every other request up)",
+)
+# the worker thread of a cold reconstruct, round the whole of its work
+# (fill + decode): wall without cpu is the worker waiting (the interpreter
+# lock, the device, the disk); the rs.* and ec.read.pread leaves say where
+EC_DEGRADED_READ_WORKER_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_degraded_read_worker_seconds_total",
+    "seconds of the executor thread that fills and decodes a cold "
+    "reconstruct, by clock (wall = perf_counter, cpu = time.thread_time)",
 )
 EC_REBUILD_STAGE_SECONDS = REGISTRY.histogram(
     "seaweedfs_tpu_ec_rebuild_stage_seconds",

@@ -3,7 +3,9 @@
 
 Python equivalents of the Go pprof flags: cProfile stats files for the CPU
 profile, tracemalloc snapshots for the memory profile, and on-demand HTTP
-handlers (/debug/pprof/...) for a live server.
+handlers (/debug/pprof/...) for a live server; /debug/pprof/device takes a
+`jax.profiler` trace of the process that holds the chip, with the program's
+stages (util/trace.stage) on the clock the device plane is on.
 """
 
 from __future__ import annotations
@@ -11,6 +13,8 @@ from __future__ import annotations
 import cProfile
 import io
 import pstats
+import sys
+import tempfile
 from typing import Optional
 
 
@@ -178,3 +182,54 @@ async def handle_pprof_dump(request):
     else:
         text = profile_sorted_text(_live_profiler, limit)
     return web.Response(text=text, content_type="text/plain")
+
+
+_device_trace_running = False
+
+
+async def handle_pprof_device(request):
+    """GET /debug/pprof/device?seconds=S: a `jax.profiler` trace of this
+    process for S seconds (the device's plane and, on the `/host:CPU`
+    plane, every thread's `rs.*` / `ec.*` / `http.*` / `gc.*` stages),
+    written to a new directory under the temporary directory; the answer
+    names it. start_trace and stop_trace (which writes the `.xplane.pb`)
+    run off the loop. 404 in a process that never imported jax (a master,
+    a filer: no device to trace), 409 while a trace is being taken (the
+    profiler is process-global)."""
+    import asyncio
+
+    from aiohttp import web
+
+    global _device_trace_running
+    if "jax" not in sys.modules:
+        return web.json_response(
+            {"error": "this process never imported jax: no device plane"},
+            status=404,
+        )
+    try:
+        seconds = min(float(request.query.get("seconds", 5)), 120.0)
+    except ValueError:
+        return web.Response(status=400, text="bad seconds parameter\n")
+    if _device_trace_running:
+        return web.Response(status=409, text="device trace already running\n")
+    import jax
+
+    _device_trace_running = True
+    loop = asyncio.get_running_loop()
+    trace_dir = tempfile.mkdtemp(prefix="seaweedfs_tpu_device_trace_")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the Python tracer slows the loop
+    try:
+        await loop.run_in_executor(
+            None,
+            lambda: jax.profiler.start_trace(
+                trace_dir, profiler_options=options
+            ),
+        )
+        try:
+            await asyncio.sleep(seconds)
+        finally:
+            await loop.run_in_executor(None, jax.profiler.stop_trace)
+    finally:
+        _device_trace_running = False
+    return web.json_response({"dir": trace_dir, "seconds": seconds})

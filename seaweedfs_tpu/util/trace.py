@@ -301,14 +301,18 @@ class Recorder:
             )
         )
 
-    def promote_fault(self, name: str, kind: str, **tags) -> None:
+    def promote_fault(
+        self, name: str, kind: str, dur: float = 0.0, **tags
+    ) -> None:
         """Retro-record a root span for an untraced request that hit the
-        fault-injection seam (promotion even at sample=0)."""
+        fault-injection seam (promotion even at sample=0). `dur`: the
+        seconds up to now the span covers, where the fault has a length
+        (a stall of the serving loop)."""
         self.promoted_fault += 1
         ctx = SpanCtx(_new_trace_id(), _new_span_id(), True)
         self.record(
             _span_dict(
-                ctx, 0, name, time.time(), 0.0,
+                ctx, 0, name, time.time() - dur, dur,
                 dict(tags, promoted="fault", fault=kind), None, None,
             )
         )
@@ -713,6 +717,75 @@ class _StageCM:
 
 
 stage = Stage  # ``trace.stage(name, seconds_child, ...)`` at a site
+
+
+def mark(name: str, **tags) -> None:
+    """A point on the profiler's clock: an event entered and left at once,
+    for something that is known only when it is over (a stall of the loop).
+    Nothing in a process that never imported jax."""
+    trace_me = _TRACE_ME or _trace_me()
+    if trace_me is not None:
+        with trace_me(name, **tags):
+            pass
+
+
+# ------------------------------------------------------------ collections --
+
+
+class GcWatch:
+    """The one `gc.callbacks` hook of a process: a collection's wall into
+    `gc_pause_seconds_total{generation}`, its count beside it, and a
+    `gc.gen<N>` event from start to stop (same thread, synchronous), so a
+    collection under a device-idle gap names it. `seconds` is the sum over
+    all generations as a plain attribute: the stall recorder reads a
+    stall's share of it without a scrape."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._t0 = 0.0
+        self._event = None
+        self._children = [
+            (
+                _metrics.GC_PAUSE_SECONDS.child(generation=str(g)),
+                _metrics.GC_COLLECTIONS.child(generation=str(g)),
+            )
+            for g in range(3)
+        ]
+        for seconds, count in self._children:
+            # on /metrics before the first collection
+            seconds.inc(0.0)
+            count.inc(0.0)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            trace_me = _TRACE_ME or _trace_me()
+            if trace_me is not None:
+                self._event = trace_me("gc.gen%d" % info["generation"])
+                self._event.__enter__()
+            self._t0 = _perf()
+            return
+        dt = _perf() - self._t0
+        if self._event is not None:
+            self._event.__exit__(None, None, None)
+            self._event = None
+        self.seconds += dt
+        seconds, count = self._children[info["generation"]]
+        seconds.inc(dt)
+        count.inc()
+
+
+GC_WATCH: Optional[GcWatch] = None
+
+
+def watch_gc() -> GcWatch:
+    """Install the process's GcWatch, once; every server's start asks."""
+    global GC_WATCH
+    if GC_WATCH is None:
+        import gc
+
+        GC_WATCH = GcWatch()
+        gc.callbacks.append(GC_WATCH)
+    return GC_WATCH
 
 
 # exemplar hook: histograms ask for the live sampled trace id at observe

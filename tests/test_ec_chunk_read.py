@@ -13,6 +13,7 @@ the shards it wants lost and mounts them again. Everything runs on the CPU:
 counts, bytes and names are checked, never a time."""
 
 import asyncio
+import json
 import threading
 
 import aiohttp
@@ -37,7 +38,7 @@ from seaweedfs_tpu.util import trace
 from benchmarks.lib import common, metrics as layer_metrics
 from benchmarks.reference import ec_locate, rs_codec
 from test_cluster import Cluster, assign_retry, free_port_pair
-from test_stage_tracing import CHUNK_CELL, CHUNK_METRICS, moved, scrape
+from test_stage_tracing import CHUNK_CELL, CHUNK_METRICS, host_event_names, moved, scrape
 
 MB = 1 << 20
 GB = 1 << 30
@@ -488,13 +489,173 @@ def test_no_survivor_is_read_on_the_loops_thread(live, monkeypatch):
     assert moved(before, after, LOCAL_READS, where="loop") == 0
     # the cell's metric file, as a run evaluates it, and on a tree without the family
     spec = common.load("layer_metrics", "ec_read.worker_read_share.json")
-    entry = common.benchmark_json()["per_layer"][-1]
-    assert entry["name"] == spec["name"] and entry["workloads"] == GET_CELLS
+    entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == spec["name"])
+    assert entry["workloads"] == GET_CELLS
     for field in ("unit", "better", "source", "layer", "moves"):
         assert entry[field] == spec[field], field
     assert layer_metrics.Observed(before, after, {}, {}, {}, {}, None, None, {}).value(spec) == 100.0
     parents = [{k: v for k, v in page.items() if not k.startswith(LOCAL_READS)} for page in (before, after)]
     assert layer_metrics.Observed(*parents, {}, {}, {}, {}, None, None, {}).value(spec) is None
+
+
+# ------------------- ISSUE 37: the residues of a degraded GET, as stages
+DEGRADED_STAGES = "seaweedfs_tpu_ec_degraded_read_stage_seconds_total"
+COLD_SECONDS = "seaweedfs_tpu_ec_degraded_read_seconds_sum"
+WORKER_SECONDS = "seaweedfs_tpu_ec_degraded_read_worker_seconds_total"
+
+
+def a_chunk_on_shard_3(live) -> int:
+    return next(k for k, body in live.body.items() if len(body) == 4 * MB
+                and any(s == 3 for s, _o, _n in live.reference_intervals(k)))
+
+
+def test_a_cold_reconstructs_stages_add_up_to_its_histogram(live):
+    """`loop_resume` (the worker's last line to the coroutine's first after
+    the hop) is what the records subtracted as "the rest": with it the
+    stages of a cold reconstruct are its histogram's sum."""
+    key = a_chunk_on_shard_3(live)
+
+    async def read():
+        await live.lose([3, 11])
+        try:
+            before = scrape()
+            n = await live.vs.read_ec_needle(live.ev, key)
+            return bytes(n.data), before, scrape()
+        finally:
+            await live.mount([3, 11])
+
+    body, before, after = live.run(read())
+    assert body == live.body[key]
+    assert moved(before, after, COLD, kind="cold") == 1
+    stages = {
+        stage: moved(before, after, DEGRADED_STAGES, stage=stage)
+        for stage in ("survivor_read", "executor_wait", "decode", "loop_resume", "cache_put")
+    }
+    assert all(v > 0 for v in stages.values()), stages
+    whole = moved(before, after, COLD_SECONDS, result="cold")
+    assert abs(whole - sum(stages.values())) <= max(0.10 * whole, 0.0005), (whole, stages)
+    # the worker's wall is its two stages, and its CPU is inside its wall
+    wall = moved(before, after, WORKER_SECONDS, clock="wall")
+    cpu = moved(before, after, WORKER_SECONDS, clock="cpu")
+    assert wall >= stages["survivor_read"] + stages["decode"] > 0.9 * wall
+    assert 0 < cpu <= wall * 1.05 + 0.001
+
+
+def test_a_reconstructs_with_blocks_are_child_spans_of_a_sampled_get(live):
+    """Under a sampled request the stages that are `with` blocks are child
+    spans of the GET, the worker's too (it runs in the request's context);
+    the two waits that cross threads (`executor_wait`, `loop_resume`) are
+    counters alone."""
+    key = a_chunk_on_shard_3(live)
+    rec = trace.RECORDER
+
+    async def read():
+        await live.lose([3, 11])
+        rec.configure(enabled=True, sample=0.0)
+        try:
+            root = trace.begin_request("volume:GET", None, server="volume")
+            await live.vs.read_ec_needle(live.ev, key)
+            root.finish()
+            return rec.spans()
+        finally:
+            rec.configure()
+            await live.mount([3, 11])
+
+    spans = live.run(read())
+    root = next(s for s in spans if s["name"] == "volume:GET")
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    for name in ("ec.read.locate", "ec.read.survivor_read", "ec.read.decode",
+                 "ec.read.cache_put", "ec.read.assemble"):
+        assert len(by_name[name]) == 1, name
+        assert by_name[name][0]["parent"] == root["span"], name
+    assert len(by_name["ec.read.pread"]) == 10
+    assert "ec.read.loop_resume" not in by_name and "ec.read.executor_wait" not in by_name
+
+
+def test_parse_locate_and_write_are_once_a_get_and_in_a_profiler_trace(live, tmp_path):
+    """One GET on the public port: `http.parse`, `ec.read.locate` and
+    `http.write` each once, their counters moved, their events on the
+    loop's line of a profiler trace taken round it."""
+    import jax
+
+    key = a_chunk_on_shard_3(live)
+    volume = dict(server="volume")
+
+    async def get():
+        async with live.session.get(f"http://{live.vs.address}/{live.fid(key)}") as resp:
+            return resp.status, await resp.read()
+
+    assert live.run(get())[0] == 200  # the connection is open, the codec is warm
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    trace_dir = str(tmp_path / "trace")
+    before = scrape()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        status, body = live.run(get())
+    finally:
+        jax.profiler.stop_trace()
+    after = scrape()
+    assert status == 200 and body == live.body[key]
+    assert moved(before, after, "seaweedfs_tpu_response_writes_total", **volume) == 1
+    sent = moved(before, after, "seaweedfs_tpu_response_bytes_total", **volume)
+    assert 4 * MB < sent < 4 * MB + 1024  # the head and the body in one write
+    buffered = moved(before, after, "seaweedfs_tpu_response_buffered_bytes_total", **volume)
+    assert 0 <= buffered <= sent
+    assert moved(before, after, "seaweedfs_tpu_response_write_seconds_total", **volume) > 0
+    assert moved(before, after, "seaweedfs_tpu_request_parse_seconds_total", **volume) > 0
+    assert moved(before, after, STAGES, stage="locate") > 0
+    assert moved(before, after, NEEDLES) == 1
+    events = host_event_names(trace_dir)
+    for name in ("http.parse", "ec.read.locate", "http.write"):
+        assert events.count(name) == 1, (name, events.count(name))
+
+
+def test_debug_pprof_device_takes_a_trace_with_the_reads_stages_in_it(live, monkeypatch):
+    """`/debug/pprof/device?seconds=S` under the opt-in of the other pprof
+    pages: 403 without it; with it a `.xplane.pb` in the directory the
+    answer names, holding the `ec.*` events of a degraded read made
+    meanwhile; 409 for a second one while the first runs."""
+    import shutil
+
+    key = a_chunk_on_shard_3(live)
+    url = f"http://{live.vs.address}/debug/pprof/device"
+
+    async def refused():
+        async with live.session.get(url + "?seconds=0.1") as resp:
+            return resp.status
+
+    monkeypatch.delenv("SEAWEEDFS_TPU_PPROF", raising=False)
+    assert live.run(refused()) == 403
+    monkeypatch.setenv("SEAWEEDFS_TPU_PPROF", "1")
+
+    async def traced_read():
+        await live.lose([3, 11])
+        try:
+            async def ask(seconds):
+                async with live.session.get(f"{url}?seconds={seconds}") as resp:
+                    return resp.status, await resp.read()
+
+            first = asyncio.ensure_future(ask(1.0))
+            await asyncio.sleep(0.3)  # the trace is on
+            second = await ask(0.1)
+            n = await live.vs.read_ec_needle(live.ev, key)
+            return await first, second, bytes(n.data)
+        finally:
+            await live.mount([3, 11])
+
+    (status, answer), (second_status, _), body = live.run(traced_read())
+    assert status == 200 and second_status == 409
+    assert body == live.body[key]
+    trace_dir = json.loads(answer)["dir"]
+    try:
+        names = set(host_event_names(trace_dir))
+        # this server decodes on the host codec: the read's own stages
+        assert {"ec.read.pread", "ec.read.locate", "ec.read.cache_put"} <= names, sorted(names)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
 
 
 def test_a_decode_altered_as_the_benchmarks_control_alters_it_fails_the_crc(live, monkeypatch):

@@ -62,6 +62,18 @@ def small_encode(tmp_path, codec, name="v", size=(3 << 20) + 123):
     )
 
 
+def host_event_names(trace_dir: str) -> list:
+    """The name of every event on the host planes of a profiler trace."""
+    assert glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    return [
+        name
+        for plane, lines in trace_reduce.read_planes(trace_dir)
+        if plane.startswith("/host:")
+        for _line, events in lines
+        for name, _start, _dur in events
+    ]
+
+
 # ------------------------------------------------------------- the helper
 def _test_stage(name, **kw):
     seconds = m.REGISTRY.counter(
@@ -163,8 +175,10 @@ def test_encode_moves_every_pipeline_stage_and_every_rs_encode_stage(tmp_path):
     assert run.route["writers"] == enc._stream_writers(
         n_files, run.route["pipeline_depth"]
     )
-    for stage in ("pack", "put", "dispatch", "fetch", "unpack"):
+    for stage in ("pack", "put", "dispatch", "fetch"):
         assert moved(before, after, RS_SECONDS, op="encode", stage=stage) > 0, stage
+    # the view back to bytes is no stage since ISSUE 37: in no /metrics line
+    assert not [k for k in after if "unpack" in k]
     assert moved(before, after, RS_SECONDS, op="decode") == 0
     # 3 MiB + 123 B in 128 KiB blocks of 10: 3 rows, one dispatch each
     dispatches = moved(before, after, "seaweedfs_tpu_rs_dispatches_total",
@@ -287,7 +301,7 @@ def test_decode_bytes_real_and_padded_to_the_kernels_granule(width, padded_width
         padded_width - width
     )
     assert moved(before, after, "seaweedfs_tpu_rs_dispatches_total", **labels) == 1
-    for stage in ("stack", "pack", "put", "dispatch", "fetch", "unpack"):
+    for stage in ("stack", "pack", "put", "dispatch", "fetch"):
         assert moved(before, after, RS_SECONDS, op="decode", stage=stage) > 0, stage
 
 
@@ -330,9 +344,10 @@ def test_stages_are_events_of_a_profiler_trace_and_nothing_encloses_them(tmp_pat
         if plane.startswith("/host:") for _line, events in lines
     ]
     names = {name for events in host_lines for name, _s, _d in events}
-    for want in ("rs.fetch", "rs.pack", "rs.put", "rs.dispatch", "rs.unpack",
+    for want in ("rs.fetch", "rs.pack", "rs.put", "rs.dispatch",
                  "ec.encode.write", "ec.encode.read", "ec.encode.sync"):
         assert want in names, (want, sorted(names))
+    assert "rs.unpack" not in names  # out with ISSUE 37: nothing read it
     # waits are counters only, and nothing is drawn around the lot
     assert not {"ec.encode.slot_wait", "ec.encode.parity_wait",
                 "ec.encode.kernel", "ec.encode.sync_drain"} & names
@@ -421,6 +436,14 @@ CHUNK_METRICS = [
 # ISSUE 36's one, last: read in the three GET cells (tests/test_ec_chunk_read.py
 # and tests/test_ec_spread_read.py evaluate it)
 WORKER_READ_METRICS = ["ec_read.worker_read_share"]
+# ISSUE 37's ten, last: the loop's own clock, the residues of a GET as stages,
+# the stall recorder, the collector (tests/test_loop_clock.py evaluates them)
+LOOP_METRICS = [
+    "http.loop_cpu_share", "http.loop_held_share", "http.loop_turn_ms",
+    "http.write_ms", "ec_read.loop_resume_ms", "ec_read.locate_ms",
+    "ec_read.worker_cpu_share", "http.loop_stall_ms_per_s",
+    "http.stall_kernel_share", "http.gc_pause_ms_per_s",
+]
 ALL_NEW_METRICS = [
     (cell, name)
     for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS, PROXIED_METRICS)
@@ -586,7 +609,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     names = [e["name"] for e in common.benchmark_json()["per_layer"]]
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
-    new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS
+    new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS + LOOP_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
