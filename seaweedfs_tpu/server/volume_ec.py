@@ -146,11 +146,12 @@ _WORKER_WALL = EC_DEGRADED_READ_WORKER_SECONDS.child(clock="wall")
 _WORKER_CPU = EC_DEGRADED_READ_WORKER_SECONDS.child(clock="cpu")
 # a needle read whole, outside any reconstruct: each interval counted once
 # by what served it (_INTERVAL[source]), the needle once by kind, and the
-# three pieces of work the loop does itself: the .ecx binary search of
-# preads that locates the needle, the synchronous pread of an interval on a
-# local shard (up to a block: 1 MiB of a chunk needle) and the assembly
-# (join, parse, CRC over the whole record). All are annotated leaves; under
-# a sampled request each is a child span
+# three pieces of work the loop does itself: the .ecx binary search that
+# locates the needle (in the index's mapping: ec_index_lookups_total says
+# so), the synchronous pread of an interval on a local shard (up to a
+# block: 1 MiB of a chunk needle) and the assembly (join, parse, CRC over
+# the whole record). All are annotated leaves; under a sampled request each
+# is a child span
 INTERVAL_SOURCES = ("local", "cold_tier", "remote", "reconstructed", "cache")
 _INTERVAL = {
     source: EC_READ_INTERVALS.child(source=source) for source in INTERVAL_SOURCES

@@ -883,7 +883,15 @@ def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx") -> None:
     single owner of log-resolution the LSM map and the vacuum replay
     use), one serialized write — no per-entry Python dict on the way,
     so EC-encoding a multi-million-needle volume's index costs
-    milliseconds, not a dict build."""
+    milliseconds, not a dict build.
+
+    The file is replaced by rename, never truncated in place. An EcVolume
+    that is mounted meanwhile keeps the index it opened, mapping and
+    descriptor (the unlinked inode): it goes on serving the OLD index, and a
+    delete it acknowledges after this reaches that old file and the .ecj
+    only, not the new .ecx, until the volume is unmounted and mounted again
+    (the .ecj is what carries such a delete over: rebuild_ecx_file replays
+    it). Generate over a mounted EC volume, then remount."""
     from ..idx import NEEDLE_MAP_ENTRY_SIZE as _ENTRY  # noqa: N811
     from ..idx import entries_to_bytes, parse_index_bytes
     from ..needle_map.lsm_map import fold_live_columns
@@ -893,8 +901,16 @@ def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx") -> None:
     usable = len(data) - (len(data) % _ENTRY)
     keys, offs, sizes = parse_index_bytes(data[:usable])
     lk, lo, ls = fold_live_columns(keys, offs, sizes)
-    with open(base_file_name + ext, "wb") as f:
+    # by rename, never in place: a mounted EC volume holds a mapping of its
+    # .ecx, and a file truncated under a mapping kills the reader (SIGBUS).
+    # The .sdx goes the same way, though nothing maps it: one writer, one
+    # way, and a crash half way leaves the old .sdx whole where a write in
+    # place left a short one that SortedFileNeedleMap's mtime check took
+    # for fresh
+    tmp = base_file_name + ext + ".tmp"
+    with open(tmp, "wb") as f:
         f.write(entries_to_bytes(lk, lo, ls))
+    os.replace(tmp, base_file_name + ext)
 
 
 _REBUILD_HOST_ROUTE: Optional[str] = None

@@ -726,8 +726,18 @@ EC_READ_STAGE_SECONDS = REGISTRY.counter(
     "EC needle read wall seconds outside any reconstruct, by stage "
     "(local_interval = the synchronous pread of an interval on a local "
     "shard: divide by ec_read_intervals_total{source=\"local\"}; assemble = "
-    "join + parse + CRC of the needle, locate = the .ecx binary search: "
-    "divide both by ec_needle_reads_total)",
+    "join + parse + CRC of the needle, locate = the .ecx binary search, in "
+    "the index's mapping since PR 38: see ec_index_lookups_total; divide "
+    "both by ec_needle_reads_total)",
+)
+EC_INDEX_LOOKUPS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_index_lookups_total",
+    "searches of a mounted EC volume's .ecx for one needle "
+    "(EcVolume._locate_entry: reads, a delete's check and its own search, "
+    "the file_key check of VolumeEcShardRead), by what was searched (via: mapping = the mapping "
+    "of the index made at mount, no system call a probe; pread = a pread a "
+    "probe, where the filesystem refused a mapping at mount); an empty "
+    "index is searched by neither",
 )
 EC_RECONSTRUCT_SURVIVOR_BYTES = REGISTRY.counter(
     "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total",

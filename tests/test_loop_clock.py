@@ -87,6 +87,7 @@ def a_second_of(what: str) -> tuple:
 
             holder = threading.Thread(target=hold_the_lock, daemon=True)
             holder.start()
+        t_before = time.perf_counter()
         before, t0 = loop_reading(), time.perf_counter()
         try:
             if what == "idle":
@@ -95,11 +96,18 @@ def a_second_of(what: str) -> tuple:
                 await spin(0.5)
         finally:
             stop.set()
+        t_after = time.perf_counter()
         after, wall = loop_reading(), time.perf_counter() - t0
         if holder is not None:
             holder.join(10)
             assert not holder.is_alive()
-        return {k: after[k] - before[k] for k in after}, wall
+        d = {k: after[k] - before[k] for k in after}
+        # a reading publishes the clock somewhere inside its own wall (the
+        # render of the whole registry follows the publish): the deltas span
+        # no less than the wall between the readings and no more than the
+        # wall round both
+        d["wall_least"], d["wall_most"] = t_after - t0, wall + t0 - t_before
+        return d, wall
 
     return asyncio.run(body(), loop_factory=sc.new_event_loop)
 
@@ -122,7 +130,8 @@ def test_the_factory_makes_a_selector_loop_with_the_clock_for_selector():
 @pytest.mark.parametrize("what", ["idle", "spin", "held"])
 def test_turn_plus_select_is_the_loops_wall(what):
     d, wall = a_second_of(what)
-    assert d["turn"] + d["poll"] + d["wait"] == pytest.approx(wall, rel=0.02)
+    # to 2 % of the wall, whichever instant of its reading each publish fell on
+    assert 0.98 * d["wall_least"] <= d["turn"] + d["poll"] + d["wait"] <= 1.02 * d["wall_most"]
     assert d["turns"] >= 1
 
 

@@ -444,6 +444,9 @@ LOOP_METRICS = [
     "ec_read.worker_cpu_share", "http.loop_stall_ms_per_s",
     "http.stall_kernel_share", "http.gc_pause_ms_per_s",
 ]
+# ISSUE 38's one, last: read in the three GET cells (tests/test_ecx_mapping.py
+# evaluates it)
+MAPPING_METRICS = ["ec_read.mapped_locate_share"]
 ALL_NEW_METRICS = [
     (cell, name)
     for cells in (NEW_METRICS, WRITER_METRICS, NO_HOLDER_METRICS, PROXIED_METRICS)
@@ -610,6 +613,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
     new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS + LOOP_METRICS
+    new += MAPPING_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
