@@ -47,6 +47,8 @@ from ..util.metrics import (
     EC_RECONSTRUCT_SURVIVOR_BYTES,
     EC_RECONSTRUCTIONS,
     EC_REMOTE_ATTEMPTS,
+    EC_REMOTE_INTERVAL_BYTES,
+    EC_REMOTE_INTERVAL_SECONDS,
     EC_REMOTE_SHARD_READ_BYTES,
     EC_REMOTE_SHARD_READ_STREAMS,
     EC_REMOTE_SHARD_READS,
@@ -181,6 +183,18 @@ _ST_ASSEMBLE = trace.stage(
     EC_READ_STAGE_SECONDS.child(stage="assemble"),
     label="assemble",
 )
+# the intervals of a needle that are not on a local shard are asked for at
+# once (read_ec_needle_at): waits, so counters only. remote_interval is a
+# healthy interval a listed holder served, from the first ask to its bytes
+# (its count is ec_read_intervals_total{source="remote"}); fetch is a
+# needle's wall from its first such ask to its last arrival, fetch_interval
+# the walls of its intervals summed, so fetch_interval / fetch is how many
+# were in flight at once
+_ST_REMOTE_INTERVAL = trace.stage(
+    "ec.read.remote_interval", EC_REMOTE_INTERVAL_SECONDS, annotate=False,
+)
+_FETCH = EC_READ_STAGE_SECONDS.child(stage="fetch")
+_FETCH_INTERVAL = EC_READ_STAGE_SECONDS.child(stage="fetch_interval")
 
 
 def _count_remote_survivor(t0: float, data: Optional[bytes], size: int) -> None:
@@ -976,7 +990,7 @@ class EcHandlers:
         file_key: int, deadline: Optional[float], sent=None,
     ) -> bytes:
         stub = Stub(grpc_address(url), "volume")
-        buf = bytearray()
+        parts = []
         if sent is not None:
             sent.inc()
         async for msg in stub.server_stream(
@@ -994,8 +1008,11 @@ class EcHandlers:
                 raise IOError(msg["error"])
             if msg.get("is_deleted"):
                 raise EcHandlers._Deleted()
-            buf.extend(msg.get("data", b""))
-        return bytes(buf)
+            parts.append(msg.get("data", b""))
+        # a span up to 1 MiB is one message: the join hands that message's
+        # bytes through as they are (bytes.join of one bytes object returns
+        # it), and copies once where there are more
+        return b"".join(parts)
 
     async def _read_remote_shard_interval(
         self,
@@ -1137,7 +1154,8 @@ class EcHandlers:
         for shard_id, got in parts.items():
             whole = None not in got and sum(map(len, got)) == size
             if whole or deleted:
-                # one message a span up to 1 MiB: the join is that message
+                # one message a span up to 1 MiB: the join hands that
+                # message's bytes through, no copy
                 out[shard_id] = b"".join(got) if whole else None
                 _count_remote_survivor(t0, out[shard_id], size)
             else:
@@ -1210,6 +1228,7 @@ class EcHandlers:
             # a holder of this shard or of a survivor may have come back
             # since. What is left is the path of a stale list
         with _ST_REMOTE_ATTEMPTS():
+            t_ask = time.perf_counter()
             try:
                 data = await self._read_remote_shard_interval(
                     ev, shard_id, offset, size, file_key, deadline
@@ -1235,7 +1254,9 @@ class EcHandlers:
                 outcome="failed" if data is None else "served"
             )
             if data is not None:
+                _ST_REMOTE_INTERVAL.since(t_ask)
                 _INTERVAL["remote"].inc()
+                EC_REMOTE_INTERVAL_BYTES.inc(len(data))
                 return data
         # degraded: reconstruct from any DATA_SHARDS_COUNT other shards
         # (ref store_ec.go:319-373)
@@ -1642,24 +1663,38 @@ class EcHandlers:
         """Interval reads for an already-located needle (the bulk path hands
         in offsets from EcVolume.bulk_locate instead of re-searching). One
         deadline covers the WHOLE needle — retries on interval 1 shrink the
-        budget intervals 2..n may spend."""
+        budget intervals 2..n may spend. The intervals on local shards are
+        read in order, synchronously; those that are not (a listed holder,
+        the cold tier, a reconstruct) are asked for at once and joined in
+        order as they arrive (upstream's readEcShardIntervals awaits them
+        in turn: this changes when the bytes come, not which). A needle
+        whose every interval is local takes no other path than before."""
         # lifecycle heat: one EC needle read = one heat unit on whichever
         # server serves it (the master sums across holders)
         ev.heat.note_read()
-        intervals = ev.intervals_for(offset_units, size)
+        intervals = [
+            iv.to_shard_id_and_offset(EC_LARGE_BLOCK_SIZE, EC_SMALL_BLOCK_SIZE)
+            + (iv.size,)
+            for iv in ev.intervals_for(offset_units, size)
+        ]
         deadline = deadline_after(EC_READ_DEADLINE_SECONDS)
-        chunks = []
+        chunks: list = [None] * len(intervals)
         recovered: list[int] = []
-        for iv in intervals:
-            shard_id, shard_offset = iv.to_shard_id_and_offset(
-                EC_LARGE_BLOCK_SIZE, EC_SMALL_BLOCK_SIZE
-            )
+        far = []
+        for j, (shard_id, shard_offset, n) in enumerate(intervals):
+            if ev.find_shard(shard_id) is None:
+                far.append(j)
+                continue
             data = await self._read_one_ec_interval(
-                ev, shard_id, shard_offset, iv.size, key, deadline, recovered
+                ev, shard_id, shard_offset, n, key, deadline, recovered
             )
-            if data is None or len(data) != iv.size:
+            if data is None or len(data) != n:
                 return None
-            chunks.append(data)
+            chunks[j] = data
+        if far and not await self._fetch_intervals(
+            ev, key, intervals, far, chunks, deadline, recovered
+        ):
+            return None
         with _ST_ASSEMBLE() as st:
             blob = b"".join(chunks)
             st.tag("intervals", len(chunks))
@@ -1668,6 +1703,44 @@ class EcHandlers:
             n.read_bytes(blob, to_actual_offset(offset_units), size, ev.version)
         (_NEEDLE_DEGRADED if recovered else _NEEDLE_HEALTHY).inc()
         return n
+
+    async def _fetch_intervals(
+        self, ev: EcVolume, key: int, intervals: list, far: list,
+        chunks: list, deadline: float, recovered: list,
+    ) -> bool:
+        """The intervals `far` of a needle (indices into `intervals`, none
+        on a local shard), asked for at once under the needle's deadline,
+        each into its slot of `chunks`. False as soon as one comes back
+        without its bytes, the rest cancelled; an exception is raised as
+        from intervals read in turn."""
+        walls = 0.0
+
+        async def one(j: int) -> tuple:
+            shard_id, shard_offset, n = intervals[j]
+            t0 = time.perf_counter()
+            data = await self._read_one_ec_interval(
+                ev, shard_id, shard_offset, n, key, deadline, recovered
+            )
+            return j, data, time.perf_counter() - t0
+
+        t_first = time.perf_counter()
+        tasks = [asyncio.ensure_future(one(j)) for j in far]
+        try:
+            for arrival in asyncio.as_completed(tasks):
+                j, data, wall = await arrival
+                if data is None or len(data) != intervals[j][2]:
+                    return False
+                chunks[j] = data
+                walls += wall
+        finally:
+            for t in tasks:
+                if not t.done():
+                    t.cancel()
+                elif not t.cancelled():
+                    t.exception()  # retrieved: a second failure is not logged
+        _FETCH.inc(time.perf_counter() - t_first)
+        _FETCH_INTERVAL.inc(walls)
+        return True
 
     async def delete_ec_needle(self, ev: EcVolume, key: int) -> int:
         """Tombstone locally + fan out to every shard holder

@@ -728,7 +728,23 @@ EC_READ_STAGE_SECONDS = REGISTRY.counter(
     "shard: divide by ec_read_intervals_total{source=\"local\"}; assemble = "
     "join + parse + CRC of the needle, locate = the .ecx binary search, in "
     "the index's mapping since PR 38: see ec_index_lookups_total; divide "
-    "both by ec_needle_reads_total)",
+    "both by ec_needle_reads_total; fetch = the wall of a needle's "
+    "intervals that are not local, asked at once, from the first ask to "
+    "the last arrival, and fetch_interval = the walls of those intervals "
+    "summed: their ratio is how many were in flight at once)",
+)
+EC_REMOTE_INTERVAL_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_remote_interval_seconds_total",
+    "wall seconds of healthy needle intervals fetched from a listed holder "
+    "over VolumeEcShardRead, from the first ask to the bytes (retries and "
+    "forced refreshes included): divide by "
+    "ec_read_intervals_total{source=\"remote\"}",
+)
+EC_REMOTE_INTERVAL_BYTES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_remote_interval_bytes_total",
+    "bytes of healthy needle intervals fetched from a listed holder over "
+    "VolumeEcShardRead (ec_read_intervals_total{source=\"remote\"}); a "
+    "reconstruct's survivors are ec_remote_shard_read_bytes_total",
 )
 EC_INDEX_LOOKUPS = REGISTRY.counter(
     "seaweedfs_tpu_ec_index_lookups_total",

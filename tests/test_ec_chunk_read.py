@@ -38,7 +38,9 @@ from seaweedfs_tpu.util import trace
 from benchmarks.lib import common, metrics as layer_metrics
 from benchmarks.reference import ec_locate, rs_codec
 from test_cluster import Cluster, assign_retry, free_port_pair
-from test_stage_tracing import CHUNK_CELL, CHUNK_METRICS, host_event_names, moved, scrape
+from test_stage_tracing import (
+    CHUNK_CELL, CHUNK_METRICS, CHUNK_SPREAD_CELL, host_event_names, moved, scrape,
+)
 
 MB = 1 << 20
 GB = 1 << 30
@@ -51,7 +53,8 @@ SURVIVOR_BYTES = "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total"
 COLD = "seaweedfs_tpu_ec_reconstructions_total"
 LOCAL_READS = "seaweedfs_tpu_ec_reconstruct_local_reads_total"
 RS_SECONDS = "seaweedfs_tpu_rs_dispatch_seconds_total"
-GET_CELLS = ["warm-rs10.4.degraded-get-c16", "warm-rs10.4-spread4.server-lost-get-c16", CHUNK_CELL]
+GET_CELLS = ["warm-rs10.4.degraded-get-c16", "warm-rs10.4-spread4.server-lost-get-c16", CHUNK_CELL,
+             CHUNK_SPREAD_CELL]
 PROXIED = "seaweedfs_tpu_request_proxied_total"
 GRANULE = DEFAULT_BLOCK_ROWS * LANE * 4  # bytes a row the Pallas kernel pads to
 
@@ -710,7 +713,7 @@ def test_each_chunk_metric_file_reads_what_the_program_wrote(live, name):
     _got, before, after = live.run(live.read_all_with([3, 11]))
     spec = common.load("layer_metrics", name + ".json")
     entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == name)
-    assert entry["workloads"] == [CHUNK_CELL]
+    assert entry["workloads"] == [CHUNK_CELL, CHUNK_SPREAD_CELL]
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == spec[key], key
     client = {"gets_good": len(live.body), "window_s": 2.0,

@@ -65,8 +65,10 @@ class Spread:
     """Four volume servers, one volume of 16 needles over every data shard,
     `ec.encode` through the shell, then `lose` holders stopped."""
 
-    def __init__(self, tmp_path, lose: int):
+    def __init__(self, tmp_path, lose: int, sizes=None):
+        # `sizes`: {key: body bytes}; by default 16 needles of 0.7 MB
         self.tmp_path, self.lose = tmp_path, lose
+        self.sizes = sizes or {key: 700_000 + 1_001 * key for key in range(1, NEEDLES + 1)}
         self.loop = asyncio.new_event_loop()
         self.thread = threading.Thread(target=self.loop.run_forever, daemon=True)
         self.thread.start()
@@ -108,7 +110,7 @@ class Spread:
         self.vid = int(ar.fid.split(",")[0])
         self.source = self.cluster.server_for(ar.url)
         rng = random.Random(32)
-        self.body = {key: rng.randbytes(700_000 + 1_001 * key) for key in range(1, NEEDLES + 1)}
+        self.body = {key: rng.randbytes(size) for key, size in self.sizes.items()}
         for key, data in self.body.items():
             await upload_data(self.session, ar.url, self.fid(key), data)
         base = os.path.join(self.source.store.locations[0].directory, str(self.vid))

@@ -39,7 +39,7 @@ from seaweedfs_tpu.types import (
 )
 
 from benchmarks.lib import common, metrics as layer_metrics
-from test_stage_tracing import CHUNK_CELL, SPREAD_CELL, moved, scrape
+from test_stage_tracing import CHUNK_CELL, CHUNK_SPREAD_CELL, SPREAD_CELL, moved, scrape
 
 LOOKUPS = "seaweedfs_tpu_ec_index_lookups_total"
 VID = 7
@@ -221,9 +221,9 @@ def test_the_cells_metric_file_reads_the_family_as_a_run_does(served):
     served.ev.find_needle_from_ecx(6)
     after = scrape()
     spec = common.load("layer_metrics", "ec_read.mapped_locate_share.json")
-    entry = common.benchmark_json()["per_layer"][-1]
-    assert entry["name"] == spec["name"]
-    assert entry["workloads"] == ["warm-rs10.4.degraded-get-c16", SPREAD_CELL, CHUNK_CELL]
+    entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == spec["name"])
+    assert entry["workloads"] == ["warm-rs10.4.degraded-get-c16", SPREAD_CELL, CHUNK_CELL,
+                                  CHUNK_SPREAD_CELL]
     for field in ("unit", "better", "source", "layer", "moves"):
         assert entry[field] == spec[field], field
     assert layer_metrics.Observed(before, after, {}, {}, {}, {}, None, None, {}).value(spec) == 100.0

@@ -27,6 +27,7 @@ GET_CELLS = [
     "warm-rs10.4.degraded-get-c16",
     "warm-rs10.4-spread4.server-lost-get-c16",
     "warm-rs10.4-filer4m.degraded-chunk-get-c16",
+    "warm-rs10.4-filer4m-spread4.server-lost-chunk-get-c16",
 ]
 DEADLINE_S = 30
 

@@ -433,6 +433,14 @@ CHUNK_METRICS = [
     "ec_read.assemble_ms", "ec_read.survivor_mb_per_reconstruct",
     "rs_decode_roofline",
 ]
+# ISSUE 40's: the chunk cell on the spread cluster reads every GET metric
+# (its name is appended to their lists, after the chunk cell's), and four of
+# its own come last in BENCHMARK.json (tests/test_ec_chunk_spread.py reads them)
+CHUNK_SPREAD_CELL = "warm-rs10.4-filer4m-spread4.server-lost-chunk-get-c16"
+CHUNK_SPREAD_METRICS = [
+    "ec_read.remote_interval_ms", "ec_read.remote_interval_mb_per_get",
+    "ec_read.interval_overlap", "peers.cpu_ms_per_mb",
+]
 # ISSUE 36's one, last: read in the three GET cells (tests/test_ec_chunk_read.py
 # and tests/test_ec_spread_read.py evaluate it)
 WORKER_READ_METRICS = ["ec_read.worker_read_share"]
@@ -571,7 +579,8 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
     before, after, _wrote, _got = degraded_get
     spec = common.load("layer_metrics", name + ".json")
     entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == name)
-    also = [BATCH_CELL] if cell == "warm-rs10.4.ec-encode" else [SPREAD_CELL, CHUNK_CELL]
+    also = ([BATCH_CELL] if cell == "warm-rs10.4.ec-encode"
+            else [SPREAD_CELL, CHUNK_CELL, CHUNK_SPREAD_CELL])
     assert entry["workloads"] == [cell] + also
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == spec[key], key
@@ -613,16 +622,21 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
     new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS + LOOP_METRICS
-    new += MAPPING_METRICS
+    new += MAPPING_METRICS + CHUNK_SPREAD_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
-        assert per_layer[name]["workloads"] == [SPREAD_CELL]
+        assert per_layer[name]["workloads"] == [SPREAD_CELL, CHUNK_SPREAD_CELL]
         spec = common.load("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
     for name in CHUNK_METRICS:  # read what the chunk deployment adds to the program
-        assert per_layer[name]["workloads"] == [CHUNK_CELL]
+        assert per_layer[name]["workloads"] == [CHUNK_CELL, CHUNK_SPREAD_CELL]
+        spec = common.load("layer_metrics", name + ".json")
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert per_layer[name][key] == spec[key], (name, key)
+    for name in CHUNK_SPREAD_METRICS:  # what only a served interval or a peer's MB shows
+        assert per_layer[name]["workloads"] == [CHUNK_SPREAD_CELL]
         spec = common.load("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
