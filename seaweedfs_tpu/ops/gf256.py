@@ -24,6 +24,19 @@ planes by a static 32x80 bit matrix via int8 dot_general) was prototyped and
 is byte-correct but lands at the same ~63 GB/s: the bit unpack/repack is VPU
 work of the same magnitude as the xtime chains, so the VPU remains the
 bottleneck either way. Kept the packed formulation (simpler, no MXU).
+
+Dispatch: a served call (gf_matmul_bytes) is ONE device program. The host
+packs the rows to uint32[C, W] with W a whole number of the kernel's blocks
+and hands the jitted kernel a view of them as uint32[C, W/128, 128], its own
+input shape, which it uploads within the same call (a separate device_put
+first was one more call into the runtime, and slower on the chip): a 3-D
+array whose second axis is a multiple of 8 has a tiled layout that is
+exactly row-major, so the upload is a straight copy, where a 2-D [10, W] one
+has its rows padded to 16 and shuffled into 8-row tiles, and needs a copy
+and a slice on the device to become the kernel's input. The [R, W/128, 128]
+result comes back as it is and is viewed as R rows of bytes on the host: no
+reshape, pad, copy or slice program round the kernel, each of which would
+be a launch (and a hand-over of the interpreter lock) of its own.
 """
 
 from __future__ import annotations
@@ -193,8 +206,10 @@ def pack_bytes_host(data: np.ndarray, granule: int = 4) -> np.ndarray:
 
 
 def unpack_bytes_host(packed: np.ndarray, n: int) -> np.ndarray:
-    """Host-side free unpacking: uint32[R, m] -> uint8[R, n]."""
-    return np.ascontiguousarray(packed).view(np.uint8)[:, :n]
+    """Host-side free unpacking: uint32[R, m] (or the kernel's
+    [R, S, LANE]) -> uint8[R, n], a view of the words."""
+    packed = np.ascontiguousarray(packed)
+    return packed.reshape(packed.shape[0], -1).view(np.uint8)[:, :n]
 
 
 def gf_matmul_packed(
@@ -209,34 +224,26 @@ def gf_matmul_packed(
 
     The native device API — keeps data uint32 end-to-end (the kernel is
     HBM-bound at this layout; measured ~450 GB/s data throughput on v5e).
-    W must be a multiple of (block_rows * LANE) for the Pallas path; the
-    jnp path takes any W.
+    For words already on the device (tests, bench.py); a served call goes
+    through gf_matmul_bytes. The Pallas path pads W to a whole number of
+    blocks and reshapes on the device, each a program of its own; the jnp
+    path takes any W.
     """
     matrix = np.asarray(matrix, dtype=np.uint8)
     packed = jnp.asarray(packed, dtype=jnp.uint32)
     assert packed.shape[0] == matrix.shape[1], (packed.shape, matrix.shape)
+    key = tuple(map(tuple, matrix))
     use_pallas = force_pallas if force_pallas is not None else on_tpu()
-    return _matmul_packed_on_device(
-        tuple(map(tuple, matrix)), packed, block_rows, use_pallas,
-        interpret, xtime_mode,
-    )
-
-
-def _matmul_packed_on_device(
-    key, packed, block_rows: int, use_pallas: bool, interpret: bool,
-    xtime_mode: str | None = None,
-):
-    """The dispatches of one call, as issued from the host, on words that
-    are already on the device: pad / reshape / kernel / reshape / slice."""
-    w = packed.shape[1]
     if not use_pallas and not interpret:
         return _gf_matmul_jnp_packed(key, packed, xtime_mode)
+    w = packed.shape[1]
     granule = block_rows * LANE
     if w % granule:
-        pad = granule - w % granule
-        packed = jnp.pad(packed, ((0, 0), (0, pad)))
-    packed3d = packed.reshape(packed.shape[0], -1, LANE)
-    out = _gf_matmul_pallas(key, packed3d, block_rows, interpret, xtime_mode)
+        packed = jnp.pad(packed, ((0, 0), (0, granule - w % granule)))
+    out = _gf_matmul_pallas(
+        key, packed.reshape(packed.shape[0], -1, LANE), block_rows,
+        interpret, xtime_mode,
+    )
     out = out.reshape(out.shape[0], -1)
     return out if out.shape[1] == w else out[:, :w]
 
@@ -309,6 +316,14 @@ def gf_matmul_bytes(
     compiled shapes instead of each compiling its own device-side pad and
     slice.
 
+    One device program a call: for the Pallas kernel the packed
+    uint32[C, W] are viewed as its own input, uint32[C, W/128, 128], and
+    that host view is handed to the jitted kernel, which uploads it within
+    the one call (`rs.dispatch` is upload + launch; the module note says
+    why that upload is a straight copy); its [R, W/128, 128] result is
+    fetched and viewed as uint8[R, N] on the host. The jnp path uploads
+    (`rs.put`) and returns [C, W] / [R, W] words, one program too.
+
     `width`: the caller's bytes a row where `data`'s rows may be wider,
     because the caller filled the rows of an array of its own. Where that
     array is a whole number of row_granule() wide it is uploaded as it is
@@ -327,27 +342,31 @@ def gf_matmul_bytes(
     granule = row_granule(block_rows, force_pallas, interpret)
     if width is not None and data.shape[1] % granule:
         data = data[:, :width]
+    key = tuple(map(tuple, matrix))
     stages = RS_STAGE[op]
     with stages["pack"]():
         packed = pack_bytes_host(
             np.asarray(data).astype(np.uint8, copy=False), granule
         )
-    with stages["put"]():
-        on_device = jnp.asarray(packed, dtype=jnp.uint32)
-    with stages["dispatch"]():
-        out = _matmul_packed_on_device(
-            tuple(map(tuple, matrix)), on_device, block_rows, use_pallas,
-            interpret,
-        )
+    if use_pallas or interpret:
+        # a whole number of blocks a row (the granule): a free view, which
+        # the jitted kernel uploads itself, inside the one call
+        words = packed.reshape(packed.shape[0], -1, LANE)
+        with stages["dispatch"]():
+            out = _gf_matmul_pallas(key, words, block_rows, interpret, None)
+    else:
+        with stages["put"]():
+            words = jnp.asarray(packed, dtype=jnp.uint32)
+        with stages["dispatch"]():
+            out = _gf_matmul_jnp_packed(key, words, None)
     with stages["fetch"]():
         out = np.asarray(out)
-    out = unpack_bytes_host(out, n)
     count_rs_dispatch(
         op,
         "device" if use_pallas and on_tpu() else "device_emulated",
         matrix.shape[0] + matrix.shape[1], n, packed.shape[1] * 4,
     )
-    return out
+    return unpack_bytes_host(out, n)
 
 
 # --- MXU bit-slice prototype (VERDICT r4 item 5) ---

@@ -32,6 +32,75 @@ def test_gf_matmul_pallas_interpret_matches():
     assert np.array_equal(got, want)
 
 
+@pytest.mark.parametrize(
+    "n", [16 << 10, 128 << 10, 256 << 10, 1 << 20, 100_001],
+    ids=["16k", "128k", "256k", "1m", "ragged"],
+)
+def test_every_codec_call_through_the_kernel_is_the_oracles(n):
+    """encode, reconstruct_rows (survivors as separate arrays, and as rows
+    a caller filled into an array a whole number of the kernel's granule
+    wide, with garbage past the span) and apply_matrix, through the Pallas
+    kernel in interpret mode: byte for byte the numpy codec's."""
+    cpu = CpuRSCodec()
+    tpu = TpuRSCodec(force_pallas=True, interpret=True)
+    rng = np.random.default_rng(n)
+    data = rng.integers(0, 256, size=(10, n), dtype=np.uint8)
+    shards = cpu.encode_all(data)
+    assert np.array_equal(tpu.encode(data), shards[10:])
+
+    lost = (3, 11)
+    partial = [None if i in lost else shards[i] for i in range(14)]
+    for i, row in zip(lost, tpu.reconstruct_rows(partial, list(lost))):
+        assert np.array_equal(row, shards[i]), i
+
+    granule = tpu.row_granule()
+    wide = -(-n // granule) * granule
+    filled = rng.integers(0, 256, size=(10, wide), dtype=np.uint8)
+    survivors = [i for i in range(14) if i not in lost][:10]
+    for j, i in enumerate(survivors):
+        filled[j, :n] = shards[i]
+    given = [None] * 14
+    for j, i in enumerate(survivors):
+        given[i] = filled[j, :n]
+    for i, row in zip(lost, tpu.reconstruct_rows(given, list(lost))):
+        assert np.array_equal(row, shards[i]), i
+
+    rebuild = cpu.matrix[list(lost)]
+    assert np.array_equal(tpu.apply_matrix(rebuild, data), shards[list(lost)])
+
+
+def test_the_words_go_up_as_a_view_and_come_back_as_one(monkeypatch):
+    """The jitted kernel is handed the packed words as a [C, S, 128] view
+    of the caller's granule-wide rows (nothing copied on the host) and
+    uploads them itself; it is the one program launched, and the rows
+    returned are a view of its fetched result."""
+    from seaweedfs_tpu.ops import gf256
+
+    seen = {"given": []}
+    kernel = gf256._gf_matmul_pallas
+
+    def spy(key, words, *args):
+        seen["given"].append(words)
+        seen["out"] = kernel(key, words, *args)
+        return seen["out"]
+
+    cpu = CpuRSCodec()
+    data = np.random.default_rng(5).integers(
+        0, 256, size=(10, 2 * gf256.row_granule(interpret=True)), dtype=np.uint8
+    )
+    monkeypatch.setattr(gf256, "_gf_matmul_pallas", spy)
+    got = gf256.gf_matmul_bytes(cpu.parity_matrix, data, interpret=True)
+    assert np.array_equal(got, cpu.encode(data))
+    [words] = seen["given"]
+    assert isinstance(words, np.ndarray) and words.shape == (
+        10, data.shape[1] // 4 // gf256.LANE, gf256.LANE
+    )
+    assert np.shares_memory(words, data)
+    fetched = np.asarray(seen["out"])
+    assert fetched.shape == (4,) + words.shape[1:]
+    assert np.shares_memory(got, fetched)
+
+
 @pytest.mark.parametrize("k,m", [(10, 4), (6, 3), (12, 4)])
 def test_tpu_codec_matches_cpu(k, m):
     cpu = CpuRSCodec(k, m)

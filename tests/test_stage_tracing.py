@@ -301,8 +301,10 @@ def test_decode_bytes_real_and_padded_to_the_kernels_granule(width, padded_width
         padded_width - width
     )
     assert moved(before, after, "seaweedfs_tpu_rs_dispatches_total", **labels) == 1
-    for stage in ("stack", "pack", "put", "dispatch", "fetch"):
+    for stage in ("stack", "pack", "dispatch", "fetch"):
         assert moved(before, after, RS_SECONDS, op="decode", stage=stage) > 0, stage
+    # the kernel is handed the host view and uploads it inside rs.dispatch
+    assert moved(before, after, RS_SECONDS, op="decode", stage="put") == 0
 
 
 def test_the_host_standin_counts_under_its_own_backend():
@@ -461,6 +463,10 @@ ALL_NEW_METRICS = [
     for cell, names in cells.items()
     for name in names
 ]
+# last in BENCHMARK.json, after the chunk-spread cell's four: the chip's
+# operations a codec call, read off the device trace in the four GET cells
+DEVICE_OPS_METRICS = ["rs_dispatch.device_ops_per_call"]
+GET_CELLS = ["warm-rs10.4.degraded-get-c16", SPREAD_CELL, CHUNK_CELL, CHUNK_SPREAD_CELL]
 
 
 async def _encode_lose_a_shard_and_get(tmp_path) -> tuple:
@@ -622,7 +628,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
     new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS + LOOP_METRICS
-    new += MAPPING_METRICS + CHUNK_SPREAD_METRICS
+    new += MAPPING_METRICS + CHUNK_SPREAD_METRICS + DEVICE_OPS_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
@@ -640,4 +646,52 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
         spec = common.load("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
+    for name in DEVICE_OPS_METRICS:
+        assert per_layer[name]["workloads"] == GET_CELLS
+        spec = common.load("layer_metrics", name + ".json")
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert per_layer[name][key] == spec[key], (name, key)
     assert len(json.dumps(common.benchmark_json())) < 64 << 10
+
+
+# the operations a traced degraded-get-c16 run showed on the chip for each
+# decode of a 128 KiB span (a [10, 512, 128] granule): with the words
+# uploaded as a 2-D [10, W] array, the relayout into the kernel's input and
+# the reshape of its answer ran beside the kernel; uploaded in the kernel's
+# own shape, the kernel runs alone
+KERNEL_OP = ("%_gf_matmul_pallas.1 = u32[1,512,128]{2,1,0:T(8,128)} custom-call("
+             "u32[10,512,128]{2,1,0:T(8,128)} %packed3d.1), "
+             'custom_call_target="tpu_custom_call"')
+RELAYOUT_OPS = [
+    "%copy = u32[2,8,512,128]{3,2,1,0:T(8,128)S(1)} copy("
+    "u32[2,8,512,128]{3,1,2,0:T(8,128)} %bitcast.4)",
+    "%slice = u32[10,512,128]{2,1,0:T(8,128)} slice("
+    "u32[16,512,128]{2,1,0:T(8,128)S(1)} %bitcast.3)",
+    "%copy.1 = u32[1,65536]{1,0:T(1,128)} copy(u32[1,65536]{1,0:T(1,128)} %bitcast.1)",
+]
+
+
+@pytest.mark.parametrize("glue,want", [(RELAYOUT_OPS, 4.0), ([], 1.0)],
+                         ids=["relayout_beside_the_kernel", "the_kernel_alone"])
+def test_the_device_ops_a_call_are_read_off_the_trace(glue, want):
+    """`rs_dispatch.device_ops_per_call` counts what the chip ran, so any
+    operation that comes back round the kernel shows: three decodes on the
+    device plane, with and without the relayout; no trace, no reading."""
+    [name] = DEVICE_OPS_METRICS
+    spec = common.load("layer_metrics", name + ".json")
+    events, t = [], 0
+    for _call in range(3):
+        for op in [KERNEL_OP] + glue:
+            events.append((op, t, 10_000))
+            t += 20_000
+        t += 5_000_000
+    planes = [
+        ("/device:TPU:0", [("XLA Ops", events)]),
+        ("/host:CPU", [("python", [("rs.dispatch", 0, 1_000)])]),
+    ]
+    reduced = trace_reduce.reduce_planes(planes)
+    seen = layer_metrics.Observed({}, {}, {}, {}, {}, {}, reduced, None, {})
+    assert seen.value(spec) == want
+    kernel_only = common.load("layer_metrics", "rs_decode_kernel_us.json")
+    assert seen.value(kernel_only) == pytest.approx(10.0)  # the same kernel, its own time
+    assert layer_metrics.Observed({}, {}, {}, {}, {}, {}, None, None, {}).value(spec) is None
