@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import gc
 import os
 import sys
 
@@ -212,6 +213,12 @@ def _build_volume_server(args, port_offset: int = 0):
 async def _run_forever(*servers) -> None:
     for s in servers:
         await s.start()
+    # what start-up left alive (modules, jax, the servers' own graphs)
+    # lives as long as the process: out of the collector's reach, a
+    # generation-2 collection walks only what came later, and does not
+    # hold the loop and every thread tens of ms (PERF.md section 6)
+    gc.collect()
+    gc.freeze()
     stop = asyncio.Event()
     try:
         await stop.wait()

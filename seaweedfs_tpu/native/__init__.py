@@ -86,7 +86,7 @@ def _build_and_open(max_tier: str = "best"):
             except (subprocess.SubprocessError, OSError):
                 continue
         try:
-            return ctypes.CDLL(lib), _tier_name(flags)
+            return ctypes.CDLL(lib, use_errno=True), _tier_name(flags)
         except OSError:
             continue
     return None, "numpy"
@@ -124,6 +124,16 @@ def load() -> Optional[ctypes.CDLL]:
             ]
             lib.gf_encode_copy.restype = ctypes.c_int
         except AttributeError:  # stale .so without the symbol
+            pass
+        try:
+            lib.write_all.argtypes = [
+                ctypes.c_int,  # n
+                ctypes.POINTER(ctypes.c_int),  # fds
+                ctypes.POINTER(ctypes.c_void_p),  # buffers
+                ctypes.POINTER(ctypes.c_size_t),  # lengths
+            ]
+            lib.write_all.restype = ctypes.c_long
+        except AttributeError:
             pass
         _lib, _tier = lib, tier
         return _lib
@@ -270,3 +280,27 @@ def _matmul_rows(
         matrix.ctypes.data_as(u8p), rows, cols, data_ptrs, out_ptrs, n
     )
     return out
+
+
+def write_all(files, rows) -> None:
+    """Append each of `rows` (1-D uint8 arrays, C-contiguous) whole to
+    the file object beside it, in one native call that gives the
+    interpreter lock up once for all of them; without the library, one
+    `write` a row. The files are written through their descriptors, so
+    their Python buffers must hold nothing: every write to them comes
+    here."""
+    lib = load()
+    if lib is None or not hasattr(lib, "write_all"):
+        for f, row in zip(files, rows):
+            f.write(row.data)
+        return
+    n = len(rows)
+    rc = lib.write_all(
+        n,
+        (ctypes.c_int * n)(*(f.fileno() for f in files)),
+        (ctypes.c_void_p * n)(*(row.ctypes.data for row in rows)),
+        (ctypes.c_size_t * n)(*(row.nbytes for row in rows)),
+    )
+    if rc < 0:
+        err = ctypes.get_errno()
+        raise OSError(err, os.strerror(err), files[-rc - 1].name)

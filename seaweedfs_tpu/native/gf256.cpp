@@ -24,9 +24,11 @@
 // broadcasting the 16-entry nibble tables to both lanes gives the
 // identical algorithm at 32 B/op)
 
+#include <cerrno>
 #include <cstddef>
 #include <cstdint>
 #include <cstring>
+#include <unistd.h>
 
 #if defined(__AVX2__) || defined(__GFNI__)
 #include <immintrin.h>
@@ -326,4 +328,28 @@ void gf_mul_add(uint8_t c, const uint8_t* src, uint8_t* out, size_t n) {
 }
 
 uint8_t gf_mul(uint8_t a, uint8_t b) { return gf_mul_scalar(a, b); }
+
+// Append each of n buffers whole to its own file descriptor, in order, in
+// ONE call: a caller through ctypes gives the interpreter lock up once
+// for all n writes, not once a write, so a shard writer that other
+// threads keep off the lock waits for it once a chunk (encoder.py
+// _encode_streamed). Returns 0, or -(i + 1) for the first buffer whose
+// write failed, with errno as the write left it.
+long write_all(int n, const int* fds, const void* const* bufs,
+               const size_t* lens) {
+  for (int i = 0; i < n; i++) {
+    const char* p = static_cast<const char*>(bufs[i]);
+    size_t left = lens[i];
+    while (left > 0) {
+      ssize_t got = write(fds[i], p, left);
+      if (got < 0) {
+        if (errno == EINTR) continue;
+        return -(long)(i + 1);
+      }
+      p += got;
+      left -= (size_t)got;
+    }
+  }
+  return 0;
+}
 }

@@ -56,6 +56,7 @@ from ..util.metrics import (
     RS_DISPATCH_BYTES,
     RS_DISPATCH_SECONDS,
     RS_DISPATCHES,
+    RS_DISPATCHES_BESIDE,
 )
 
 # x^8 + x^4 + x^3 + x^2 + 1 (0x11D), matching the galois tables (galois.py).
@@ -274,6 +275,25 @@ def _rs_count_children(op: str, backend: str) -> tuple:
     )
 
 
+# the codec calls in flight, each from its dispatch to the end of its
+# fetch: token -> (op, the ops of the other calls it met). A call enters
+# itself, then marks itself and every call it finds with each other's op,
+# so of two calls in flight at once the later one marks both. Each step is
+# one dict or set operation under the interpreter lock: no lock of its own
+_IN_FLIGHT: dict = {}
+
+
+@functools.lru_cache(maxsize=None)
+def _beside_child(op: str, beside: str):
+    return RS_DISPATCHES_BESIDE.child(op=op, beside=beside)
+
+
+def _count_beside(op: str, met: set) -> None:
+    """`rs_dispatches_beside_total`: another op first, else the call's own."""
+    beside = next((o for o in RS_OPS if o != op and o in met), None)
+    _beside_child(op, beside or (op if op in met else "none")).inc()
+
+
 def count_rs_dispatch(
     op: str, backend: str, rows: int, width: int, padded_width: int
 ) -> None:
@@ -333,7 +353,9 @@ def gf_matmul_bytes(
     `width` and padded like any.
 
     `op` (encode/decode/apply) labels the call's stages, dispatch and bytes
-    on /metrics (RS_STAGE, count_rs_dispatch).
+    on /metrics (RS_STAGE, count_rs_dispatch), and which other op's call
+    it met between its dispatch and the end of its fetch (_IN_FLIGHT,
+    `rs_dispatches_beside_total`).
     """
     matrix = np.asarray(matrix, dtype=np.uint8)
     assert data.shape[0] == matrix.shape[1], (data.shape, matrix.shape)
@@ -352,15 +374,26 @@ def gf_matmul_bytes(
         # a whole number of blocks a row (the granule): a free view, which
         # the jitted kernel uploads itself, inside the one call
         words = packed.reshape(packed.shape[0], -1, LANE)
-        with stages["dispatch"]():
-            out = _gf_matmul_pallas(key, words, block_rows, interpret, None)
     else:
         with stages["put"]():
             words = jnp.asarray(packed, dtype=jnp.uint32)
+    token, met = object(), set()
+    _IN_FLIGHT[token] = (op, met)
+    try:
+        for other, other_met in list(_IN_FLIGHT.values()):
+            if other_met is not met:
+                met.add(other)
+                other_met.add(op)
         with stages["dispatch"]():
-            out = _gf_matmul_jnp_packed(key, words, None)
-    with stages["fetch"]():
-        out = np.asarray(out)
+            if use_pallas or interpret:
+                out = _gf_matmul_pallas(key, words, block_rows, interpret, None)
+            else:
+                out = _gf_matmul_jnp_packed(key, words, None)
+        with stages["fetch"]():
+            out = np.asarray(out)
+    finally:
+        del _IN_FLIGHT[token]
+    _count_beside(op, met)
     count_rs_dispatch(
         op,
         "device" if use_pallas and on_tpu() else "device_emulated",

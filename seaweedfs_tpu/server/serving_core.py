@@ -64,6 +64,8 @@ from ..util.fasthttp import (
     render_response,
 )
 from ..util.metrics import (
+    BACKGROUND_JOBS,
+    EVENT_LOOP_CONVERTING_SECONDS,
     EVENT_LOOP_CPU_SECONDS,
     EVENT_LOOP_LAG_SECONDS,
     EVENT_LOOP_LAG_TICKS,
@@ -105,26 +107,57 @@ class LoopClock(selectors.DefaultSelector):
     `publish` moves them to /metrics with the thread's CPU time, so turn +
     select is the loop's wall at every reading. Made on the thread that
     runs the loop, as `asyncio.run(..., loop_factory=new_event_loop)`
-    makes it."""
+    makes it.
+
+    While a conversion runs in the process (metrics.BACKGROUND_JOBS above 0)
+    the same pieces are summed apart as well (`conv_*`), for
+    `event_loop_converting_seconds_total`: a turn counts to the state it
+    ran in, the state is looked at on each select call, and the thread's
+    CPU time is read where it changes."""
 
     def __init__(self):
         super().__init__()
         self.turns = 0
         self.turn_s = self.poll_s = self.wait_s = 0.0
+        self.conv_turn_s = self.conv_poll_s = self.conv_wait_s = 0.0
+        self.conv_cpu_s = 0.0
+        # thread_time where the unpublished part of a converting stretch
+        # began; None while no conversion is in flight
+        self._conv_cpu0: Optional[float] = None
         self._returned = _perf()
         self._cpu = time.thread_time()
 
     def select(self, timeout=None):
         t0 = _perf()
-        self.turn_s += t0 - self._returned
+        turn = t0 - self._returned
+        self.turn_s += turn
         self.turns += 1
+        converting = self._conv_cpu0 is not None
+        if converting:
+            self.conv_turn_s += turn
+        if (BACKGROUND_JOBS.n > 0) is not converting:
+            converting = self._converting_changed()
         events = _select(self, timeout)  # no super() lookup a turn
         t1 = self._returned = _perf()
         if timeout == 0:
             self.poll_s += t1 - t0
+            if converting:
+                self.conv_poll_s += t1 - t0
         else:
             self.wait_s += t1 - t0
+            if converting:
+                self.conv_wait_s += t1 - t0
         return events
+
+    def _converting_changed(self) -> bool:
+        """A conversion began or the last one ended: the stretch's CPU."""
+        cpu = time.thread_time()
+        if self._conv_cpu0 is None:
+            self._conv_cpu0 = cpu
+            return True
+        self.conv_cpu_s += cpu - self._conv_cpu0
+        self._conv_cpu0 = None
+        return False
 
     def publish(self) -> dict:
         """On the loop's thread (a probe's tick, the /metrics render).
@@ -145,6 +178,18 @@ class LoopClock(selectors.DefaultSelector):
         _LOOP_POLL.inc(self.poll_s)
         _LOOP_WAIT.inc(self.wait_s)
         _LOOP_CPU.inc(cpu - self._cpu)
+        if self._conv_cpu0 is not None:  # a converting stretch so far
+            self.conv_turn_s += now - self._returned
+            self.conv_cpu_s += cpu - self._conv_cpu0
+            self._conv_cpu0 = cpu
+        busy = self.conv_turn_s + self.conv_poll_s
+        if busy or self.conv_wait_s:
+            running = min(self.conv_cpu_s, busy)
+            _CONV_RUNNING.inc(running)
+            _CONV_HELD.inc(busy - running)
+            _CONV_IDLE.inc(self.conv_wait_s)
+            self.conv_turn_s = self.conv_poll_s = self.conv_wait_s = 0.0
+            self.conv_cpu_s = 0.0
         self.turns = 0
         self.turn_s = self.poll_s = self.wait_s = 0.0
         self._returned, self._cpu = now, cpu
@@ -157,6 +202,9 @@ _LOOP_TURNS = EVENT_LOOP_TURNS.child()
 _LOOP_POLL = EVENT_LOOP_SELECT_SECONDS.child(mode="poll")
 _LOOP_WAIT = EVENT_LOOP_SELECT_SECONDS.child(mode="wait")
 _LOOP_CPU = EVENT_LOOP_CPU_SECONDS.child()
+_CONV_RUNNING = EVENT_LOOP_CONVERTING_SECONDS.child(mode="running")
+_CONV_HELD = EVENT_LOOP_CONVERTING_SECONDS.child(mode="held")
+_CONV_IDLE = EVENT_LOOP_CONVERTING_SECONDS.child(mode="idle")
 _STALLS = EVENT_LOOP_STALLS.child()
 _STALL_SECONDS = EVENT_LOOP_STALL_SECONDS.child()
 

@@ -32,9 +32,11 @@ from . import (
     TOTAL_SHARDS_COUNT,
     to_ext,
 )
+from ... import native
 from ...types import TOMBSTONE_FILE_SIZE, to_actual_offset
 from ...util import trace
 from ...util.metrics import (
+    BACKGROUND_JOBS,
     EC_ENCODE_BATCH_PIECES,
     EC_ENCODE_STAGE_CALLS,
     EC_ENCODE_STAGE_SECONDS,
@@ -117,6 +119,7 @@ _ST_SYNC_DRAIN = trace.stage(
 
 # the volume pieces each dispatch of the streamed pipeline carries: one
 _BATCH_PIECES = EC_ENCODE_BATCH_PIECES.child()
+
 
 # per-stage wall seconds of the last rebuild_ec_files run (read_s /
 # decode_s / write_s / total_s). On the pipelined route the stages
@@ -435,9 +438,15 @@ def _encode_streamed(
                 err[0] = e
 
     def write_files(mine, rows, first: int, used: int) -> None:
+        # one native call for the thread's files of the chunk: beside a
+        # busy serving loop a thread waits its turn at the interpreter
+        # lock after every write it gives the lock up for (PERF.md
+        # section 6)
         with _ST_WRITE_THREAD(run):
-            for i in mine:
-                outputs[i].write(rows[i - first, :used].data)
+            native.write_all(
+                [outputs[i] for i in mine],
+                [rows[i - first, :used] for i in mine],
+            )
 
     def helper(data_files, parity_files, tasks) -> None:
         while True:
@@ -731,7 +740,7 @@ def write_ec_files(
         large_block_size, small_block_size,
     )
     try:
-        with open(dat_path, "rb") as dat_f:
+        with BACKGROUND_JOBS, open(dat_path, "rb") as dat_f:
             spliced, input_kind, writers = _encode_streamed(
                 run, base_file_name, dat_f, codec,
                 n_large, large_block_size, n_small, small_block_size,

@@ -419,6 +419,37 @@ EVENT_LOOP_CPU_SECONDS = REGISTRY.counter(
     "CPU seconds of the serving loop's thread (time.thread_time): over turn "
     "+ select the share of its wall the loop ran",
 )
+
+
+class JobsInFlight:
+    """Background jobs running in this process now (`n`, read without a
+    lock): a conversion (erasure_coding/encoder.write_ec_files) enters it
+    for its whole run, and a serving loop's clock splits its second by it
+    (server/serving_core.LoopClock, `event_loop_converting_seconds_total`)."""
+
+    def __init__(self):
+        self.n = 0
+        self._lock = threading.Lock()
+
+    def __enter__(self):
+        with self._lock:
+            self.n += 1
+
+    def __exit__(self, *exc):
+        with self._lock:
+            self.n -= 1
+
+
+BACKGROUND_JOBS = JobsInFlight()
+# the same clock while a background job runs in the process (BACKGROUND_JOBS
+# above 0): running = cpu (at most turn + poll), held = turn + poll -
+# running, idle = wait; a turn belongs to the state seen at its select call
+EVENT_LOOP_CONVERTING_SECONDS = REGISTRY.counter(
+    "seaweedfs_tpu_event_loop_converting_seconds_total",
+    "wall seconds of the serving loop while a conversion (write_ec_files) "
+    "was in flight in the process, by mode (running = on a CPU; held = had "
+    "work and did not run; idle = select with nothing ready)",
+)
 # a lag-probe tick LOOP_STALL_SECONDS late or later is a stall, counted once
 # a loop whichever servers share it; beside it the kernel's account of the
 # same interval, each delta capped at the lateness (a source the host lacks
@@ -600,6 +631,15 @@ RS_DISPATCH_BYTES = REGISTRY.counter(
     "bytes of TpuRSCodec calls, by op, backend and kind (real = (rows in "
     "+ rows out) x the caller's width; padded = what the pad to the "
     "kernel's granule added)",
+)
+# which other codec call a call met between its dispatch and the end of its
+# fetch: another op's first (encode/decode/apply), else its own op's, else
+# none; counted once a call that returned
+RS_DISPATCHES_BESIDE = REGISTRY.counter(
+    "seaweedfs_tpu_rs_dispatches_beside_total",
+    "TpuRSCodec calls by op and by the op of another call in flight "
+    "between its dispatch and the end of its fetch (beside = another op "
+    "first, else the same op, else none)",
 )
 # encode-plane attribution (the mirror of ec_rebuild_stage_seconds):
 # stage walls of every write_ec_files run; kernel (pool) and write /

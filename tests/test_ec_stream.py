@@ -192,17 +192,29 @@ def test_streamed_matches_oneshot_from_any_number_of_writers(
 
 
 class _FailingShard:
-    """A shard file whose second write finds the disk full."""
+    """A shard file whose second write finds the disk full: the pipeline
+    writes a chunk's rows through the files' descriptors in one native
+    call, so the second descriptor it asks for is /dev/full's (and where
+    the library is missing, the second `write` raises as that would)."""
 
     def __init__(self, f, raised_on: list):
         self._f = f
+        self.name = f.name
+        self._full = open("/dev/full", "wb")
         self._writes = 0
         self._raised_on = raised_on
 
-    def write(self, data):
+    def _second(self) -> bool:
         self._writes += 1
         if self._writes == 2:
             self._raised_on.append(threading.current_thread().name)
+        return self._writes == 2
+
+    def fileno(self):
+        return self._full.fileno() if self._second() else self._f.fileno()
+
+    def write(self, data):
+        if self._second():
             raise OSError(errno.ENOSPC, "No space left on device")
         return self._f.write(data)
 
@@ -211,6 +223,7 @@ class _FailingShard:
 
     def close(self):
         self._f.close()
+        self._full.close()
 
 
 @pytest.mark.parametrize("shard", [11, 6], ids=["parity", "data"])

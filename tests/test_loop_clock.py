@@ -20,15 +20,9 @@ from seaweedfs_tpu.server import serving_core as sc
 from seaweedfs_tpu.util import trace
 
 from benchmarks.lib import common, metrics as layer_metrics
-from test_stage_tracing import LOOP_METRICS, host_event_names, moved, scrape
+from test_stage_tracing import LOOP_METRICS, get_cells_of, host_event_names, moved, scrape
 
 LOOP = "seaweedfs_tpu_event_loop_"
-GET_CELLS = [
-    "warm-rs10.4.degraded-get-c16",
-    "warm-rs10.4-spread4.server-lost-get-c16",
-    "warm-rs10.4-filer4m.degraded-chunk-get-c16",
-    "warm-rs10.4-filer4m-spread4.server-lost-chunk-get-c16",
-]
 DEADLINE_S = 30
 
 
@@ -507,7 +501,7 @@ WANT = {
 def test_each_of_the_ten_metric_files_reads_its_families(name):
     spec = common.load("layer_metrics", name + ".json")
     entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == name)
-    assert entry["workloads"] == GET_CELLS
+    assert entry["workloads"] == get_cells_of(name)
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == spec[key], key
     before, after = a_recorded_pair()

@@ -4,6 +4,7 @@ to the tests' oracle (ec_oracle; ref semantics:
 weed/storage/erasure_coding/ec_encoder.go) — and the adaptive codec choice.
 """
 
+import errno
 import os
 
 import numpy as np
@@ -113,3 +114,43 @@ def test_adaptive_codec_cpu_platform_short_circuits(monkeypatch):
         assert coder.adaptive_codec() is c  # cached
     finally:
         coder.reset_adaptive_cache()
+
+
+def _rows_of_a_strided_view(rng, k: int, width: int, block: int) -> np.ndarray:
+    """k rows `block` apart in one buffer, as the encode views a .dat."""
+    flat = rng.integers(0, 256, k * block, dtype=np.uint8)
+    return np.lib.stride_tricks.as_strided(flat, shape=(k, width), strides=(block, 1))
+
+
+@pytest.mark.parametrize("with_library", [True, False], ids=["native", "without"])
+def test_write_all_appends_each_row_to_its_file(tmp_path, monkeypatch, with_library):
+    """Two chunks of rows, each row appended whole to the file beside it:
+    the files hold the rows in order, through the one native call or,
+    where the library is missing, one `write` a row."""
+    if not with_library:
+        monkeypatch.setattr(native, "load", lambda: None)
+    rng = np.random.default_rng(7)
+    chunks = [_rows_of_a_strided_view(rng, 5, 3000, 4096) for _ in range(2)]
+    files = [open(tmp_path / f"s{i}", "wb") for i in range(5)]
+    for rows in chunks:
+        native.write_all(files, [rows[i, :2999] for i in range(5)])
+    for f in files:
+        f.close()
+    for i in range(5):
+        want = b"".join(rows[i, :2999].tobytes() for rows in chunks)
+        assert (tmp_path / f"s{i}").read_bytes() == want
+
+
+def test_write_all_names_the_file_whose_write_failed(tmp_path):
+    """A full disk is the run's OSError, with its errno and the file's name;
+    the rows before it are written."""
+    ok = open(tmp_path / "ok", "wb")
+    full = open("/dev/full", "wb")
+    rows = [np.full(4096, 3, np.uint8), np.full(4096, 4, np.uint8)]
+    with pytest.raises(OSError) as raised:
+        native.write_all([ok, full], rows)
+    assert raised.value.errno == errno.ENOSPC
+    assert raised.value.filename == "/dev/full"
+    ok.close()
+    full.close()
+    assert (tmp_path / "ok").read_bytes() == rows[0].tobytes()

@@ -467,6 +467,29 @@ ALL_NEW_METRICS = [
 # operations a codec call, read off the device trace in the four GET cells
 DEVICE_OPS_METRICS = ["rs_dispatch.device_ops_per_call"]
 GET_CELLS = ["warm-rs10.4.degraded-get-c16", SPREAD_CELL, CHUNK_CELL, CHUNK_SPREAD_CELL]
+# the converting cell: the GET cells' metrics whose reading means the same there
+# list it last; its six new metrics come last in BENCHMARK.json
+CONVERTING_CELL = "warm-rs10.4-converting.degraded-get-c16-while-encode"
+WITH_CONVERTING = {
+    "ec_read.decode_ms", "ec_read.cold_reconstruct_ms", "ec_read.worker_cpu_share",
+    "http.loop_held_share", "http.loop_cpu_share", "http.shed_share",
+    "http.loop_stall_ms_per_s", "rs_dispatch.device_ops_per_call",
+    "http.gc_pause_ms_per_s", "http.stall_kernel_share", "ec_read.survivor_read_ms",
+    "ec_read.executor_wait_ms", "ec_read.locate_ms", "ec_read.loop_resume_ms",
+    "ec_read.cache_hit_share", "ec_read.device_decode_share", "http.request_wait_ms",
+    "device_idle_share.get",
+}
+CONVERTING_METRICS = [
+    "rs_dispatch.decode_beside_encode_share", "http.loop_held_share_converting",
+    "ec_background.convert_share", "ec_background.late_ms",
+    "rs_kernel.decode_call_us", "rs_kernel.encode_call_us",
+]
+
+
+def get_cells_of(name: str) -> list:
+    """The cells a GET metric lists: the four GET cells, and the converting
+    cell where its reading means the same there."""
+    return GET_CELLS + ([CONVERTING_CELL] if name in WITH_CONVERTING else [])
 
 
 async def _encode_lose_a_shard_and_get(tmp_path) -> tuple:
@@ -586,7 +609,7 @@ def test_each_new_metric_file_reads_the_recorded_counters(degraded_get, cell, na
     spec = common.load("layer_metrics", name + ".json")
     entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == name)
     also = ([BATCH_CELL] if cell == "warm-rs10.4.ec-encode"
-            else [SPREAD_CELL, CHUNK_CELL, CHUNK_SPREAD_CELL])
+            else get_cells_of(name)[1:])
     assert entry["workloads"] == [cell] + also
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[key] == spec[key], key
@@ -628,7 +651,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     new = [name for _cell, name in ALL_NEW_METRICS]
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
     new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS + LOOP_METRICS
-    new += MAPPING_METRICS + CHUNK_SPREAD_METRICS + DEVICE_OPS_METRICS
+    new += MAPPING_METRICS + CHUNK_SPREAD_METRICS + DEVICE_OPS_METRICS + CONVERTING_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
@@ -647,7 +670,12 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
     for name in DEVICE_OPS_METRICS:
-        assert per_layer[name]["workloads"] == GET_CELLS
+        assert per_layer[name]["workloads"] == get_cells_of(name)
+        spec = common.load("layer_metrics", name + ".json")
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert per_layer[name][key] == spec[key], (name, key)
+    for name in CONVERTING_METRICS:  # what only the two halves at once show
+        assert per_layer[name]["workloads"] == [CONVERTING_CELL]
         spec = common.load("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
