@@ -135,6 +135,19 @@ def load() -> Optional[ctypes.CDLL]:
             lib.write_all.restype = ctypes.c_long
         except AttributeError:
             pass
+        try:
+            lib.fill_rows.argtypes = [
+                ctypes.c_int,  # n
+                ctypes.POINTER(ctypes.c_int),  # fds (-1 = copy srcs[i])
+                ctypes.c_int64,  # offset of every read
+                ctypes.POINTER(ctypes.c_void_p),  # srcs
+                ctypes.POINTER(ctypes.c_void_p),  # rows
+                ctypes.POINTER(ctypes.c_size_t),  # lengths
+                ctypes.POINTER(ctypes.c_int64),  # got
+            ]
+            lib.fill_rows.restype = None
+        except AttributeError:
+            pass
         _lib, _tier = lib, tier
         return _lib
 
@@ -304,3 +317,40 @@ def write_all(files, rows) -> None:
     if rc < 0:
         err = ctypes.get_errno()
         raise OSError(err, os.strerror(err), files[-rc - 1].name)
+
+
+def fill_rows(rows, sources, offset: int) -> Optional[list]:
+    """Fill each of `rows` (writable 1-D uint8 arrays, C-contiguous) from
+    the source beside it, in one native call that gives the interpreter
+    lock up once for all of them: an int is a file descriptor, read from
+    `offset` on until the row is full or the file ends; anything else is
+    a buffer of at least the row's length, whose first bytes are copied.
+    -> one int a row, the bytes read or copied, or -errno where the read
+    failed (every row is tried); None without the library, where the
+    caller reads row by row."""
+    lib = load()
+    if lib is None or not hasattr(lib, "fill_rows"):
+        return None
+    n = len(rows)
+    fds = (ctypes.c_int * n)()
+    srcs = (ctypes.c_void_p * n)()
+    kept = []  # the copies' arrays, alive until the call returns
+    for i, (row, src) in enumerate(zip(rows, sources)):
+        if row.dtype != np.uint8 or not (row.flags.c_contiguous and row.flags.writeable):
+            raise ValueError(f"row {i}: not a writable C-contiguous uint8 array")
+        if isinstance(src, int):
+            fds[i] = src
+        else:
+            buf = np.frombuffer(src, dtype=np.uint8)
+            if buf.nbytes < row.nbytes:
+                raise ValueError(f"row {i}: {buf.nbytes} bytes to copy into {row.nbytes}")
+            kept.append(buf)
+            fds[i], srcs[i] = -1, buf.ctypes.data
+    got = (ctypes.c_int64 * n)()
+    lib.fill_rows(
+        n, fds, offset, srcs,
+        (ctypes.c_void_p * n)(*(row.ctypes.data for row in rows)),
+        (ctypes.c_size_t * n)(*(row.nbytes for row in rows)),
+        got,
+    )
+    return list(got)

@@ -43,6 +43,7 @@ from ..util.metrics import (
     EC_NEEDLE_READS,
     EC_READ_INTERVALS,
     EC_READ_STAGE_SECONDS,
+    EC_RECONSTRUCT_FILLS,
     EC_RECONSTRUCT_LOCAL_READS,
     EC_RECONSTRUCT_SURVIVOR_BYTES,
     EC_RECONSTRUCTIONS,
@@ -108,8 +109,9 @@ def _read_stage(label: str, annotate: bool = True):
 # where an await or a thread hand-off lets other requests run inside:
 # survivor_read is the wall of the gathers of remote survivors on the loop
 # (none where every survivor is local) plus the worker's wall filling the
-# decode's input rows (its leaf, each shard read into a row, is the
-# `ec.read.pread` event, on the worker's thread), executor_wait runs from
+# decode's input rows (its leaf, the one native call that reads and copies
+# them all, is the `ec.read.pread` event, on the worker's thread; a spare
+# read after a failed row is one more), executor_wait runs from
 # run_in_executor to the worker's first line, decode is the worker's wall
 # around reconstruct_rows alone (its leaves are the codec's `rs.*` events),
 # loop_resume runs from the worker's last line to the coroutine's first line
@@ -167,6 +169,10 @@ _SURVIVOR_BYTES_REMOTE = EC_RECONSTRUCT_SURVIVOR_BYTES.child(origin="remote")
 _LOCAL_READS = {
     where: EC_RECONSTRUCT_LOCAL_READS.child(where=where)
     for where in ("worker", "loop")
+}
+# a cold reconstruct's fill, once, by how its rows were filled
+_FILLS = {
+    how: EC_RECONSTRUCT_FILLS.child(how=how) for how in ("one_call", "row_by_row")
 }
 _ST_LOCATE = trace.stage(
     "ec.read.locate",
@@ -1562,39 +1568,70 @@ class EcHandlers:
 
         def fill() -> Optional[list]:
             """The k survivors with the lowest ids among the local shards
-            and the fetched spans, row by row into the array the decode
-            uploads, as reconstruct_rows takes them: a slot a shard id.
-            None where fewer than k can be had: a local read that raises or
-            comes short leaves its row to the next survivor."""
+            and the fetched spans, each into its row of the array the
+            decode uploads, as reconstruct_rows takes them: a slot a shard
+            id. The first k are read and copied in ONE native call, which
+            gives the interpreter lock up once, not once a survivor; from
+            the first of them that came short or failed, and without the
+            library from the first, row by row, a local read that raises or
+            comes short leaving its row to the next survivor. None where
+            fewer than k can be had."""
             granule = codec.row_granule()
             rows = _survivor_rows(k, -(-span_size // granule) * granule)
             shards: list[Optional[np.ndarray]] = [None] * total
             where = _LOCAL_READS[
                 "worker" if asyncio._get_running_loop() is None else "loop"
             ]
-            filled = read = 0
+            ids, sources = [], []
             for shard_id in sorted(fetched.keys() | set(local)):
-                row = rows[filled, :span_size]
-                b = fetched.get(shard_id)
-                if b is not None:
-                    row[:] = np.frombuffer(b, dtype=np.uint8)
-                else:
-                    shard = ev.find_shard(shard_id)
-                    if shard is None:
+                src = fetched.get(shard_id)
+                if src is None:
+                    src = ev.find_shard(shard_id)
+                    if src is None:
                         continue  # unmounted since the plan was made
-                    where.inc()
-                    try:
-                        with _ST_PREAD():
-                            got = shard.read_into(row, span_start)
-                    except OSError:
-                        continue
-                    read += got
-                    if got != span_size:
-                        continue
-                shards[shard_id] = row
+                ids.append(shard_id)
+                sources.append(src)
+            filled = reads = read = 0
+            with _ST_PREAD():
+                got = EcVolumeShard.fill_rows(
+                    [rows[j, :span_size] for j in range(min(k, len(ids)))],
+                    sources[:k], span_start,
+                )
+            if got is None:
+                _FILLS["row_by_row"].inc()
+                got = []
+            else:
+                _FILLS["one_call"].inc()
+            for j, n in enumerate(got):
+                if isinstance(sources[j], EcVolumeShard):
+                    reads += 1
+                    read += max(n, 0)
+                if n != span_size:
+                    continue
+                if j != filled:  # a row above came short: close the gap
+                    rows[filled, :span_size] = rows[j, :span_size]
+                shards[ids[j]] = rows[filled, :span_size]
                 filled += 1
+            for shard_id, src in zip(ids[len(got):], sources[len(got):]):
                 if filled == k:
                     break
+                row = rows[filled, :span_size]
+                if isinstance(src, EcVolumeShard):
+                    reads += 1
+                    try:
+                        with _ST_PREAD():
+                            n = src.read_into(row, span_start)
+                    except OSError:
+                        continue
+                    read += n
+                    if n != span_size:
+                        continue
+                else:
+                    row[:] = np.frombuffer(src, dtype=np.uint8)
+                shards[shard_id] = row
+                filled += 1
+            if reads:
+                where.inc(reads)
             if read:
                 _SURVIVOR_BYTES_LOCAL.inc(read)
             return shards if filled == k else None

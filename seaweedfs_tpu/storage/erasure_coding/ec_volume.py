@@ -15,6 +15,7 @@ import struct
 import threading
 from typing import Callable, Optional
 
+from ... import native
 from . import (
     DATA_SHARDS_COUNT,
     EC_LARGE_BLOCK_SIZE,
@@ -126,6 +127,20 @@ class EcVolumeShard:
         fewer than `len(buf)` at the file's end. For a caller that owns
         the array the bytes are wanted in (a reconstruct's survivor rows)."""
         return os.preadv(self._f.fileno(), [buf], offset)
+
+    @staticmethod
+    def fill_rows(rows, sources, offset: int) -> Optional[list]:
+        """Fill each of `rows` from the source beside it in ONE native
+        call, which gives the interpreter lock up once for all of them: a
+        shard is read from `offset` on, anything else is a buffer of at
+        least the row's length, copied. -> one int a row, the bytes read
+        or copied, or -errno where a read failed; None without the native
+        library, where the caller reads a row at a time (`read_into`)."""
+        return native.fill_rows(
+            rows,
+            [s._f.fileno() if isinstance(s, EcVolumeShard) else s for s in sources],
+            offset,
+        )
 
     def close(self) -> None:
         self._f.close()

@@ -810,6 +810,13 @@ EC_RECONSTRUCT_LOCAL_READS = REGISTRY.counter(
     "into the decode's input array; loop = a thread that runs an event "
     "loop, where a read holds every other request up)",
 )
+EC_RECONSTRUCT_FILLS = REGISTRY.counter(
+    "seaweedfs_tpu_ec_reconstruct_fills_total",
+    "cold reconstructs' survivor rows filled, once a fill, by how (one_call "
+    "= the first data_shards survivors read and copied into their rows in "
+    "one native call, the interpreter lock given up once; row_by_row = a "
+    "read or a copy a row, where the native library is missing)",
+)
 # the worker thread of a cold reconstruct, round the whole of its work
 # (fill + decode): wall without cpu is the worker waiting (the interpreter
 # lock, the device, the disk); the rs.* and ec.read.pread leaves say where

@@ -39,7 +39,7 @@ from benchmarks.lib import common, metrics as layer_metrics
 from benchmarks.reference import ec_locate, rs_codec
 from test_cluster import Cluster, assign_retry, free_port_pair
 from test_stage_tracing import (
-    CHUNK_CELL, CHUNK_METRICS, CHUNK_SPREAD_CELL, host_event_names, moved, scrape,
+    CHUNK_CELL, CHUNK_METRICS, CHUNK_SPREAD_CELL, CONVERTING_CELL, host_event_names, moved, scrape,
 )
 
 MB = 1 << 20
@@ -52,6 +52,7 @@ STAGES = "seaweedfs_tpu_ec_read_stage_seconds_total"
 SURVIVOR_BYTES = "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total"
 COLD = "seaweedfs_tpu_ec_reconstructions_total"
 LOCAL_READS = "seaweedfs_tpu_ec_reconstruct_local_reads_total"
+FILLS = "seaweedfs_tpu_ec_reconstruct_fills_total"
 RS_SECONDS = "seaweedfs_tpu_rs_dispatch_seconds_total"
 GET_CELLS = ["warm-rs10.4.degraded-get-c16", "warm-rs10.4-spread4.server-lost-get-c16", CHUNK_CELL,
              CHUNK_SPREAD_CELL]
@@ -460,18 +461,34 @@ def test_the_new_stages_are_child_spans_of_a_sampled_request(live):
     assert {s["parent"] for s in local + [assemble]} == {root["span"]}
 
 
-def test_no_survivor_is_read_on_the_loops_thread(live, monkeypatch):
-    """Ten `read_into`s a cold reconstruct, each on an executor thread; the
-    loop's thread does the healthy intervals' `read_at`s and nothing else."""
-    key = next(k for k, body in live.body.items() if len(body) == 4 * MB
-               and any(s == 3 for s, _o, _n in live.reference_intervals(k)))
-    seen = {"read_into": [], "read_at": []}
-    for name in seen:
+def _spy_shard_reads(monkeypatch) -> dict:
+    """{seam: [(shard id, thread)]} of every shard read from now on: the
+    survivors a cold reconstruct reads in its one `fill_rows` call, a spare
+    read with `read_into`, and the healthy intervals' `read_at`s."""
+    seen = {"fill_rows": [], "read_into": [], "read_at": []}
+    for name in ("read_into", "read_at"):
         def spy(self, *args, _inner=getattr(EcVolumeShard, name), _name=name):
             seen[_name].append((self.shard_id, threading.get_ident()))
             return _inner(self, *args)
 
         monkeypatch.setattr(EcVolumeShard, name, spy)
+
+    def fill_rows(rows, sources, offset, _inner=EcVolumeShard.fill_rows):
+        seen["fill_rows"] += [(src.shard_id, threading.get_ident())
+                              for src in sources if isinstance(src, EcVolumeShard)]
+        return _inner(rows, sources, offset)
+
+    monkeypatch.setattr(EcVolumeShard, "fill_rows", staticmethod(fill_rows))
+    return seen
+
+
+def test_no_survivor_is_read_on_the_loops_thread(live, monkeypatch):
+    """Ten survivors a cold reconstruct, read in one `fill_rows` call on an
+    executor thread, and no spare; the loop's thread does the healthy
+    intervals' `read_at`s and nothing else."""
+    key = next(k for k, body in live.body.items() if len(body) == 4 * MB
+               and any(s == 3 for s, _o, _n in live.reference_intervals(k)))
+    seen = _spy_shard_reads(monkeypatch)
 
     async def read():
         await live.lose([3, 11])
@@ -485,8 +502,8 @@ def test_no_survivor_is_read_on_the_loops_thread(live, monkeypatch):
     body, before, after = live.run(read())
     assert body == live.body[key]
     loop_thread = live.thread.ident
-    assert [s for s, _t in seen["read_into"]] == [0, 1, 2, 4, 5, 6, 7, 8, 9, 10]
-    assert loop_thread not in {t for _s, t in seen["read_into"]}
+    assert [s for s, _t in seen["fill_rows"]] == [0, 1, 2, 4, 5, 6, 7, 8, 9, 10]
+    assert loop_thread not in {t for _s, t in seen["fill_rows"]} and not seen["read_into"]
     assert {t for _s, t in seen["read_at"]} == {loop_thread} and len(seen["read_at"]) == 4
     assert moved(before, after, LOCAL_READS, where="worker") == 10
     assert moved(before, after, LOCAL_READS, where="loop") == 0
@@ -510,6 +527,38 @@ WORKER_SECONDS = "seaweedfs_tpu_ec_degraded_read_worker_seconds_total"
 def a_chunk_on_shard_3(live) -> int:
     return next(k for k, body in live.body.items() if len(body) == 4 * MB
                 and any(s == 3 for s, _o, _n in live.reference_intervals(k)))
+
+
+
+def test_a_reconstructs_fill_is_one_native_call_and_the_cells_metric_says_so(live, monkeypatch):
+    """Each cold reconstruct fills its ten survivor rows in one native call
+    on the worker, counted `how="one_call"`, so `ec_read.one_call_fill_share`
+    reads 100 as a run evaluates it; a tree without the family leaves it out."""
+    key = a_chunk_on_shard_3(live)
+    seen = _spy_shard_reads(monkeypatch)
+
+    async def read():
+        await live.lose([3, 11])
+        try:
+            before = scrape()
+            n = await live.vs.read_ec_needle(live.ev, key)
+            return bytes(n.data), before, scrape()
+        finally:
+            await live.mount([3, 11])
+
+    body, before, after = live.run(read())
+    assert body == live.body[key]
+    cold = moved(before, after, COLD, kind="cold")
+    assert cold >= 1 and len(seen["fill_rows"]) == 10 * cold and not seen["read_into"]
+    assert moved(before, after, FILLS, how="one_call") == moved(before, after, FILLS) == cold
+    spec = common.load("layer_metrics", "ec_read.one_call_fill_share.json")
+    entry = next(e for e in common.benchmark_json()["per_layer"] if e["name"] == spec["name"])
+    assert entry["workloads"] == GET_CELLS + [CONVERTING_CELL]
+    for field in ("unit", "better", "source", "layer", "moves"):
+        assert entry[field] == spec[field], field
+    assert layer_metrics.Observed(before, after, {}, {}, {}, {}, None, None, {}).value(spec) == 100.0
+    parents = [{k: v for k, v in page.items() if not k.startswith(FILLS)} for page in (before, after)]
+    assert layer_metrics.Observed(*parents, {}, {}, {}, {}, None, None, {}).value(spec) is None
 
 
 def test_a_cold_reconstructs_stages_add_up_to_its_histogram(live):
@@ -581,7 +630,8 @@ def test_a_reconstructs_with_blocks_are_child_spans_of_a_sampled_get(live):
                  "ec.read.cache_put", "ec.read.assemble"):
         assert len(by_name[name]) == 1, name
         assert by_name[name][0]["parent"] == root["span"], name
-    assert len(by_name["ec.read.pread"]) == 10
+    # the ten survivors' reads: one event round the one native call
+    assert len(by_name["ec.read.pread"]) == 1
     assert "ec.read.loop_resume" not in by_name and "ec.read.executor_wait" not in by_name
 
 

@@ -17,6 +17,7 @@ its coroutine with a time limit of its own.
 """
 
 import asyncio
+import errno
 import os
 import random
 import threading
@@ -27,6 +28,7 @@ import pytest
 
 from benchmarks.lib import common, metrics as layer_metrics
 from benchmarks.reference import ec_spread, rs_codec
+from seaweedfs_tpu import native
 from seaweedfs_tpu.client.operation import upload_data
 from seaweedfs_tpu.pb import grpc_address
 from seaweedfs_tpu.server import volume_ec
@@ -58,6 +60,7 @@ COPY_BYTES = "seaweedfs_tpu_ec_shard_copy_bytes_total"
 COPY_SECONDS = "seaweedfs_tpu_ec_shard_copy_seconds_total"
 SHARD_READ = dict(server="volume", operation="VolumeEcShardRead")
 LOCAL_READS = "seaweedfs_tpu_ec_reconstruct_local_reads_total"
+FILLS = "seaweedfs_tpu_ec_reconstruct_fills_total"
 SURVIVOR_BYTES = "seaweedfs_tpu_ec_reconstruct_survivor_bytes_total"
 
 
@@ -708,23 +711,38 @@ def _one_server(tmp_path, mounted: int):
 
 def _spy_preads(monkeypatch) -> list:
     """[(offset, size)] of every survivor span a reconstruct reads from a
-    shard file here from now on (`read_into`: the worker that decodes reads
-    each into its row of the decode's input), and on `.shards` and `.threads`
-    the shard and the thread of each."""
+    shard file here from now on, and on `.shards` and `.threads` the shard
+    and the thread of each; on `.fills` the `how` of each fill. The worker
+    that decodes reads each span into its row of the decode's input: the
+    first `data_shards` survivors in one `EcVolumeShard.fill_rows`, a spare
+    after a read that failed with `read_into`."""
 
     class Preads(list):
         pass
 
     preads = Preads()
-    preads.shards, preads.threads = [], []
-    inner = EcVolumeShard.read_into
+    preads.shards, preads.threads, preads.fills = [], [], []
+    inner_fill, inner_read = EcVolumeShard.fill_rows, EcVolumeShard.read_into
+
+    def note(shard, buf, offset):
+        preads.append((offset, len(buf)))
+        preads.shards.append(shard.shard_id)
+        preads.threads.append(threading.get_ident())
+
+    def fill_rows(rows, sources, offset):
+        got = inner_fill(rows, sources, offset)
+        preads.fills.append("row_by_row" if got is None else "one_call")
+        if got is not None:
+            for row, src in zip(rows, sources):
+                if isinstance(src, EcVolumeShard):
+                    note(src, row, offset)
+        return got
 
     def read_into(self, buf, offset):
-        preads.append((offset, len(buf)))
-        preads.shards.append(self.shard_id)
-        preads.threads.append(threading.get_ident())
-        return inner(self, buf, offset)
+        note(self, buf, offset)
+        return inner_read(self, buf, offset)
 
+    monkeypatch.setattr(EcVolumeShard, "fill_rows", staticmethod(fill_rows))
     monkeypatch.setattr(EcVolumeShard, "read_into", read_into)
     return preads
 
@@ -758,6 +776,9 @@ def test_with_every_survivor_local_the_span_is_the_wide_one(tmp_path, monkeypatc
     assert set(preads) == {(2 * WIDE, WIDE)}
     assert preads.shards == [s for s in range(K + M) if s != DEAD][:K]
     assert threading.get_ident() not in preads.threads
+    # in one native call
+    assert preads.fills == ["one_call"]
+    assert moved(before, after, FILLS, how="one_call") == moved(before, after, FILLS) == 1
     assert moved(before, after, LOCAL_READS, where="worker") == moved(before, after, LOCAL_READS) == K
     assert moved(before, after, SURVIVOR_BYTES, origin="local") == K * WIDE
     assert moved(before, after, SURVIVOR_BYTES, origin="remote") == 0
@@ -781,6 +802,9 @@ def test_with_a_survivor_on_another_server_the_span_is_the_narrow_one(tmp_path, 
     assert len(asked) == K - mounted and {a[1:] for a in asked} == {narrow}
     assert set(preads) <= {narrow} and len(preads) == mounted
     assert threading.get_ident() not in preads.threads
+    # the local reads and the fetched spans' copies in one native call
+    assert preads.fills == ["one_call"]
+    assert moved(before, after, FILLS, how="one_call") == moved(before, after, FILLS) == 1
     assert moved(before, after, LOCAL_READS, where="worker") == moved(before, after, LOCAL_READS) == mounted
     assert moved(before, after, SURVIVOR_BYTES, origin="local") == mounted * narrow[1]
     assert moved(before, after, SURVIVOR_BYTES, origin="remote") == (K - mounted) * narrow[1]
@@ -806,17 +830,29 @@ def test_a_local_survivor_that_cannot_be_read_is_replaced_by_the_next_one(
 
         host._read_remote_shard_interval = nobody
     preads = _spy_preads(monkeypatch)
-    spied = EcVolumeShard.read_into
+    spied_fill, spied_read = EcVolumeShard.fill_rows, EcVolumeShard.read_into
     bad = min(s for s in range(K + M) if s != DEAD)
+
+    def fill_rows(rows, sources, offset):
+        # the bad shard's row a byte short, so that its read comes short;
+        # or its read done and then failed, as a read that raises is
+        rows = [row[:-1] if getattr(src, "shard_id", None) == bad and fault == "short" else row
+                for row, src in zip(rows, sources)]
+        got = spied_fill(rows, sources, offset)
+        if got is None:
+            return None
+        return [-errno.EIO if getattr(src, "shard_id", None) == bad and fault == "raises" else n
+                for n, src in zip(got, sources)]
 
     def read_into(self, buf, offset):
         if self.shard_id != bad:
-            return spied(self, buf, offset)
+            return spied_read(self, buf, offset)
         if fault == "raises":
             preads.shards.append(bad)
             raise OSError(5, "injected")
-        return spied(self, buf[:-1], offset)
+        return spied_read(self, buf[:-1], offset)
 
+    monkeypatch.setattr(EcVolumeShard, "fill_rows", staticmethod(fill_rows))
     monkeypatch.setattr(EcVolumeShard, "read_into", read_into)
     off = 4 * WIDE + 777
     before = scrape()
@@ -831,18 +867,56 @@ def test_a_local_survivor_that_cannot_be_read_is_replaced_by_the_next_one(
         assert _cached_spans(host) == [(4 * WIDE, WIDE)]
         assert moved(before, after, "seaweedfs_tpu_ec_reconstructions_total", kind="cold") == 1
     if spare == "local":
-        # eleven reads: the ten lowest, the bad one among them, and one spare
+        # eleven reads: the ten lowest, the bad one among them, in one call,
+        # and one spare after it
         assert not asked and preads.shards == survivors[: K + 1]
+        assert preads.fills == ["one_call"]
     else:
         # the ten local ones, then the three others, then the local ones again
         assert preads.shards == survivors[:K] * 2
         assert len(asked) == (3 if spare == "remote" else 0)
+        assert preads.fills == ["one_call"] * 2
+    assert moved(before, after, FILLS, how="one_call") == len(preads.fills)
     assert threading.get_ident() not in preads.threads
     short = (len(preads.shards) // K) * (WIDE - 1) if fault == "short" else 0
     good = sum(s != bad for s in preads.shards) * WIDE
     assert moved(before, after, SURVIVOR_BYTES, origin="local") == good + short
     assert moved(before, after, LOCAL_READS, where="worker") == len(preads.shards)
     assert moved(before, after, LOCAL_READS, where="loop") == 0
+    ev.close()
+
+
+@pytest.mark.parametrize("mounted", [13, 4])
+def test_without_the_native_library_the_rows_are_filled_one_by_one(tmp_path, monkeypatch, mounted):
+    """A library without `fill_rows`, or none: the same survivors are read
+    and copied a row at a time, on the worker, into rows byte for byte
+    those the one native call fills, and the fill is counted `row_by_row`."""
+    base, ev, host, asked = _one_server(tmp_path, mounted)
+    codec_type, decoded = type(host.codec), []
+
+    def reconstruct_rows(self, shards, missing, _inner=codec_type.reconstruct_rows):
+        decoded.append({i: bytes(row) for i, row in enumerate(shards) if row is not None})
+        return _inner(self, shards, missing)
+
+    monkeypatch.setattr(codec_type, "reconstruct_rows", reconstruct_rows)
+    preads = _spy_preads(monkeypatch)
+    off = 6 * WIDE + 5 * SPAN + 33
+    want = _shard_bytes(base, off, 1500)
+    assert _recover(host, ev, off, 1500) == want
+    host._ec_degraded_cache().invalidate(ev.volume_id)
+    monkeypatch.setattr(native, "load", lambda: None)
+    before = scrape()
+    assert _recover(host, ev, off, 1500) == want
+    after = scrape()
+    assert preads.fills == ["one_call", "row_by_row"]
+    assert len(decoded) == 2 and decoded[0] == decoded[1]
+    local = min(mounted, K)
+    # each planned survivor read once a fill, the second fill's on the worker too
+    assert preads.shards == [s for s in range(K + M) if s != DEAD][:local] * 2
+    assert threading.get_ident() not in preads.threads
+    assert len(asked) == 2 * (K - local)
+    assert moved(before, after, FILLS, how="row_by_row") == moved(before, after, FILLS) == 1
+    assert moved(before, after, LOCAL_READS, where="worker") == moved(before, after, LOCAL_READS) == local
     ev.close()
 
 

@@ -154,3 +154,85 @@ def test_write_all_names_the_file_whose_write_failed(tmp_path):
     ok.close()
     full.close()
     assert (tmp_path / "ok").read_bytes() == rows[0].tobytes()
+
+
+def _a_file(tmp_path, size: int) -> tuple:
+    """(bytes, an open descriptor) of a file of `size` random bytes."""
+    data = np.random.default_rng(size).integers(0, 256, size, dtype=np.uint8).tobytes()
+    path = tmp_path / f"f{size}"
+    path.write_bytes(data)
+    return data, os.open(path, os.O_RDONLY)
+
+
+def test_fill_rows_reads_a_whole_span_into_each_row(tmp_path):
+    """Rows of one array, each read from its own file at the one offset."""
+    files = [_a_file(tmp_path, 50_000 + i) for i in range(3)]
+    rows = np.zeros((3, 4096), np.uint8)
+    try:
+        got = native.fill_rows(list(rows), [fd for _d, fd in files], 12_345)
+    finally:
+        for _d, fd in files:
+            os.close(fd)
+    assert got == [4096] * 3
+    for row, (data, _fd) in zip(rows, files):
+        assert row.tobytes() == data[12_345:12_345 + 4096]
+
+
+def test_fill_rows_stops_a_read_at_the_end_of_the_file(tmp_path):
+    """A span past the end comes back short, by what the file held; the
+    rest of its row is left as it was."""
+    data, fd = _a_file(tmp_path, 10_000)
+    row = np.full(4096, 7, np.uint8)
+    try:
+        assert native.fill_rows([row], [fd], 8_000) == [2_000]
+        assert native.fill_rows([row], [fd], 20_000) == [0]
+    finally:
+        os.close(fd)
+    assert row[:2_000].tobytes() == data[8_000:]
+    assert (row[2_000:] == 7).all()
+
+
+def test_fill_rows_gives_errno_for_a_bad_descriptor_and_does_the_others(tmp_path):
+    """A closed descriptor is -EBADF for its row alone; the rows after it
+    are still read."""
+    data, fd = _a_file(tmp_path, 9_000)
+    _d, closed = _a_file(tmp_path, 9_001)
+    os.close(closed)
+    rows = np.zeros((3, 1000), np.uint8)
+    try:
+        got = native.fill_rows(list(rows), [fd, closed, fd], 100)
+    finally:
+        os.close(fd)
+    assert got == [1000, -errno.EBADF, 1000]
+    assert rows[0].tobytes() == rows[2].tobytes() == data[100:1100]
+    assert not rows[1].any()
+
+
+def test_fill_rows_copies_buffers_beside_the_reads(tmp_path):
+    """A buffer is copied into its row, its first bytes where it is longer,
+    in the same call as the reads; one shorter than its row, or a row the
+    call cannot write straight through, is refused before anything is done."""
+    data, fd = _a_file(tmp_path, 30_000)
+    fetched = [bytes(range(256)) * 8, np.arange(3000, dtype=np.uint8) * 3]
+    rows = np.zeros((4, 2048), np.uint8)
+    try:
+        got = native.fill_rows(list(rows), [fetched[0], fd, fetched[1], fd], 5_000)
+        with pytest.raises(ValueError):
+            native.fill_rows([np.zeros(4096, np.uint8)], [fetched[0]], 0)
+        for bad in (np.zeros(4096, np.uint8)[::2], np.zeros(512, np.uint32),
+                    np.frombuffer(bytes(2048), np.uint8)):
+            with pytest.raises(ValueError):
+                native.fill_rows([bad], [fd], 0)
+    finally:
+        os.close(fd)
+    assert got == [2048] * 4
+    assert rows[0].tobytes() == fetched[0]
+    assert rows[2].tobytes() == fetched[1][:2048].tobytes()
+    assert rows[1].tobytes() == rows[3].tobytes() == data[5_000:7_048]
+
+
+def test_fill_rows_of_no_rows_is_an_empty_answer(monkeypatch):
+    """Nothing to fill is an empty list; without the library, None."""
+    assert native.fill_rows([], [], 0) == []
+    monkeypatch.setattr(native, "load", lambda: None)
+    assert native.fill_rows([], [], 0) is None
