@@ -139,7 +139,7 @@ def load() -> Optional[ctypes.CDLL]:
             lib.fill_rows.argtypes = [
                 ctypes.c_int,  # n
                 ctypes.POINTER(ctypes.c_int),  # fds (-1 = copy srcs[i])
-                ctypes.c_int64,  # offset of every read
+                ctypes.POINTER(ctypes.c_int64),  # offset of each read
                 ctypes.POINTER(ctypes.c_void_p),  # srcs
                 ctypes.POINTER(ctypes.c_void_p),  # rows
                 ctypes.POINTER(ctypes.c_size_t),  # lengths
@@ -319,19 +319,26 @@ def write_all(files, rows) -> None:
         raise OSError(err, os.strerror(err), files[-rc - 1].name)
 
 
-def fill_rows(rows, sources, offset: int) -> Optional[list]:
+def fill_rows(rows, sources, offset) -> Optional[list]:
     """Fill each of `rows` (writable 1-D uint8 arrays, C-contiguous) from
     the source beside it, in one native call that gives the interpreter
     lock up once for all of them: an int is a file descriptor, read from
-    `offset` on until the row is full or the file ends; anything else is
-    a buffer of at least the row's length, whose first bytes are copied.
-    -> one int a row, the bytes read or copied, or -errno where the read
-    failed (every row is tried); None without the library, where the
-    caller reads row by row."""
+    its offset on until the row is full or the file ends; anything else
+    is a buffer of at least the row's length, whose first bytes are
+    copied. `offset` is every read's, or a sequence of one a row (a row
+    that copies ignores its own). -> one int a row, the bytes read or
+    copied, or -errno where the read failed (every row is tried); None
+    without the library, where the caller reads row by row."""
     lib = load()
     if lib is None or not hasattr(lib, "fill_rows"):
         return None
     n = len(rows)
+    if np.ndim(offset) == 0:
+        offs = (ctypes.c_int64 * n)(*([int(offset)] * n))
+    else:
+        if len(offset) != n:
+            raise ValueError(f"{len(offset)} offsets for {n} rows")
+        offs = (ctypes.c_int64 * n)(*offset)
     fds = (ctypes.c_int * n)()
     srcs = (ctypes.c_void_p * n)()
     kept = []  # the copies' arrays, alive until the call returns
@@ -348,7 +355,7 @@ def fill_rows(rows, sources, offset: int) -> Optional[list]:
             fds[i], srcs[i] = -1, buf.ctypes.data
     got = (ctypes.c_int64 * n)()
     lib.fill_rows(
-        n, fds, offset, srcs,
+        n, fds, offs, srcs,
         (ctypes.c_void_p * n)(*(row.ctypes.data for row in rows)),
         (ctypes.c_size_t * n)(*(row.nbytes for row in rows)),
         got,

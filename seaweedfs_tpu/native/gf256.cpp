@@ -355,13 +355,15 @@ long write_all(int n, const int* fds, const void* const* bufs,
 
 // Fill n rows in ONE call, the read side of write_all: a reconstruct's
 // survivor rows (volume_ec.py _recover_one_interval) give the interpreter
-// lock up once for all of them, not once a shard read. Row i is dsts[i],
-// lens[i] bytes: where fds[i] >= 0 it is read from fds[i] at off, looping
-// on short counts and EINTR until the row is full or the file ends; else
-// lens[i] bytes of srcs[i] are copied into it. got[i] = the bytes read or
-// copied, or -errno where a read failed; every row is tried.
-void fill_rows(int n, const int* fds, int64_t off, const void* const* srcs,
-               void* const* dsts, const size_t* lens, int64_t* got) {
+// lock up once for all of them, not once a shard read; the reconstructs
+// of one batch give it up once for all of theirs. Row i is dsts[i],
+// lens[i] bytes: where fds[i] >= 0 it is read from fds[i] at offs[i],
+// looping on short counts and EINTR until the row is full or the file
+// ends; else lens[i] bytes of srcs[i] are copied into it. got[i] = the
+// bytes read or copied, or -errno where a read failed; every row is tried.
+void fill_rows(int n, const int* fds, const int64_t* offs,
+               const void* const* srcs, void* const* dsts, const size_t* lens,
+               int64_t* got) {
   for (int i = 0; i < n; i++) {
     char* p = static_cast<char*>(dsts[i]);
     if (fds[i] < 0) {
@@ -372,7 +374,8 @@ void fill_rows(int n, const int* fds, int64_t off, const void* const* srcs,
     size_t done = 0;
     int64_t err = 0;
     while (done < lens[i]) {
-      ssize_t r = pread(fds[i], p + done, lens[i] - done, off + (off_t)done);
+      ssize_t r =
+          pread(fds[i], p + done, lens[i] - done, offs[i] + (off_t)done);
       if (r < 0) {
         if (errno == EINTR) continue;
         err = -(int64_t)errno;

@@ -317,6 +317,30 @@ def row_granule(
     return block_rows * LANE * 4 if use_pallas or interpret else 4
 
 
+def compile_gf_matmul_bytes(
+    matrix: np.ndarray,
+    max_width: int,
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+    force_pallas: bool | None = None,
+    interpret: bool = False,
+) -> int:
+    """Compile the Pallas kernel of `matrix` at every width a
+    gf_matmul_bytes call of up to `max_width` bytes a row is padded to,
+    each a whole number of blocks, by running it once on zeros: a call
+    counted nowhere. The jnp path takes any width and compiles nothing
+    ahead. -> the widths run."""
+    use_pallas = force_pallas if force_pallas is not None else on_tpu()
+    if not (use_pallas or interpret):
+        return 0
+    granule = row_granule(block_rows, force_pallas, interpret)
+    key = tuple(map(tuple, np.asarray(matrix, dtype=np.uint8)))
+    widths = range(granule, max_width + 1, granule)
+    for w in widths:
+        words = np.zeros((len(key[0]), w // (4 * LANE), LANE), np.uint32)
+        np.asarray(_gf_matmul_pallas(key, words, block_rows, interpret, None))
+    return len(widths)
+
+
 def gf_matmul_bytes(
     matrix: np.ndarray,
     data,

@@ -21,7 +21,13 @@ from ..storage.erasure_coding.galois import (
     reconstruction_matrix,
 )
 from ..util.device import on_tpu
-from .gf256 import RS_STAGE, count_rs_dispatch, gf_matmul_bytes, row_granule
+from .gf256 import (
+    RS_STAGE,
+    compile_gf_matmul_bytes,
+    count_rs_dispatch,
+    gf_matmul_bytes,
+    row_granule,
+)
 
 
 def rows_of_one_array(rows: Sequence) -> Optional[np.ndarray]:
@@ -228,6 +234,24 @@ class TpuRSCodec:
         """Public bulk GF(2^8) matmul on the device kernel (the primitive
         batched multi-volume rebuild dispatches through)."""
         return self._apply(np.asarray(m, dtype=np.uint8), data, "apply")
+
+    def compile_decode(
+        self, survivors: Sequence[int], wanted: Sequence[int], max_width: int
+    ) -> int:
+        """Compile reconstruct_rows' kernel for `wanted` from `survivors`
+        at every padded width up to `max_width` bytes a row, so that no
+        call of that decode up to that width compiles later (the Pallas
+        kernel's whole blocks: four up to 1 MiB); nothing where the kernel
+        takes any width. -> the widths compiled."""
+        rows = DECODE_ROWS_CACHE.rows_for(
+            self.matrix, list(survivors), list(wanted)
+        )
+        return compile_gf_matmul_bytes(
+            rows,
+            max_width,
+            force_pallas=self._force_pallas,
+            interpret=self._interpret,
+        )
 
     def reconstruct_rows(
         self,

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import contextvars
+import functools
 import os
 import random
 import threading
@@ -43,6 +44,7 @@ from ..util.metrics import (
     EC_NEEDLE_READS,
     EC_READ_INTERVALS,
     EC_READ_STAGE_SECONDS,
+    EC_RECONSTRUCT_BATCHES,
     EC_RECONSTRUCT_FILLS,
     EC_RECONSTRUCT_LOCAL_READS,
     EC_RECONSTRUCT_SURVIVOR_BYTES,
@@ -111,11 +113,13 @@ def _read_stage(label: str, annotate: bool = True):
 # (none where every survivor is local) plus the worker's wall filling the
 # decode's input rows (its leaf, the one native call that reads and copies
 # them all, is the `ec.read.pread` event, on the worker's thread; a spare
-# read after a failed row is one more), executor_wait runs from
-# run_in_executor to the worker's first line, decode is the worker's wall
-# around reconstruct_rows alone (its leaves are the codec's `rs.*` events),
-# loop_resume runs from the worker's last line to the coroutine's first line
-# after the hop (the loop coming round to the finished future). With
+# read after a failed row is one more), batch_wait runs from the plan's
+# hand-off to the batcher to its job's run_in_executor, executor_wait from
+# there to the worker's first line, decode is the worker's wall around
+# reconstruct_rows alone (its leaves are the codec's `rs.*` events),
+# loop_resume runs from the worker's answer to the coroutine's first line
+# after the hop (the loop coming round to the finished future). A wall the
+# members of one batch waited out together is charged once to each. With
 # cache_put they add up to ec_degraded_read_seconds{result="cold"}.
 # Before a reconstruct is tried at all, remote_attempts: the TTL'd
 # location refresh (one LookupEcVolume a SHARD_LOCATION_TTL, a dict look-up
@@ -140,6 +144,7 @@ _SHARD_READ_SERVED = REQUEST_HISTOGRAM.child(
     server="volume", operation="VolumeEcShardRead"
 )
 _ST_PREAD = trace.stage("ec.read.pread")
+_ST_BATCH_WAIT = _read_stage("batch_wait", annotate=False)
 _ST_EXECUTOR_WAIT = _read_stage("executor_wait", annotate=False)
 _ST_DECODE = _read_stage("decode", annotate=False)
 _ST_LOOP_RESUME = _read_stage("loop_resume", annotate=False)
@@ -250,8 +255,8 @@ _WORKER = threading.local()
 
 def _survivor_rows(k: int, width: int) -> np.ndarray:
     """uint8[k, width], C-contiguous, for the calling worker thread to read
-    a reconstruct's survivors into and hand to the codec, which uploads it
-    as it is. The thread's own array, used again by its next reconstruct (a
+    a reconstruct job's survivors into and hand to the codec, which uploads
+    it as it is. The thread's own array, used again by its next reconstruct (a
     decode has fetched its answer before it returns, so nothing reads the
     rows after that; memory the runtime has seen before uploads faster than
     fresh pages): what it held stays in it, so a row is valid as far as it
@@ -344,6 +349,293 @@ class DegradedIntervalCache:
     def __len__(self) -> int:
         with self._lock:
             return len(self._spans)
+
+
+# the decodes of cold reconstructs, coalesced (ReconstructBatcher, one a
+# server): at most EC_DECODE_JOBS_IN_FLIGHT of its jobs are on the executor
+# at once, and the reconstructs handed to it while they run go together in
+# the next, side by side in one survivor array a decode matrix, a call at
+# most EC_DECODE_CALL_BYTES a row: on the chip four of the Pallas kernel's
+# blocks, the four shapes a decode matrix's first job compiles. A
+# reconstruct whose span fills a call by itself never waits
+EC_DECODE_JOBS_IN_FLIGHT = 1
+EC_DECODE_CALL_BYTES = 1 << 20
+
+
+class ReconstructPlan:
+    """One cold reconstruct for the batcher: `size` bytes from `start` of
+    shard `missing`, from the survivors `ids` (lowest first; the first
+    data_shards planned, the rest spares) and their `sources` beside
+    them, an EcVolumeShard to read or a span fetched from elsewhere.
+    `key` is the decode it can share: the codec, the planned survivors
+    and the shard."""
+
+    __slots__ = (
+        "codec", "missing", "start", "size", "ids", "sources", "key",
+        "granule", "width", "context", "t_submit", "future",
+    )
+
+    def __init__(self, codec, missing: int, start: int, size: int,
+                 ids: list, sources: list):
+        self.codec, self.missing, self.start, self.size = (
+            codec, missing, start, size
+        )
+        self.ids, self.sources = ids, sources
+        self.key = (codec, tuple(ids[: codec.data_shards]), missing)
+        self.granule = codec.row_granule()
+        self.width = -(-size // self.granule) * self.granule
+
+
+class ReconstructBatcher:
+    """The cold reconstructs of one server, decoded in jobs on the
+    executor. A plan starts a job at once, alone, where fewer than
+    EC_DECODE_JOBS_IN_FLIGHT are running; else it waits, and when a
+    running job ends the next starts with every plan waiting, as far as
+    each decode matrix's call has room (the rest wait for the job after).
+    A job fills every member's survivor rows in ONE native call, decodes
+    each matrix's members in ONE codec call over their spans side by side
+    (GF(2^8) is column by column, so each member's columns are its own
+    decode), and hands the answers to the loop in one callback: the
+    interpreter lock changes hands once for all of them where each
+    reconstruct alone took it four or five times. A member with a row
+    that came short or failed is finished alone after the others are
+    answered, as one reconstruct was before: spares row by row, then its
+    own decode. A job that raises fails every member it has not answered."""
+
+    def __init__(self):
+        self._loop = None
+        self._pending: list = []
+        self._in_flight = 0
+        self._compiled: set = set()  # the keys whose call shapes compiled
+
+    def submit(self, plan: ReconstructPlan) -> asyncio.Future:
+        """-> a future on the running loop of (the span decoded, or None
+        short of survivors; the worker's clock when it was done)."""
+        loop = asyncio.get_running_loop()
+        if loop is not self._loop:
+            # a loop of its own (each asyncio.run): nothing of another
+            # loop's is in flight on this one
+            self._loop, self._pending, self._in_flight = loop, [], 0
+        # in the request's context, so that under a sampled request the
+        # worker's stages are child spans as the loop's are (a job's are
+        # its first member's)
+        plan.context = contextvars.copy_context()
+        plan.t_submit = time.perf_counter()
+        plan.future = loop.create_future()
+        if plan.width >= EC_DECODE_CALL_BYTES:
+            self._start(loop, [plan], counted=False)
+        else:
+            self._pending.append(plan)
+            if self._in_flight < EC_DECODE_JOBS_IN_FLIGHT:
+                self._start_pending(loop)
+        return plan.future
+
+    def _start_pending(self, loop) -> None:
+        taken, left, used = [], [], {}
+        for plan in self._pending:
+            u = used.get(plan.key, 0) + plan.size
+            if -(-u // plan.granule) * plan.granule <= EC_DECODE_CALL_BYTES:
+                used[plan.key] = u
+                taken.append(plan)
+            else:
+                left.append(plan)
+        self._pending = left
+        self._start(loop, taken, counted=True)
+
+    def _start(self, loop, members: list, counted: bool) -> None:
+        if counted:
+            self._in_flight += 1
+        EC_RECONSTRUCT_BATCHES.inc()
+        t_start = time.perf_counter()
+        for plan in members:
+            _ST_BATCH_WAIT.since(plan.t_submit)
+        job = loop.run_in_executor(
+            None, members[0].context.run, self._run, loop, members, t_start
+        )
+        job.add_done_callback(
+            functools.partial(self._job_done, loop, members, counted)
+        )
+
+    def _job_done(self, loop, members: list, counted: bool, job) -> None:
+        for plan in members:
+            if plan.future.done():
+                continue
+            if job.cancelled():
+                plan.future.cancel()
+            else:
+                plan.future.set_exception(
+                    job.exception()
+                    or RuntimeError("a reconstruct job gave no answer")
+                )
+        if counted and loop is self._loop:
+            self._in_flight -= 1
+            if self._pending and self._in_flight < EC_DECODE_JOBS_IN_FLIGHT:
+                self._start_pending(loop)
+
+    @staticmethod
+    def _answer(answers: list) -> None:
+        for plan, answer in answers:
+            if not plan.future.done():
+                plan.future.set_result(answer)
+
+    def _run(self, loop, members: list, t_start: float) -> None:
+        """The job, on an executor thread: one fill, one decode call a
+        decode matrix, the answers to the loop in one callback; then each
+        member a row of which came short or failed, alone. Each member is
+        charged the whole wall of every stage it waited out."""
+        riders = len(members)
+        t0 = _ST_EXECUTOR_WAIT.since(t_start, riders=riders)
+        clocks = [t0, time.thread_time()]
+
+        def read_clocks() -> float:
+            now, cpu = time.perf_counter(), time.thread_time()
+            _WORKER_WALL.inc(now - clocks[0])
+            _WORKER_CPU.inc(cpu - clocks[1])
+            clocks[:] = now, cpu
+            return now
+
+        groups: dict = {}
+        for plan in members:
+            groups.setdefault(plan.key, []).append(plan)
+        # one array for the job: k rows a decode matrix, as wide as the
+        # widest of their calls, each member's rows its columns of them
+        at, row, width = {}, 0, 0
+        for plans in groups.values():
+            col = 0
+            for plan in plans:
+                at[plan] = (row, col)
+                col += plan.size
+            width = max(width, -(-col // plans[0].granule) * plans[0].granule)
+            row += plans[0].codec.data_shards
+        rows = _survivor_rows(row, width)
+        with _ST_SURVIVOR_READ(riders=riders):
+            got = _fill_members(rows, members, at)
+        answers, alone = [], []
+        with _ST_DECODE(riders=riders):
+            for key, plans in groups.items():
+                codec, survivors, missing = key
+                k = codec.data_shards
+                clean = [p for p in plans
+                         if len(got[p]) == k and all(n == p.size for n in got[p])]
+                alone += [p for p in plans if p not in clean]
+                if not clean:
+                    continue
+                if key not in self._compiled:
+                    self._compiled.add(key)
+                    compile_decode = getattr(codec, "compile_decode", None)
+                    if compile_decode is not None:
+                        compile_decode(survivors, [missing], EC_DECODE_CALL_BYTES)
+                r = at[clean[0]][0]
+                used = max(at[p][1] + p.size for p in clean)
+                shards: list = [None] * codec.total_shards
+                for j, shard_id in enumerate(survivors):
+                    shards[shard_id] = rows[r + j, :used]
+                out = codec.reconstruct_rows(shards, [missing])[0]
+                answers += [(p, out[at[p][1] : at[p][1] + p.size]) for p in clean]
+        if answers:
+            t_done = read_clocks()
+            loop.call_soon_threadsafe(
+                self._answer, [(p, (span, t_done)) for p, span in answers]
+            )
+        for plan in alone:
+            with _ST_SURVIVOR_READ():
+                shards = _finish_fill(rows, plan, at[plan], got[plan])
+            span = None
+            if shards is not None:
+                with _ST_DECODE():
+                    span = plan.codec.reconstruct_rows(shards, [plan.missing])[0]
+            loop.call_soon_threadsafe(
+                self._answer, [(plan, (span, read_clocks()))]
+            )
+
+
+def _count_local_reads(sources, got) -> None:
+    """The local survivor reads among `sources` and the bytes `got` of
+    each, by the thread they ran on."""
+    reads = read = 0
+    for src, n in zip(sources, got):
+        if isinstance(src, EcVolumeShard):
+            reads += 1
+            read += max(n, 0)
+    if reads:
+        _LOCAL_READS[
+            "worker" if asyncio._get_running_loop() is None else "loop"
+        ].inc(reads)
+    if read:
+        _SURVIVOR_BYTES_LOCAL.inc(read)
+
+
+def _fill_members(rows: np.ndarray, members: list, at: dict) -> dict:
+    """The first data_shards survivors of every member, each into its row
+    of `rows` at the member's columns, in ONE native call, which gives the
+    interpreter lock up once for the job. -> {member: one int a row it
+    planned, the bytes read or copied, or -errno}; empty lists without the
+    library, where each member is filled alone, row by row."""
+    views, sources, offsets, owners = [], [], [], []
+    for plan in members:
+        r, col = at[plan]
+        n = min(plan.codec.data_shards, len(plan.ids))
+        views += [rows[r + j, col : col + plan.size] for j in range(n)]
+        sources += plan.sources[:n]
+        offsets += [plan.start] * n
+        owners += [plan] * n
+    with _ST_PREAD():
+        got = EcVolumeShard.fill_rows(
+            views, sources,
+            offsets if len(members) > 1 else members[0].start,
+        )
+    if got is None:
+        _FILLS["row_by_row"].inc(len(members))
+        return {plan: [] for plan in members}
+    _FILLS["one_call"].inc(len(members))
+    _count_local_reads(sources, got)
+    out: dict = {plan: [] for plan in members}
+    for plan, n in zip(owners, got):
+        out[plan].append(n)
+    return out
+
+
+def _finish_fill(rows: np.ndarray, plan: ReconstructPlan, at: tuple,
+                 got: list) -> Optional[list]:
+    """A member's shards as reconstruct_rows takes them (a slot a shard
+    id) from its rows that `got` filled whole, and from the first of
+    those that came short or failed (without the library, from the
+    first), its survivors read or copied row by row, a local read that
+    raises or comes short leaving its row to the next. None where fewer
+    than data_shards can be had."""
+    r, col = at
+    k, size = plan.codec.data_shards, plan.size
+    shards: list = [None] * plan.codec.total_shards
+    filled = 0
+    for j, n in enumerate(got):
+        if n != size:
+            continue
+        if j != filled:  # a row above came short: close the gap
+            rows[r + filled, col : col + size] = rows[r + j, col : col + size]
+        shards[plan.ids[j]] = rows[r + filled, col : col + size]
+        filled += 1
+    read, read_got = [], []
+    for shard_id, src in zip(plan.ids[len(got):], plan.sources[len(got):]):
+        if filled == k:
+            break
+        row = rows[r + filled, col : col + size]
+        if isinstance(src, EcVolumeShard):
+            read.append(src)
+            try:
+                with _ST_PREAD():
+                    n = src.read_into(row, plan.start)
+            except OSError:
+                read_got.append(0)
+                continue
+            read_got.append(n)
+            if n != size:
+                continue
+        else:
+            row[:] = np.frombuffer(src, dtype=np.uint8)
+        shards[shard_id] = row
+        filled += 1
+    _count_local_reads(read, read_got)
+    return shards if filled == k else None
 
 
 class EcHandlers:
@@ -1291,6 +1583,12 @@ class EcHandlers:
             cache[key] = get_codec(self.codec_backend, data_shards, parity_shards)
         return cache[key]
 
+    def _reconstruct_batcher(self) -> ReconstructBatcher:
+        batcher = getattr(self, "_batcher", None)
+        if batcher is None:
+            batcher = self._batcher = ReconstructBatcher()
+        return batcher
+
     def _ec_degraded_cache(self) -> DegradedIntervalCache:
         cache = getattr(self, "_degraded_cache", None)
         if cache is None:
@@ -1473,8 +1771,10 @@ class EcHandlers:
         Those on other servers or the cold tier are fetched CONCURRENTLY on
         the loop (one gather — wall clock is the slowest survivor, not the
         sum; the survivors one holder lists share one stream, a holder's
-        call costing more than its bytes). Those on this server's disks are
-        read by the worker thread that decodes, each span straight into its
+        call costing more than its bytes). Then the reconstruct is a plan
+        for the server's ReconstructBatcher, decoded with the others
+        waiting beside it. Those survivors on this server's disks are read
+        by the worker thread that decodes, each span straight into its
         row of the array the codec uploads: no local read runs on the loop,
         none is a task, no spare is read unless a read fails, and no copy
         stands between the shard file and the upload. The decode is
@@ -1564,102 +1864,23 @@ class EcHandlers:
                     *(fetch_group(url, g) for url, g in groups.items()),
                 )
         codec = self.codec_for(k, ev.parity_shards)
-        loop = asyncio.get_event_loop()
-
-        def fill() -> Optional[list]:
-            """The k survivors with the lowest ids among the local shards
-            and the fetched spans, each into its row of the array the
-            decode uploads, as reconstruct_rows takes them: a slot a shard
-            id. The first k are read and copied in ONE native call, which
-            gives the interpreter lock up once, not once a survivor; from
-            the first of them that came short or failed, and without the
-            library from the first, row by row, a local read that raises or
-            comes short leaving its row to the next survivor. None where
-            fewer than k can be had."""
-            granule = codec.row_granule()
-            rows = _survivor_rows(k, -(-span_size // granule) * granule)
-            shards: list[Optional[np.ndarray]] = [None] * total
-            where = _LOCAL_READS[
-                "worker" if asyncio._get_running_loop() is None else "loop"
-            ]
-            ids, sources = [], []
-            for shard_id in sorted(fetched.keys() | set(local)):
-                src = fetched.get(shard_id)
-                if src is None:
-                    src = ev.find_shard(shard_id)
-                    if src is None:
-                        continue  # unmounted since the plan was made
-                ids.append(shard_id)
-                sources.append(src)
-            filled = reads = read = 0
-            with _ST_PREAD():
-                got = EcVolumeShard.fill_rows(
-                    [rows[j, :span_size] for j in range(min(k, len(ids)))],
-                    sources[:k], span_start,
-                )
-            if got is None:
-                _FILLS["row_by_row"].inc()
-                got = []
-            else:
-                _FILLS["one_call"].inc()
-            for j, n in enumerate(got):
-                if isinstance(sources[j], EcVolumeShard):
-                    reads += 1
-                    read += max(n, 0)
-                if n != span_size:
-                    continue
-                if j != filled:  # a row above came short: close the gap
-                    rows[filled, :span_size] = rows[j, :span_size]
-                shards[ids[j]] = rows[filled, :span_size]
-                filled += 1
-            for shard_id, src in zip(ids[len(got):], sources[len(got):]):
-                if filled == k:
-                    break
-                row = rows[filled, :span_size]
-                if isinstance(src, EcVolumeShard):
-                    reads += 1
-                    try:
-                        with _ST_PREAD():
-                            n = src.read_into(row, span_start)
-                    except OSError:
-                        continue
-                    read += n
-                    if n != span_size:
-                        continue
-                else:
-                    row[:] = np.frombuffer(src, dtype=np.uint8)
-                shards[shard_id] = row
-                filled += 1
-            if reads:
-                where.inc(reads)
-            if read:
-                _SURVIVOR_BYTES_LOCAL.inc(read)
-            return shards if filled == k else None
-
-        def rebuild(t_submit: float) -> tuple:
-            """-> (the decoded rows, or None short of survivors; when the
-            worker was done, which is where loop_resume starts)."""
-            t0 = _ST_EXECUTOR_WAIT.since(t_submit)
-            cpu0 = time.thread_time()
-            rows = None
-            with _ST_SURVIVOR_READ():
-                shards = fill()
-            if shards is not None:
-                with _ST_DECODE():
-                    rows = codec.reconstruct_rows(shards, [missing_shard])
-            _WORKER_CPU.inc(time.thread_time() - cpu0)
-            t_done = time.perf_counter()
-            _WORKER_WALL.inc(t_done - t0)
-            return rows, t_done
-
         decoded = None
         for more in (rest, ()):
             if len(local) + len(fetched) >= k:
-                # in the request's context, so that under a sampled request
-                # the worker's stages are child spans as the loop's are
-                decoded, t_done = await loop.run_in_executor(
-                    None, contextvars.copy_context().run, rebuild,
-                    time.perf_counter(),
+                ids, sources = [], []
+                for shard_id in sorted(fetched.keys() | set(local)):
+                    src = fetched.get(shard_id)
+                    if src is None:
+                        src = ev.find_shard(shard_id)
+                        if src is None:
+                            continue  # unmounted since the plan was made
+                    ids.append(shard_id)
+                    sources.append(src)
+                decoded, t_done = await self._reconstruct_batcher().submit(
+                    ReconstructPlan(
+                        codec, missing_shard, span_start, span_size, ids,
+                        sources,
+                    )
                 )
                 _ST_LOOP_RESUME.since(t_done)
                 if decoded is not None:
@@ -1669,13 +1890,10 @@ class EcHandlers:
             # short of survivors: ask whoever has not been asked yet
             with _ST_SURVIVOR_READ():
                 await asyncio.gather(*(fetch(i) for i in more))
-        out = decoded[0]
-        if out is None:
-            return None
         with _ST_CACHE_PUT():
             # the span alone: what lay past it in the worker's rows was
             # cut off by the codec
-            span = np.ascontiguousarray(out).tobytes()
+            span = np.ascontiguousarray(decoded).tobytes()
             cache.put(ev.volume_id, missing_shard, span_start, span)
         EC_RECONSTRUCTIONS.inc(kind="cold")
         _INTERVAL["reconstructed"].inc()

@@ -129,13 +129,14 @@ class EcVolumeShard:
         return os.preadv(self._f.fileno(), [buf], offset)
 
     @staticmethod
-    def fill_rows(rows, sources, offset: int) -> Optional[list]:
+    def fill_rows(rows, sources, offset) -> Optional[list]:
         """Fill each of `rows` from the source beside it in ONE native
         call, which gives the interpreter lock up once for all of them: a
-        shard is read from `offset` on, anything else is a buffer of at
-        least the row's length, copied. -> one int a row, the bytes read
-        or copied, or -errno where a read failed; None without the native
-        library, where the caller reads a row at a time (`read_into`)."""
+        shard is read from `offset` on (an int for every row, or a
+        sequence of one a row), anything else is a buffer of at least the
+        row's length, copied. -> one int a row, the bytes read or copied,
+        or -errno where a read failed; None without the native library,
+        where the caller reads a row at a time (`read_into`)."""
         return native.fill_rows(
             rows,
             [s._f.fileno() if isinstance(s, EcVolumeShard) else s for s in sources],

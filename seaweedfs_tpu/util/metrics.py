@@ -678,7 +678,8 @@ EC_ENCODE_BATCH_FALLBACKS = REGISTRY.counter(
 # where a degraded read's wall goes. Of a cold reconstruct: survivor_read
 # (the gathers of remote survivor fetches on the loop, plus the worker's
 # wall filling the decode's input rows: local survivors read, fetched ones
-# copied in), executor_wait (submit -> the worker's first line), decode (the
+# copied in), batch_wait (handed to the batcher -> its job handed to the
+# executor), executor_wait (-> the worker's first line), decode (the
 # worker's wall around reconstruct_rows alone), loop_resume (the worker's
 # last line -> the coroutine's first line after the hop: the loop coming
 # round to it), cache_put; divide by ec_reconstructions_total{kind="cold"}. Before any reconstruct, hit or
@@ -688,8 +689,10 @@ EC_DEGRADED_READ_STAGE_SECONDS = REGISTRY.counter(
     "degraded EC read stage wall seconds, by stage (remote_attempts = the "
     "TTL'd location refresh, and where a holder is listed the holders "
     "tried and the forced refreshes after them, before any reconstruct; of "
-    "a cold reconstruct: survivor_read/executor_wait/decode/loop_resume/"
-    "cache_put, which add up to ec_degraded_read_seconds{result=cold}; "
+    "a cold reconstruct: batch_wait/survivor_read/executor_wait/decode/"
+    "loop_resume/cache_put, which add up to "
+    "ec_degraded_read_seconds{result=cold}, a stage that reconstructs of one "
+    "batch shared charged once to each; "
     "remote_read = each survivor fetched from another server, inside "
     "survivor_read: divide by ec_remote_shard_reads_total)",
 )
@@ -816,6 +819,13 @@ EC_RECONSTRUCT_FILLS = REGISTRY.counter(
     "= the first data_shards survivors read and copied into their rows in "
     "one native call, the interpreter lock given up once; row_by_row = a "
     "read or a copy a row, where the native library is missing)",
+)
+EC_RECONSTRUCT_BATCHES = REGISTRY.counter(
+    "seaweedfs_tpu_ec_reconstruct_batches_total",
+    "jobs of cold reconstructs handed to the executor together, one a job: "
+    "one fill of every member's survivor rows, one decode call a decode "
+    "matrix; ec_reconstructions_total{kind=cold} over it is the "
+    "reconstructs a job",
 )
 # the worker thread of a cold reconstruct, round the whole of its work
 # (fill + decode): wall without cpu is the worker waiting (the interpreter

@@ -642,7 +642,9 @@ class Stage:
       sampled, so a slow request in `/debug/traces` shows its stages.
 
     `sink`, when a run passes one, also gets ``sink.add(label, seconds)``
-    (the per-run budget of `write_ec_files`). With no profiler running and
+    (the per-run budget of `write_ec_files`). `riders`, where a batch's
+    members all waited out one wall, charges the counter that wall once a
+    member (the event and the span are one). With no profiler running and
     the request unsampled a stage costs two clock reads, one inactive
     TraceMe and one counter add."""
 
@@ -656,32 +658,33 @@ class Stage:
         self._calls = calls
         self._annotate = annotate
 
-    def __call__(self, sink=None) -> "_StageCM":
-        return _StageCM(self, sink)
+    def __call__(self, sink=None, riders: int = 1) -> "_StageCM":
+        return _StageCM(self, sink, riders)
 
-    def since(self, t0: float, sink=None) -> float:
+    def since(self, t0: float, sink=None, riders: int = 1) -> float:
         """Add the seconds since `t0` (a `perf_counter` reading) and
         return now: for a wait that starts on one thread and ends on
         another, which no `with` block can hold. Counter only."""
         now = _perf()
-        self._add(now - t0, sink)
+        self._add(now - t0, sink, riders)
         return now
 
-    def _add(self, dt: float, sink) -> None:
+    def _add(self, dt: float, sink, riders: int = 1) -> None:
         if self._seconds is not None:
-            self._seconds.inc(dt)
+            self._seconds.inc(dt * riders)
         if self._calls is not None:
-            self._calls.inc()
+            self._calls.inc(riders)
         if sink is not None:
-            sink.add(self.label, dt)
+            sink.add(self.label, dt * riders)
 
 
 class _StageCM:
-    __slots__ = ("_stage", "_sink", "_t0", "_event", "_span")
+    __slots__ = ("_stage", "_sink", "_riders", "_t0", "_event", "_span")
 
-    def __init__(self, st: Stage, sink):
+    def __init__(self, st: Stage, sink, riders: int = 1):
         self._stage = st
         self._sink = sink
+        self._riders = riders
 
     def __enter__(self):
         st = self._stage
@@ -712,7 +715,7 @@ class _StageCM:
             self._event.__exit__(et, ev, tb)
         if self._span is not None:
             self._span.__exit__(et, ev, tb)
-        self._stage._add(dt, self._sink)
+        self._stage._add(dt, self._sink, self._riders)
         return False
 
 

@@ -186,8 +186,9 @@ def get_all(served: Served, reader, order: list, stop: threading.Event, done: li
 @pytest.fixture(scope="module")
 def recorded(tmp_path_factory):
     """GETs on four connections while the conversions run one after another
-    (at least two, until a decode met an encode): the answers, the volumes
-    converted, and /metrics before and after."""
+    (at least two, until a decode met an encode and the loop was held off
+    while one ran: the two new metrics then have something to read): the
+    answers, the volumes converted, and /metrics before and after."""
     served = Served(tmp_path_factory.mktemp("converting"))
     try:
         reader = sealed_template.Reader(served.store, SEED, {"volume": DEGRADED, "on_shard": 3})
@@ -208,8 +209,10 @@ def recorded(tmp_path_factory):
             for vid in CONVERTED:
                 replies.append(served.server.run(served.convert(vid)))
                 converted.append(vid)
-                if len(converted) >= 2 and moved(before, served.metrics(), BESIDE,
-                                                 op="decode", beside="encode"):
+                now = served.metrics()
+                if (len(converted) >= 2
+                        and moved(before, now, BESIDE, op="decode", beside="encode")
+                        and moved(before, now, CONVERTING, mode="held")):
                     break
         finally:
             stop.set()

@@ -231,6 +231,34 @@ def test_fill_rows_copies_buffers_beside_the_reads(tmp_path):
     assert rows[1].tobytes() == rows[3].tobytes() == data[5_000:7_048]
 
 
+def test_fill_rows_reads_each_row_at_its_own_offset(tmp_path):
+    """An offset a row (the rows of several reconstructs in one call): reads
+    from two files at offsets of their own, copies beside them, and a read
+    in the middle that the file's end cuts short, which leaves the rows
+    after it whole; the int form still reads every row at the one offset."""
+    data, fd = _a_file(tmp_path, 40_000)
+    other, fd2 = _a_file(tmp_path, 40_001)
+    fetched = bytes(range(256)) * 4
+    rows = np.zeros((5, 1024), np.uint8)
+    offsets = [0, 39_500, 7, 31_000, 12_345]
+    try:
+        got = native.fill_rows(list(rows), [fd, fd2, fetched, fd2, fd], offsets)
+        with pytest.raises(ValueError):
+            native.fill_rows(list(rows), [fd] * 5, offsets[:4])
+        same = np.zeros((2, 1024), np.uint8)
+        assert native.fill_rows(list(same), [fd, fd], 3_000) == [1024, 1024]
+    finally:
+        os.close(fd)
+        os.close(fd2)
+    assert got == [1024, 501, 1024, 1024, 1024]
+    assert rows[0].tobytes() == data[:1024]
+    assert rows[1, :501].tobytes() == other[39_500:] and not rows[1, 501:].any()
+    assert rows[2].tobytes() == fetched
+    assert rows[3].tobytes() == other[31_000:32_024]
+    assert rows[4].tobytes() == data[12_345:13_369]
+    assert same[0].tobytes() == same[1].tobytes() == data[3_000:4_024]
+
+
 def test_fill_rows_of_no_rows_is_an_empty_answer(monkeypatch):
     """Nothing to fill is an empty list; without the library, None."""
     assert native.fill_rows([], [], 0) == []

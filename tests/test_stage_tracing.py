@@ -484,9 +484,11 @@ CONVERTING_METRICS = [
     "ec_background.convert_share", "ec_background.late_ms",
     "rs_kernel.decode_call_us", "rs_kernel.encode_call_us",
 ]
-# last: how a cold reconstruct's survivor rows were filled, read in the five
+# how a cold reconstruct's survivor rows were filled, read in the five
 # cells that GET (tests/test_ec_chunk_read.py evaluates it)
 FILL_METRICS = ["ec_read.one_call_fill_share"]
+# what the reconstruct batcher adds, read in the five cells that GET
+RECONSTRUCT_BATCH_METRICS = ["ec_read.reconstructs_per_batch", "ec_read.batch_wait_ms"]
 
 
 def get_cells_of(name: str) -> list:
@@ -655,7 +657,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
     new[-2:-2] = BATCH_METRICS  # before ISSUE 29's one and ISSUE 31's
     new += SPREAD_METRICS + CHUNK_METRICS + WORKER_READ_METRICS + LOOP_METRICS
     new += MAPPING_METRICS + CHUNK_SPREAD_METRICS + DEVICE_OPS_METRICS + CONVERTING_METRICS
-    new += FILL_METRICS
+    new += FILL_METRICS + RECONSTRUCT_BATCH_METRICS
     assert names[-len(new):] == new and len(names) == 13 + len(new)
     per_layer = {e["name"]: e for e in common.benchmark_json()["per_layer"]}
     for name in SPREAD_METRICS:  # the healthy cell has no remote survivor to read
@@ -683,7 +685,7 @@ def test_benchmark_json_gained_entries_at_the_end_and_lost_none():
         spec = common.load("layer_metrics", name + ".json")
         for key in ("unit", "better", "source", "layer", "moves"):
             assert per_layer[name][key] == spec[key], (name, key)
-    for name in FILL_METRICS:
+    for name in FILL_METRICS + RECONSTRUCT_BATCH_METRICS:
         assert per_layer[name]["workloads"] == GET_CELLS + [CONVERTING_CELL]
     assert len(json.dumps(common.benchmark_json())) < 64 << 10
 
