@@ -28,7 +28,7 @@ async def http_assign(
 ) -> AssignResult:
     """One `/dir/assign` over a FastHTTPClient-shaped keep-alive pool —
     the HTTP twin of the gRPC :func:`assign`, shared by the benchmark
-    clients' leases (`command/benchmark.py`, bench.py's open-loop leg).
+    clients' leases (`command/benchmark.py`).
     Status is checked BEFORE parsing: a non-JSON error body (plain-text
     500, dropped connection) must report the status, not die as a
     JSONDecodeError that hides it."""

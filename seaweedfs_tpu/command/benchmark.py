@@ -109,13 +109,12 @@ async def run_benchmark(
 ) -> str:
     """Returns the human report; when `stats_out` is given it also receives
     {write_qps, write_failed, read_qps, read_failed, write_stats,
-    read_stats, fids} for machine use (bench.py's serving-QPS north-star
-    entry), plus the write-path attribution legs {assign_stats, build_stats,
-    upload_stats} (per-request wall time of the assign RPC, client-side
-    request build, and upload RPC — they partition each recorded write
-    latency) and write_samples (early/final QPS sub-samples). `fids_in`
-    seeds the read phase so read-only passes (do_write=False) can re-read a
-    previously written set.
+    read_stats, fids} for machine use, plus the write-path attribution
+    legs {assign_stats, build_stats, upload_stats} (per-request wall time
+    of the assign RPC, client-side request build, and upload RPC — they
+    partition each recorded write latency) and write_samples (early/final
+    QPS sub-samples). `fids_in` seeds the read phase so read-only passes
+    (do_write=False) can re-read a previously written set.
 
     assign_batch > 1 leases file ids in count=N batches through an
     AssignLease (the reference benchmark's fid-reuse trick,

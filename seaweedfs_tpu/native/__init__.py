@@ -32,15 +32,13 @@ def _cpu_flags() -> set:
     return set()
 
 
-def _flag_candidates(max_tier: str = "best") -> list:
+def _flag_candidates() -> list:
     """Compiler-flag candidates for the SIMD this CPU actually has (the flag
     alone isn't enough — g++ accepts -mavx2 on any x86, then SIGILLs at
-    runtime). max_tier="avx2" caps at the PSHUFB tier — the technique of the
-    reference's vendored klauspost/reedsolomon v1.9.2 (pre-GFNI), used for
-    honest baseline measurement."""
+    runtime), best first."""
     have = _cpu_flags()
     candidates = []
-    if max_tier == "best" and {"gfni", "avx512f", "avx512bw"} <= have:
+    if {"gfni", "avx512f", "avx512bw"} <= have:
         candidates.append(["-mgfni", "-mavx512f", "-mavx512bw", "-mavx2"])
     if "avx2" in have:
         candidates.append(["-mavx2"])
@@ -65,11 +63,11 @@ def _tier_name(flags: list) -> str:
     return "-".join(f[2:] for f in flags) or "scalar"  # "-mavx2" -> "avx2"
 
 
-def _build_and_open(max_tier: str = "best"):
+def _build_and_open():
     """(CDLL, tier name) of the best flag set this CPU has that builds and
     loads, building from gf256.cpp when the named file is missing or older
     than the source; (None, "numpy") when no candidate does."""
-    for flags in _flag_candidates(max_tier):
+    for flags in _flag_candidates():
         lib = _lib_path(flags)
         if not os.path.exists(lib) or os.path.getmtime(lib) < os.path.getmtime(
             _SRC
@@ -162,42 +160,6 @@ def tier() -> str:
     no compiler was found and the table codec serves."""
     load()
     return _tier
-
-
-_base_lib = None
-_base_failed = False
-
-
-def load_baseline():
-    """The PSHUFB-tier (AVX2-capped) build of the same kernel source — the
-    technique of the reference's vendored klauspost/reedsolomon v1.9.2,
-    which predates GFNI support. Bench CPU baselines measure against this
-    so the GFNI tier registers as the technique win it is."""
-    global _base_lib, _base_failed
-    with _lock:
-        if _base_lib is not None or _base_failed:
-            return _base_lib
-        lib, _ = _build_and_open(max_tier="avx2")
-        if lib is None:
-            _base_failed = True
-            return None
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        lib.gf_matmul.argtypes = [
-            u8p, ctypes.c_int, ctypes.c_int,
-            ctypes.POINTER(u8p), ctypes.POINTER(u8p), ctypes.c_size_t,
-        ]
-        lib.gf_matmul.restype = None
-        _base_lib = lib
-        return _base_lib
-
-
-def gf_matmul_baseline(matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
-    """uint8[R,C] x uint8[C,N] -> uint8[R,N] via the PSHUFB-tier library."""
-    lib = load_baseline()
-    if lib is None:
-        raise RuntimeError("baseline gf256 library unavailable")
-    data = np.ascontiguousarray(data, dtype=np.uint8)
-    return _matmul_rows(lib, matrix, list(data))
 
 
 def encode_copy_available() -> bool:

@@ -11,9 +11,9 @@ Also home to the serving-plane load machinery that exercises those paths:
 
 - loadgen: open-loop (Poisson-arrival, coordinated-omission-corrected)
   load generation with zipfian key popularity and log-bucketed latency
-  histograms — the `serving.open_loop` bench leg's engine;
+  histograms;
 - proc_cluster: the multi-PROCESS cluster fixture (every server role a
   real OS process with readiness probes, per-child fault-plan env, and
-  no-orphan teardown) plus process-level fault delivery — the
-  `soak.production` chaos leg's substrate.
+  no-orphan teardown) plus process-level fault delivery (kill, pause,
+  restart on a seeded schedule).
 """

@@ -8,22 +8,18 @@ XOR-accumulates the t_k selected by the bits of its matrix entry.
 Layout: Mosaic vectorizes i32, not i8, so bytes are packed 4-per-uint32 lane
 and xtime runs byte-parallel inside each word with masks:
 
-    msb     = x & 0x80808080
     doubled = (x << 1) & 0xFEFEFEFE       # per-byte shift, bit0 cleared
-    r       = msb >> 7                     # 0x01 per overflowing byte
-    xtime   = doubled ^ (r<<4 ^ r<<3 ^ r<<2 ^ r)   # r * 0x1D
+    r       = (x & 0x80808080) >> 7        # 0x01 per overflowing byte
+    xtime   = doubled ^ r * 0x1D           # 1 * 0x1D < 256: no byte carries
 
-~9 i32 ops per 4 bytes — no gathers, no tables; pure VPU work that replaces
+6 i32 ops per 4 bytes — no gathers, no tables; pure VPU work that replaces
 the reference's table-driven SIMD GF multiply (klauspost/reedsolomon,
 ref: ec_encoder.go:198). All byte positions are independent so the uint32
 packing order never matters.
 
-Measured 65 GB/s data throughput on one v5e chip — VPU-compute-bound at
-~1.3e12 i32 ops/s. An MXU bit-slice formulation (GF(2) matmul of 80 bit
-planes by a static 32x80 bit matrix via int8 dot_general) was prototyped and
-is byte-correct but lands at the same ~63 GB/s: the bit unpack/repack is VPU
-work of the same magnitude as the xtime chains, so the VPU remains the
-bottleneck either way. Kept the packed formulation (simpler, no MXU).
+Speed on the chip: the benchmark's one reading of the kernel is
+`rs_encode_roofline`, 38.83 % of a v5e's 819 GB/s HBM bandwidth
+(PERF_LEDGER.jsonl); whether the VPU or HBM bounds it is not measured.
 
 Dispatch: a served call (gf_matmul_bytes) is ONE device program. The host
 packs the rows to uint32[C, W] with W a whole number of the kernel's blocks
@@ -42,7 +38,6 @@ be a launch (and a hand-over of the interpreter lock) of its own.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -60,33 +55,20 @@ from ..util.metrics import (
 )
 
 # x^8 + x^4 + x^3 + x^2 + 1 (0x11D), matching the galois tables (galois.py).
-# 0x1D = bits 4,3,2,0 — the shift set in _xtime.
+# 0x1D is the reduction _xtime multiplies each overflowing byte's 0x01 by.
 
 LANE = 128
-SUBLANE = 8  # i32 min tile sublane count
 _MSB = np.uint32(0x80808080)
 _LOW7 = np.uint32(0xFEFEFEFE)
 _POLY = np.uint32(0x1D)
 
-# xtime formulation: "mul" folds the 0x1D reduction into one byte-parallel
-# i32 multiply (r is 0x00/0x01 per byte, and 1*0x1D = 29 < 256 so no byte
-# crosses its lane) — 6 VPU ops vs the 11-op shift/xor chain. The kernel is
-# VPU-op-bound, so fewer ops per word is directly throughput (measured in
-# bench kernel_roofline; override with SEAWEED_GF_XTIME=shift to compare).
-_XTIME_MODE = os.environ.get("SEAWEED_GF_XTIME", "mul")
 
-
-def _xtime(x, mode: str | None = None):
+def _xtime(x):
     """Byte-parallel multiply-by-2 in GF(2^8) on packed uint32 words."""
-    if (mode or _XTIME_MODE) == "mul":
-        return ((x << 1) & _LOW7) ^ (((x & _MSB) >> 7) * _POLY)
-    msb = x & _MSB
-    doubled = (x << 1) & _LOW7
-    r = msb >> 7
-    return doubled ^ (r << 4) ^ (r << 3) ^ (r << 2) ^ r
+    return ((x << 1) & _LOW7) ^ (((x & _MSB) >> 7) * _POLY)
 
 
-def gf_matmul_expr(matrix: np.ndarray, rows: list, xtime_mode: str | None = None):
+def gf_matmul_expr(matrix: np.ndarray, rows: list):
     """out[i] = XOR_j matrix[i,j] * rows[j] in GF(2^8), on packed uint32.
 
     matrix is a static numpy uint8 [R, C]; rows is a list of C equal-shaped
@@ -107,69 +89,36 @@ def gf_matmul_expr(matrix: np.ndarray, rows: list, xtime_mode: str | None = None
                 if (col[i] >> k) & 1:
                     acc[i] = t if acc[i] is None else acc[i] ^ t
             if k + 1 < max_bits:
-                t = _xtime(t, xtime_mode)
+                t = _xtime(t)
     zero = jnp.zeros_like(rows[0])
     return [a if a is not None else zero for a in acc]
 
 
-def count_expr_ops(matrix: np.ndarray, xtime_mode: str | None = None) -> int:
-    """Static i32-op count of gf_matmul_expr per packed input WORD COLUMN
-    (i.e. per 4 bytes of every input row together) — the numerator of the
-    VPU roofline in bench kernel_roofline."""
-    mode = xtime_mode or _XTIME_MODE
-    per_xtime = 6 if mode == "mul" else 11
-    matrix = np.asarray(matrix, dtype=np.uint8)
-    r_cnt, c_cnt = matrix.shape
-    ops = 0
-    # acc in gf_matmul_expr is shared across COLUMNS: only each row's very
-    # first contribution overall is free, not its first per column
-    first = [True] * r_cnt
-    for j in range(c_cnt):
-        col = [int(matrix[i, j]) for i in range(r_cnt)]
-        max_bits = max((c.bit_length() for c in col), default=0)
-        if max_bits == 0:
-            continue
-        ops += (max_bits - 1) * per_xtime  # the shared chain
-        for k in range(max_bits):
-            for i in range(r_cnt):
-                if (col[i] >> k) & 1:
-                    if not first[i]:
-                        ops += 1  # XOR-accumulate
-                    first[i] = False
-    return ops
-
-
 # --- pure-jnp path (CPU fallback + reference for the kernel) ---
-@functools.partial(jax.jit, static_argnums=(0, 2))
-def _gf_matmul_jnp_packed(matrix_key, packed, xtime_mode: str | None = None):
+@functools.partial(jax.jit, static_argnums=(0,))
+def _gf_matmul_jnp_packed(matrix_key, packed):
     matrix = np.asarray(matrix_key, dtype=np.uint8)
     rows = [packed[j] for j in range(matrix.shape[1])]
-    return jnp.stack(gf_matmul_expr(matrix, rows, xtime_mode))
+    return jnp.stack(gf_matmul_expr(matrix, rows))
 
 
 # --- pallas kernel ---
-def _gf_kernel(matrix: np.ndarray, xtime_mode, data_ref, out_ref):
+def _gf_kernel(matrix: np.ndarray, data_ref, out_ref):
     c_cnt = matrix.shape[1]
     rows = [data_ref[j] for j in range(c_cnt)]
-    outs = gf_matmul_expr(matrix, rows, xtime_mode)
+    outs = gf_matmul_expr(matrix, rows)
     for i, o in enumerate(outs):
         out_ref[i] = o
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
-def _gf_matmul_pallas(
-    matrix_key,
-    packed3d,
-    block_rows: int,
-    interpret: bool,
-    xtime_mode: str | None = None,
-):
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def _gf_matmul_pallas(matrix_key, packed3d, block_rows: int, interpret: bool):
     """packed3d: uint32[C, S, LANE] with S % block_rows == 0 -> [R, S, LANE]."""
     matrix = np.asarray(matrix_key, dtype=np.uint8)
     r_cnt, c_cnt = matrix.shape
     _, s, lane = packed3d.shape
     return pl.pallas_call(
-        functools.partial(_gf_kernel, matrix, xtime_mode),
+        functools.partial(_gf_kernel, matrix),
         out_shape=jax.ShapeDtypeStruct((r_cnt, s, lane), jnp.uint32),
         grid=(s // block_rows,),
         in_specs=[
@@ -211,42 +160,6 @@ def unpack_bytes_host(packed: np.ndarray, n: int) -> np.ndarray:
     [R, S, LANE]) -> uint8[R, n], a view of the words."""
     packed = np.ascontiguousarray(packed)
     return packed.reshape(packed.shape[0], -1).view(np.uint8)[:, :n]
-
-
-def gf_matmul_packed(
-    matrix: np.ndarray,
-    packed,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    force_pallas: bool | None = None,
-    interpret: bool = False,
-    xtime_mode: str | None = None,
-):
-    """GF(2^8) matmul on packed words: uint32[C, W] -> uint32[R, W].
-
-    The native device API — keeps data uint32 end-to-end (the kernel is
-    HBM-bound at this layout; measured ~450 GB/s data throughput on v5e).
-    For words already on the device (tests, bench.py); a served call goes
-    through gf_matmul_bytes. The Pallas path pads W to a whole number of
-    blocks and reshapes on the device, each a program of its own; the jnp
-    path takes any W.
-    """
-    matrix = np.asarray(matrix, dtype=np.uint8)
-    packed = jnp.asarray(packed, dtype=jnp.uint32)
-    assert packed.shape[0] == matrix.shape[1], (packed.shape, matrix.shape)
-    key = tuple(map(tuple, matrix))
-    use_pallas = force_pallas if force_pallas is not None else on_tpu()
-    if not use_pallas and not interpret:
-        return _gf_matmul_jnp_packed(key, packed, xtime_mode)
-    w = packed.shape[1]
-    granule = block_rows * LANE
-    if w % granule:
-        packed = jnp.pad(packed, ((0, 0), (0, granule - w % granule)))
-    out = _gf_matmul_pallas(
-        key, packed.reshape(packed.shape[0], -1, LANE), block_rows,
-        interpret, xtime_mode,
-    )
-    out = out.reshape(out.shape[0], -1)
-    return out if out.shape[1] == w else out[:, :w]
 
 
 RS_OPS = ("encode", "decode", "apply")
@@ -337,7 +250,7 @@ def compile_gf_matmul_bytes(
     widths = range(granule, max_width + 1, granule)
     for w in widths:
         words = np.zeros((len(key[0]), w // (4 * LANE), LANE), np.uint32)
-        np.asarray(_gf_matmul_pallas(key, words, block_rows, interpret, None))
+        np.asarray(_gf_matmul_pallas(key, words, block_rows, interpret))
     return len(widths)
 
 
@@ -410,9 +323,9 @@ def gf_matmul_bytes(
                 other_met.add(op)
         with stages["dispatch"]():
             if use_pallas or interpret:
-                out = _gf_matmul_pallas(key, words, block_rows, interpret, None)
+                out = _gf_matmul_pallas(key, words, block_rows, interpret)
             else:
-                out = _gf_matmul_jnp_packed(key, words, None)
+                out = _gf_matmul_jnp_packed(key, words)
         with stages["fetch"]():
             out = np.asarray(out)
     finally:
@@ -424,75 +337,3 @@ def gf_matmul_bytes(
         matrix.shape[0] + matrix.shape[1], n, packed.shape[1] * 4,
     )
     return unpack_bytes_host(out, n)
-
-
-# --- MXU bit-slice prototype (VERDICT r4 item 5) ---
-#
-# GF(2^8) multiplication by a CONSTANT is GF(2)-linear on the 8 bits of
-# the input byte, so the whole RS(10,4) encode is one binary matmul:
-# out_bits[N, R*8] = in_bits[N, C*8] @ B[C*8, R*8]  (mod 2), which is MXU
-# food (int8 dot + parity) instead of VPU shift/xor chains. The unpack/
-# repack to bit-planes is the tax: 8x the data volume through HBM unless
-# fused into the matmul kernel. This prototype keeps the jnp formulation
-# (XLA decides the fusion) and exists to MEASURE that trade against the
-# packed VPU kernel — bench leg `kernel_mxu_bitslice` — not to ship it.
-# An earlier out-of-tree version measured ~63 GB/s on v5e, on par with the
-# VPU formulation; in-tree now so the number is reproducible.
-
-
-@functools.lru_cache(maxsize=None)
-def _bitslice_matrix(matrix_key) -> np.ndarray:
-    """B[C*8, R*8] over GF(2): column block r, bit b gets the b-th bit of
-    matrix[r, c] * 2^k for input bit k of input byte c."""
-    from ..storage.erasure_coding.galois import MUL_TABLE
-
-    matrix = np.asarray(matrix_key, dtype=np.uint8)
-    r_cnt, c_cnt = matrix.shape
-    B = np.zeros((c_cnt * 8, r_cnt * 8), dtype=np.int8)
-    for c in range(c_cnt):
-        for k in range(8):
-            for r in range(r_cnt):
-                prod = int(MUL_TABLE[matrix[r, c], 1 << k])
-                for b in range(8):
-                    B[c * 8 + k, r * 8 + b] = (prod >> b) & 1
-    return B
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _gf_matmul_bitsliced_jit(matrix_key, packed):
-    matrix = np.asarray(matrix_key, dtype=np.uint8)
-    r_cnt, c_cnt = matrix.shape
-    B = jnp.asarray(_bitslice_matrix(matrix_key))
-    w = packed.shape[1]
-    # packed uint32[C, W] -> bytes uint8[C, W*4] -> bits int8[N, C*8]
-    data = jax.lax.bitcast_convert_type(
-        packed.reshape(c_cnt, w, 1), jnp.uint8
-    ).reshape(c_cnt, w * 4)
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (
-        (data.T[:, :, None] >> shifts[None, None, :]) & 1
-    ).astype(jnp.int8).reshape(w * 4, c_cnt * 8)
-    # MXU: int8 x int8 -> int32 accumulation, then parity
-    out_bits = (
-        jax.lax.dot_general(
-            bits, B, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        & 1
-    ).astype(jnp.uint8).reshape(w * 4, r_cnt, 8)
-    # repack: bits -> bytes -> uint32 words
-    weights = (jnp.uint8(1) << shifts)[None, None, :]
-    out_bytes = (out_bits * weights).sum(axis=2, dtype=jnp.uint8)
-    return jax.lax.bitcast_convert_type(
-        out_bytes.T.reshape(r_cnt, w, 4), jnp.uint32
-    ).reshape(r_cnt, w)
-
-
-def gf_matmul_bitsliced(matrix: np.ndarray, packed):
-    """MXU bit-slice route: uint32[C, W] -> uint32[R, W], byte-identical
-    to gf_matmul_packed. Prototype — see module note above."""
-    matrix = np.asarray(matrix, dtype=np.uint8)
-    key = tuple(map(tuple, matrix))
-    packed = jnp.asarray(packed, dtype=jnp.uint32)
-    assert packed.shape[0] == matrix.shape[1], (packed.shape, matrix.shape)
-    return _gf_matmul_bitsliced_jit(key, packed)

@@ -1,11 +1,10 @@
 """Subprocess cluster fixture: every role a real OS process.
 
-The bench legs' `_start_cluster_thread` scaffolding proves the serving
-planes inside ONE process (dedicated thread + event loop). That shape
+A cluster inside ONE process (a thread and an event loop per server)
 cannot host process-level chaos — SIGKILL has no per-thread aim — so this
-module promotes it to real processes: master + N volume servers + a filer
-fleet + S3 gateway + blob backend, each spawned through the `weed-tpu`
-CLI entry points (`python -m seaweedfs_tpu <role> ...`), with
+module runs real processes: master + N volume servers + a filer fleet +
+S3 gateway + blob backend, each spawned through the `weed-tpu` CLI entry
+points (`python -m seaweedfs_tpu <role> ...`), with
 
 - readiness probes (`/metrics` answering 200 before a child counts as
   up, with the child's log tail in the error when it does not);
@@ -50,9 +49,8 @@ from typing import Optional
 
 from ..util.faults import FaultPlan, ProcessFault, partition
 
-# distinct band from bench.py's _free_port_pair (18200-19200): a soak
-# leg running inside the bench process must not race its threaded legs
-# for ports
+# the band free_port_pair scans for a child's HTTP port; its gRPC twin is
+# _GRPC_OFFSET above, so the band stays clear of ephemeral ports
 _PORT_LO, _PORT_HI = 19300, 20800
 _GRPC_OFFSET = 10000
 

@@ -108,10 +108,6 @@ class BatchLookupGate:
             "small_wakeups": 0,
             "identity_mismatches": 0,
         }
-        # pow2-bucketed flush sizes: the batch-size distribution this
-        # gate ACTUALLY produces, scraped by the device-lookup bench leg
-        # so its ragged batches match production shape
-        self.batch_hist: dict = {}
 
     def lookup(self, vid: int, key: int):
         """Awaitable -> (offset_units, size) or None when absent/deleted.
@@ -165,8 +161,6 @@ class BatchLookupGate:
         pending, self._pending, count = self._pending, {}, self._count
         self._count = 0
         traces, self._pending_traces = self._pending_traces, {}
-        bucket = 1 << max(0, (count - 1).bit_length())
-        self.batch_hist[bucket] = self.batch_hist.get(bucket, 0) + 1
         if self.arena is not None and count >= _ARENA_MIN_WAKEUP:
             self._flush_arena(pending, traces, count)
             return

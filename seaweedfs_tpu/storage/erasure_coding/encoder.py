@@ -1052,7 +1052,6 @@ def rebuild_ec_files(
     codec=None,
     chunk: int = DEFAULT_CHUNK,
     pipeline: Optional[bool] = None,
-    full_reconstruct: bool = False,
     route: Optional[str] = None,
 ) -> list[int]:
     """Reconstruct missing .ecNN files from survivors; returns the generated
@@ -1080,8 +1079,6 @@ def rebuild_ec_files(
     LAST_REBUILD_ROUTE. route=None picks the host structure by a one-time
     measured race (_calibrate_rebuild_route: pread vs mmap vs fused
     onepass); route="pread"/"mmap"/"onepass" forces one.
-    full_reconstruct=True keeps the old all-rows codec.reconstruct per
-    chunk (the benchmark's reference leg).
 
     Serialized per volume base (process-wide): a concurrent second rebuild
     of the same volume waits, re-surveys, and returns [] — it can never
@@ -1089,7 +1086,7 @@ def rebuild_ec_files(
     """
     with _base_rebuild_lock(base_file_name):
         return _rebuild_ec_files_unlocked(
-            base_file_name, codec, chunk, pipeline, full_reconstruct, route
+            base_file_name, codec, chunk, pipeline, route
         )
 
 
@@ -1098,7 +1095,6 @@ def _rebuild_ec_files_unlocked(
     codec,
     chunk: int,
     pipeline: Optional[bool],
-    full_reconstruct: bool,
     route: Optional[str],
 ) -> list[int]:
     import time as _time
@@ -1124,7 +1120,6 @@ def _rebuild_ec_files_unlocked(
     # route skips the race (the race's own legs, benchmarks, tests)
     if (
         route is None
-        and not full_reconstruct
         and shard_size > 0
         and getattr(codec, "zero_copy_rows", False)
         and not getattr(codec, "is_device", False)
@@ -1166,17 +1161,12 @@ def _rebuild_ec_files_unlocked(
         slots: list, width: int, out: Optional[np.ndarray] = None
     ) -> list[np.ndarray]:
         t0 = _time.perf_counter()
-        if full_reconstruct:
-            full = codec.reconstruct(slots)
-            outs = [np.ascontiguousarray(full[i]) for i in missing]
-        else:
-            outs = [
-                np.ascontiguousarray(o)
-                for o in codec.reconstruct_rows(
-                    slots, missing,
-                    out=out[:, :width] if out is not None else None,
-                )
-            ]
+        outs = [
+            np.ascontiguousarray(o)
+            for o in codec.reconstruct_rows(
+                slots, missing, out=out[:, :width] if out is not None else None
+            )
+        ]
         _rebuild_stage_add("decode_s", _time.perf_counter() - t0)
         return outs
 

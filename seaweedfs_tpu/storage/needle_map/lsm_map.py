@@ -1096,8 +1096,8 @@ class LsmNeedleMap:
             pass
 
     def bloom_stats(self) -> dict:
-        """Aggregate per-run filter economics (the needle_map.lookup
-        bench leg's disclosure): probes that consulted a filter, probes
+        """Aggregate per-run filter economics (what /debug/needle_map
+        serves): probes that consulted a filter, probes
         a filter short-circuited, how many runs carry one, the active
         consultation threshold, and the per-run consult/hit counts
         (newest run last, matching probe order reversed)."""

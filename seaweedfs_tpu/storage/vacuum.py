@@ -19,8 +19,8 @@ structure in `LAST_VACUUM_ROUTE`. Optional CRC verification
 (`SEAWEEDFS_TPU_VACUUM_VERIFY` / verify=True) re-parses every copied
 record through the same CRC-verifying needle parser the scrubber uses, so
 a verified vacuum doubles as a scrub pass over the live set. The
-per-needle loop survives as `_copy_naive` — the benchmark baseline and
-the fallback for TTL volumes (expiry needs the per-needle timestamps).
+per-needle loop survives as `_copy_naive`, the path of TTL volumes
+(expiry needs the per-needle timestamps).
 
 Crash safety: `commit_compact` renames .cpd over .dat and then .cpx over
 .idx. Volume load (`sweep_compaction_shadows`) repairs every interruption

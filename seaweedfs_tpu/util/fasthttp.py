@@ -826,7 +826,7 @@ class FastHTTPServer:
             self._server.close()
         # connections first: since Python 3.12 wait_closed() returns only
         # when every connection is gone, and a keep-alive client never
-        # hangs up by itself (five bench legs waited here for ever)
+        # hangs up by itself (a server's stop() waited here for ever)
         for conn in list(self._conns):
             try:
                 if conn.transport is not None:

@@ -193,14 +193,6 @@ class Histogram(_Labeled):
     def _series_dicts(self) -> list:
         return [self._counts, self._sums, self._totals, self._exemplars]
 
-    def sum_count(self, **labels) -> tuple:
-        """(sum, count) snapshot for one label set — bench legs
-        difference these across a measured window to get per-stage
-        averages without parsing the rendered exposition."""
-        key = tuple(sorted(labels.items()))
-        with self._lock:
-            return self._sums.get(key, 0.0), self._totals.get(key, 0)
-
     def _exemplar_suffix(self, key: tuple, idx: int) -> str:
         ex = self._exemplars.get((key, idx))
         if ex is None:

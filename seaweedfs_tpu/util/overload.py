@@ -193,7 +193,7 @@ class AdaptiveLimiter:
 # every percentile carries <= ~19% relative error, recording is one log
 # + one list increment — cheap enough for the admitted fast path, and
 # the per-server admitted p50/p99 it yields is the number an operator
-# (and the overload bench) actually wants next to shed counts
+# actually wants next to shed counts
 _LAT_BASE = 1e-6
 _LAT_LOG_G = math.log(math.sqrt(2.0))
 _LAT_BUCKETS = 64
@@ -242,8 +242,7 @@ class _TenantState:
 
     __slots__ = (
         "name", "weight", "quota", "admitted", "shed", "queued",
-        "inflight", "admitted_counts", "pinned", "t_seen", "pub_label",
-        "pub_queued",
+        "inflight", "pinned", "t_seen", "pub_label", "pub_queued",
     )
 
     def __init__(self, name: str, weight: float, quota):
@@ -263,11 +262,6 @@ class _TenantState:
         # wins per tenant would under-report and zero out real backlog)
         self.pub_label = None
         self.pub_queued = 0
-        # per-tenant twin of AdmissionGate.admitted_counts (log-bucketed
-        # server-side wait+service): the fairness bench judges tenant
-        # isolation on THESE — a saturated open-loop generator's own
-        # client backlog rides the RTT numbers, not the server's
-        self.admitted_counts = [0] * _LAT_BUCKETS
 
 
 class AdmissionGate:
@@ -353,7 +347,7 @@ class AdmissionGate:
 
     def set_read_budget(self, read_budget_s: float) -> None:
         """Reset the per-class queue-wait budgets from the foreground-read
-        budget (benches scale it from a measured baseline p99)."""
+        budget (a caller may scale it from a measured baseline p99)."""
         self.queue_budget_s = tuple(
             read_budget_s * s for s in _BUDGET_SCALE
         )
@@ -423,8 +417,8 @@ class AdmissionGate:
         self, name: str, qps: float = 0.0, byte_ps: float = 0.0,
         burst_s: float = 1.0,
     ) -> None:
-        """Install/replace one tenant's quota buckets (bench legs and
-        shell tooling; env config covers the deployed path)."""
+        """Install/replace one tenant's quota buckets (tests and shell
+        tooling; env config covers the deployed path)."""
         ts = self._tenant(name)
         ts.pinned = True  # operator-installed: survives table pruning
         ts.quota = (
@@ -440,16 +434,6 @@ class AdmissionGate:
         ts = self._tenant(name)
         ts.pinned = True  # operator-installed: survives table pruning
         ts.weight = min(100.0, max(0.1, weight))
-
-    def tenant_admitted_counts(self, name: str) -> list:
-        """Snapshot of one tenant's log-bucketed server-side admitted
-        latency counts (see latency_percentile); zeros when unseen."""
-        ts = self._tenants.get(name)
-        return (
-            list(ts.admitted_counts)
-            if ts is not None
-            else [0] * _LAT_BUCKETS
-        )
 
     def _tenant_depth(self, ts: _TenantState) -> None:
         """Publish ts's queued count into the per-LABEL depth gauge.
@@ -600,6 +584,13 @@ class AdmissionGate:
         try:
             await asyncio.wait_for(fut, left)
         except asyncio.TimeoutError:
+            if fut.done() and not fut.cancelled():
+                # _wake granted the slot in the same turn as the timeout
+                # fired: the grant already counted it inflight and out of
+                # the queue, so the request is admitted and releases as
+                # any other (shedding it would leak the slot for good)
+                self._fut_tenant.pop(fut, None)
+                return True
             # wait_for cancelled the future; _next_queued skips
             # cancelled entries lazily — only the live count must drop
             # NOW
@@ -688,8 +679,6 @@ class AdmissionGate:
             if ts.quota is not None and resp_bytes:
                 ts.quota.charge_bytes(resp_bytes)
         if total_s is not None:
-            if ts is not None:
-                ts.admitted_counts[i] += 1
             self._check_children_gen()
             label = tenancy.tenant_label(name)
             child = self._tlat_children.get(label)

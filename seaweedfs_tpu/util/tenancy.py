@@ -98,7 +98,7 @@ def _parse_kv_env(name: str) -> dict:
 
 class TenancyConfig:
     """Weights + quota rates, env-parsed once and overridable for tests
-    and bench legs (`configure`)."""
+    (`configure`)."""
 
     def __init__(self):
         self.reload()
@@ -416,13 +416,13 @@ def note_heat(name: str) -> None:
 
 
 def reset_policy(cap: Optional[int] = None, **kw) -> None:
-    """Fresh label policy (tests / bench legs). The OLD policy's
+    """Fresh label policy (tests). The OLD policy's
     admitted labels are purged first: a swap that abandoned them would
     leave series no retirement can ever reach — permanently stale
     cardinality that breaks the cumulative cap invariant (and made the
     test suite order-dependent before this purge existed). Live
     counters of currently-admitted tenants restart; acceptable for a
-    test/bench hook."""
+    test hook."""
     global POLICY
     for name in POLICY.admitted():
         _purge_retired(name)

@@ -26,10 +26,9 @@ fan-out it rode. This module closes that attribution gap:
   histogram over every root request) are retro-promoted, and requests
   that touched an error / retry / hedge / injected fault are flagged on
   their context and promoted at finish. The unsampled fast path allocates
-  NOTHING per request — no context object, no span — which the
-  `serving.trace_overhead` bench leg asserts via the admission counters
-  (ring admissions == spans of sampled+promoted requests, never one per
-  request).
+  NOTHING per request — no context object, no span — which the admission
+  counters show (ring admissions == spans of sampled+promoted requests,
+  never one per request).
 
 - **Span links**: batch seams (lookup gate, chunk-upload gate, group
   commit) amortize many requests into one flush; the flush records ONE
@@ -210,8 +209,8 @@ class Recorder:
         capacity: Optional[int] = None,
         min_roots: int = 500,
     ) -> None:
-        """(Re)configure and reset counters/ring — tests and the
-        trace_overhead bench flip enabled/sample between phases."""
+        """(Re)configure and reset counters/ring — tests flip
+        enabled/sample between phases."""
         with self._lock:
             if enabled is not None:
                 self.enabled = enabled

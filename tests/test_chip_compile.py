@@ -124,7 +124,7 @@ def test_gf_kernel_compiles_at_served_width(one_chip, make_matrix, words):
     )
     m = _compile(
         gf256._gf_matmul_pallas.lower(
-            tuple(map(tuple, matrix)), x, gf256.DEFAULT_BLOCK_ROWS, False, None
+            tuple(map(tuple, matrix)), x, gf256.DEFAULT_BLOCK_ROWS, False
         ),
         pallas=True,
     )

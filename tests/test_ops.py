@@ -248,23 +248,3 @@ def test_native_codec_matches_oracle():
         full = nat.reconstruct(partial)
         for i in range(k + m):
             assert np.array_equal(full[i], shards[i])
-
-
-def test_gf_matmul_bitsliced_matches_packed():
-    """The MXU bit-slice prototype (GF(2) matmul over bit planes) must be
-    byte-identical to the shipping packed formulation, including the
-    xtime-chain math it replaces."""
-    from seaweedfs_tpu.ops.gf256 import (
-        gf_matmul_bitsliced,
-        gf_matmul_packed,
-        pack_bytes_host,
-    )
-    from seaweedfs_tpu.storage.erasure_coding.coder_cpu import CpuRSCodec
-
-    cpu = CpuRSCodec()
-    rng = np.random.default_rng(7)
-    data = rng.integers(0, 256, size=(10, 2048), dtype=np.uint8)
-    packed = pack_bytes_host(data)
-    a = np.asarray(gf_matmul_packed(cpu.parity_matrix, packed))
-    b = np.asarray(gf_matmul_bitsliced(cpu.parity_matrix, packed))
-    assert (a == b).all()

@@ -247,6 +247,43 @@ def test_gate_queued_wait_past_budget_sheds():
     asyncio.run(main())
 
 
+def test_gate_grant_in_the_turn_of_the_timeout_is_an_admission(monkeypatch):
+    """The slot is granted in the same loop turn as the waiter's budget
+    runs out, so `wait_for` raises TimeoutError over a granted future.
+    The grant counted the request inflight and out of the queue: it is
+    admitted, and its release frees the slot. Shedding it instead left
+    the slot held for good and `queued` below zero, until every slot was
+    held and the gate shed everything by deadline."""
+    g = _gate(read_budget_s=0.02)
+    real_wait_for = asyncio.wait_for
+
+    async def grant_then_time_out(fut, timeout):
+        g.release()  # a slot frees; _wake grants `fut`
+        assert fut.done() and fut.result() is True
+        raise asyncio.TimeoutError
+
+    async def main():
+        assert g.try_admit(CLASS_READ) is True
+        assert g.try_admit(CLASS_READ) is True  # limit 2 reached
+        fut = g.try_admit(CLASS_READ)
+        assert asyncio.isfuture(fut)
+        monkeypatch.setattr(overload.asyncio, "wait_for", grant_then_time_out)
+        try:
+            assert await g.wait_queued(CLASS_READ, fut, 0.0) is True
+        finally:
+            monkeypatch.setattr(overload.asyncio, "wait_for", real_wait_for)
+        assert (g.inflight, g.queued) == (2, 0)
+        assert (CLASS_READ, "deadline", "default") not in g._shed_children
+        assert not g._fut_tenant
+        g.release()
+        g.release()
+        assert (g.inflight, g.queued) == (0, 0)
+        assert g.try_admit(CLASS_READ) is True
+        g.release()
+
+    asyncio.run(main())
+
+
 def test_gate_pressure_signal_decays():
     clk = FakeClock()
     g = _gate(clock=clk)

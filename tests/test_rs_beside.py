@@ -35,7 +35,7 @@ class HeldKernel:
         self.entered[name] = threading.Event()
         self.release[name] = threading.Event()
 
-    def __call__(self, key, words, _mode):
+    def __call__(self, key, words):
         name = threading.current_thread().name
         if name in self.entered:
             self.entered[name].set()
